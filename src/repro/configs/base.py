@@ -3,8 +3,9 @@
 One ``ModelConfig`` dataclass covers every assigned architecture family
 (dense / MoE / enc-dec / VLM / SSM / hybrid); family-specific fields are
 ignored elsewhere.  ``smoke()`` derives the reduced-size variant used by CPU
-smoke tests; the full config is only ever traced via ShapeDtypeStruct in the
-multi-pod dry-run.
+smoke tests and ``serving()`` the variant the serving path runs (full width
+on the chip, see ``chip_smoke.py``); the multi-pod dry-run traces full
+configs via ShapeDtypeStruct.
 """
 
 from __future__ import annotations
@@ -103,6 +104,12 @@ class ModelConfig:
     @property
     def has_decoder(self) -> bool:
         return True  # no encoder-only arch in the assigned pool
+
+    def serving(self) -> "ModelConfig":
+        """The variant the serving path runs: per-layer params (the stage
+        executor places layers one by one) and the Pallas attention kernel,
+        which specializes on each layer's static window."""
+        return replace(self, scan_layers=False, attention_impl="pallas")
 
     def smoke(self) -> "ModelConfig":
         """Reduced config of the same family for CPU smoke tests."""

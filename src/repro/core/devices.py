@@ -282,6 +282,66 @@ TPU_V5E_HBM_BYTES = 16 * GB     # per chip
 TPU_ICI_BW = 50e9               # bytes/s per link (per direction)
 TPU_DCN_BW = 25e9 / 8 * 8       # ~25 GB/s host DCN (inter-pod)
 
+# Published per-chip peaks keyed by ``jax.Device.device_kind``:
+# (bf16 FLOP/s, HBM bytes, HBM bytes/s).  Source: Google Cloud
+# documentation, "TPU v5e" (197 TFLOP/s bf16, 16 GB HBM at 819 GB/s).
+CHIP_PEAKS: Dict[str, Tuple[float, float, float]] = {
+    "TPU v5 lite": (TPU_V5E_PEAK_BF16, TPU_V5E_HBM_BYTES, TPU_V5E_HBM_BW),
+}
+# what a CPU rehearsal of the serving path plans for
+REHEARSAL_KIND = "TPU v5 lite"
+
+
+def chip_spec(device_kind: str) -> DeviceSpec:
+    """One chip's :class:`DeviceSpec` from :data:`CHIP_PEAKS`; a kind
+    missing from the table is an error, never a default."""
+    if device_kind not in CHIP_PEAKS:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}: add it to "
+            "CHIP_PEAKS with its source"
+        )
+    flops, mem, bw = CHIP_PEAKS[device_kind]
+    return DeviceSpec(device_kind, peak_flops=flops, mem_bytes=mem, hbm_bw=bw,
+                      kind="tpu")
+
+
+def device_cluster(
+    devices: Sequence,
+    n: Optional[int] = None,
+    *,
+    heterogeneous: bool = False,
+) -> ClusterSpec:
+    """The cluster the planner sees for ``devices`` (``jax.devices()``): one
+    chip per device, with the peaks of its ``device_kind``, in an ICI ring.
+
+    ``n`` models a fleet of that many such chips instead (plans for more
+    chips than this host holds).  Off the TPU (a CPU rehearsal) each device
+    stands in for a :data:`REHEARSAL_KIND` chip.  ``heterogeneous=True``
+    halves the speed of every odd chip, the mixed-generation case Moirai
+    targets."""
+    kinds = {
+        d.device_kind if d.platform == "tpu" else REHEARSAL_KIND
+        for d in devices
+    }
+    if len(kinds) != 1:
+        raise ValueError(f"mixed device kinds {sorted(kinds)}")
+    spec = chip_spec(kinds.pop())
+    n = n or len(devices)
+    chips = [
+        _dc_replace(spec, name=f"{spec.name}#{i}").derated(
+            0.5 if heterogeneous and i % 2 else 1.0
+        )
+        for i in range(n)
+    ]
+    bw = np.zeros((n, n))
+    for i in range(n):
+        j = (i + 1) % n
+        if j != i:
+            bw[i, j] = bw[j, i] = TPU_ICI_BW
+    lat = np.full((n, n), 1e-6)
+    np.fill_diagonal(lat, 0.0)
+    return ClusterSpec(chips, bw, lat, name=f"{spec.name} x{n}")
+
 
 def tpu_slice_cluster(
     n_slices: int = 4,

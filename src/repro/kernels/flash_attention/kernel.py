@@ -19,8 +19,10 @@ decode rows at ``q_len=1``, prefill chunks at ``q_len=chunk``, idle rows at
 ``q_len=0``, every serving slot at its own cache depth — run in ONE kernel
 launch with per-row causal masks; queries beyond a row's ``q_len`` are
 fully masked and produce exact zeros (the fused-batch padding contract).
-BQ=BK=128 blocks align with the 128×128 MXU; ops.py pads head_dim to a
-lane multiple.
+BQ=128 query rows per block; KV blocks are BK=64 tokens, one page of a
+64-token paged pool, so the dense and paged kernels walk KV in the same
+tiles and, on the same K/V, return bit-identical attention at that page
+size.  Every block spans the whole head_dim.
 """
 
 from __future__ import annotations
@@ -34,7 +36,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BQ = 128
-DEFAULT_BK = 128
+DEFAULT_BK = 64
 NEG_INF = -1e30
 
 
@@ -142,8 +144,8 @@ def _flash_kernel_paged(
 
 def flash_attention_pallas_paged(
     q: jax.Array,            # [BH, Sq, D] (GQA-folded, row-major (b, kv, rep))
-    pool_k: jax.Array,       # [num_pages + 1, P, KV, D] — last page = trash
-    pool_v: jax.Array,       # [num_pages + 1, P, KV, D]
+    pool_k: jax.Array,       # [KV, num_pages + 1, P, D] — last page = trash
+    pool_v: jax.Array,       # [KV, num_pages + 1, P, D]
     table: jax.Array,        # [B, pages_per_slot] int32, trash-clamped (≥ 0)
     *,
     scale: float,
@@ -161,9 +163,13 @@ def flash_attention_pallas_paged(
     gather is ever materialized.  ``block_k`` is pinned to the page size so
     each KV grid step maps 1:1 onto one physical page: the K/V index maps
     read ``table[b, j]`` from the scalar-prefetch operand and point the DMA
-    at that page of the pool (kv-head axis indexed per folded row)."""
+    at that page of the pool (kv-head axis indexed per folded row).
+
+    The pool is kv-head-major, so one (head, page) block is a contiguous
+    ``[P, D]`` tile: its last two dims are the pool's own, which is what the
+    TPU's (8, 128) block rule accepts for any page size and head_dim."""
     bh, sq, d = q.shape
-    page_tokens = pool_k.shape[1]
+    page_tokens = pool_k.shape[2]
     pages_per_slot = table.shape[1]
     assert sq % block_q == 0, (sq, block_q)
     assert bh == table.shape[0] * kv_heads * rep, (bh, table.shape, kv_heads, rep)
@@ -187,13 +193,13 @@ def flash_attention_pallas_paged(
     )
 
     def _kv_spec():
-        # block (1, P, 1, D): index maps pick (physical page, 0, kv head, 0)
+        # block (1, 1, P, D): index maps pick (kv head, physical page, 0, 0)
         # — tbl is the third scalar-prefetch ref, available at
         # DMA-schedule time exactly like the (offs, lens) rows
         return pl.BlockSpec(
-            (None, page_tokens, None, d),
+            (None, None, page_tokens, d),
             lambda b, i, j, offs, lens, tbl: (
-                tbl[b // (kv_heads * rep), j], 0, (b // rep) % kv_heads, 0
+                (b // rep) % kv_heads, tbl[b // (kv_heads * rep), j], 0, 0
             ),
         )
 

@@ -1,10 +1,10 @@
 """jit'd wrapper: model-layout [B,S,H,D] GQA attention on the flash kernel.
 
 Handles GQA head grouping (queries of one KV head's group are folded into
-the batch·kv_head axis — KV is streamed once per group, never repeated),
-head-dim padding to the 128-lane boundary, and sequence padding to block
-multiples.  On CPU the kernel runs in interpret mode (correctness path);
-on TPU it compiles to the real blockwise kernel.
+the batch·kv_head axis) and sequence padding to block multiples; blocks
+span the whole head_dim, which the TPU accepts for any head_dim.  On CPU
+the kernel runs in interpret mode (correctness path); on TPU it compiles
+to the real blockwise kernel (see ``repro.kernels.resolve_interpret``).
 
 Ragged decode batches are supported through ``q_pos``: a per-row position
 operand ([B] or [B, Sq]) makes every batch row mask against its own cache
@@ -26,16 +26,14 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import resolve_interpret
+
 from .kernel import (
     DEFAULT_BK,
     DEFAULT_BQ,
     flash_attention_pallas,
     flash_attention_pallas_paged,
 )
-
-
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 @functools.partial(
@@ -62,8 +60,7 @@ def flash_attention(
     q_offset: int = 0,
     interpret: Optional[bool] = None,
 ) -> jax.Array:
-    if interpret is None:
-        interpret = _interpret_default()
+    interpret = resolve_interpret(interpret)
     del k_pos  # affine by construction (cache rows 0..Sk-1); masking uses q_pos
     b, sq, h, d = q.shape
     sk, kv = k.shape[1], k.shape[2]
@@ -90,12 +87,8 @@ def flash_attention(
         v.transpose(0, 2, 1, 3)[:, :, None], (b, kv, rep, sk, d)
     ).reshape(b * kv * rep, sk, d)
 
-    # pad head_dim to the 128-lane boundary, sequences to block multiples
-    dp = (-d) % 128
-    if dp:
-        qk = jnp.pad(qk, ((0, 0), (0, 0), (0, dp)))
-        kk = jnp.pad(kk, ((0, 0), (0, 0), (0, dp)))
-        vk = jnp.pad(vk, ((0, 0), (0, 0), (0, dp)))
+    # pad sequences to block multiples; every block spans the full head_dim
+    # (legal on the TPU for any head_dim), so head_dim is never padded
     bq = min(DEFAULT_BQ, max(8, sq))
     bk = min(DEFAULT_BK, max(8, sk))
     sqp = (-sq) % bq
@@ -119,7 +112,7 @@ def flash_attention(
         block_k=bk,
         interpret=interpret,
     )
-    out = out[:, :sq, :d]
+    out = out[:, :sq]
     return out.reshape(b, kv, rep, sq, d).transpose(0, 3, 1, 2, 4).reshape(b, sq, h, d)
 
 
@@ -129,8 +122,8 @@ def flash_attention(
 )
 def flash_attention_paged(
     q: jax.Array,            # [B, Sq, H, D]
-    pool_k: jax.Array,       # [num_pages + 1, P, KV, D] — last page = trash
-    pool_v: jax.Array,       # [num_pages + 1, P, KV, D]
+    pool_k: jax.Array,       # [KV, num_pages + 1, P, D] — last page = trash
+    pool_v: jax.Array,       # [KV, num_pages + 1, P, D]
     table: jax.Array,        # [B, pages_per_slot] int32 (−1 = unmapped)
     q_pos: Optional[jax.Array] = None,   # [Sq] or [B, Sq] query positions
     q_lens: Optional[jax.Array] = None,  # [B] valid query rows per batch row
@@ -152,12 +145,11 @@ def flash_attention_paged(
     ``num_pages``; whatever garbage it holds sits at logical positions
     beyond every row's written span, where the causal mask already
     guarantees exact zero attention weight."""
-    if interpret is None:
-        interpret = _interpret_default()
+    interpret = resolve_interpret(interpret)
     b, sq, h, d = q.shape
-    kv = pool_k.shape[2]
+    kv = pool_k.shape[0]
     rep = h // kv
-    num_pages = pool_k.shape[0] - 1
+    num_pages = pool_k.shape[1] - 1
     tbl = jnp.where(table >= 0, table, num_pages).astype(jnp.int32)
 
     if q_pos is None:
@@ -176,14 +168,8 @@ def flash_attention_paged(
         b * kv * rep, sq, d
     )
 
-    # pad head_dim to the 128-lane boundary (pools included — on TPU the
-    # pool would be stored pre-padded; here the pad is the correctness
-    # path's price), queries to a block multiple
-    dp = (-d) % 128
-    if dp:
-        qk = jnp.pad(qk, ((0, 0), (0, 0), (0, dp)))
-        pool_k = jnp.pad(pool_k, ((0, 0), (0, 0), (0, 0), (0, dp)))
-        pool_v = jnp.pad(pool_v, ((0, 0), (0, 0), (0, 0), (0, dp)))
+    # pad queries to a block multiple; head_dim is never padded — every
+    # block spans the full head_dim, so the pools are read in place
     bq = min(DEFAULT_BQ, max(8, sq))
     sqp = (-sq) % bq
     if sqp:
@@ -202,7 +188,7 @@ def flash_attention_paged(
         block_q=bq,
         interpret=interpret,
     )
-    out = out[:, :sq, :d]
+    out = out[:, :sq]
     return out.reshape(b, kv, rep, sq, d).transpose(0, 3, 1, 2, 4).reshape(
         b, sq, h, d
     )

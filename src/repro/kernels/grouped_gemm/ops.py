@@ -14,11 +14,9 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import resolve_interpret
+
 from .kernel import BLOCK_M, grouped_gemm_pallas
-
-
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "block_m"))
@@ -30,8 +28,7 @@ def grouped_gemm(
     block_m: int = BLOCK_M,
     interpret: Optional[bool] = None,
 ) -> jax.Array:
-    if interpret is None:
-        interpret = _interpret_default()
+    interpret = resolve_interpret(interpret)
     m, k = x.shape
     e, _, n = w.shape
 

@@ -8,11 +8,9 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import resolve_interpret
+
 from .kernel import ssd_scan_pallas
-
-
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
@@ -26,8 +24,7 @@ def ssd_scan(
     chunk: int = 128,
     interpret: Optional[bool] = None,
 ) -> Tuple[jax.Array, jax.Array]:
-    if interpret is None:
-        interpret = _interpret_default()
+    interpret = resolve_interpret(interpret)
     bsz, s, h, p = x.shape
     g, n = b.shape[2], b.shape[3]
     pad = (-s) % chunk

@@ -44,7 +44,6 @@ from repro.core.costmodel import (
     DEFAULT_BATCH_INVARIANT_FRAC,
     calibrate_invariant_frac,
 )
-from repro.launch.roofline import cost_analysis_dict
 
 # decode-step working sizes: big enough that byte counts dominate compiler
 # bookkeeping noise, small enough to compile instantly on CPU
@@ -123,7 +122,7 @@ OPS: Dict[str, Callable[[int], Tuple[Callable, tuple]]] = {
 def measured_bytes(cls: str, batch: int) -> float:
     fn, args = OPS[cls](batch)
     compiled = jax.jit(fn).lower(*args).compile()
-    ca = cost_analysis_dict(compiled.cost_analysis())
+    ca = compiled.cost_analysis()
     return float(ca.get("bytes accessed", 0.0))
 
 

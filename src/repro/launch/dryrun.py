@@ -37,7 +37,6 @@ from repro.configs.base import ModelConfig, ShapeConfig
 from repro.launch.mesh import make_production_mesh
 from repro.launch.roofline import (
     collective_bytes_from_hlo,
-    cost_analysis_dict,
     roofline_terms,
 )
 from repro.models.model import active_param_count, build_model, param_count_shape
@@ -211,7 +210,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, *, verbose: bool = Tru
         compiled = lowered.compile()
 
     mem = compiled.memory_analysis()
-    cost = cost_analysis_dict(compiled.cost_analysis())
+    cost = compiled.cost_analysis()
     hlo = compiled.as_text()
     coll = collective_bytes_from_hlo(hlo)
     n_dev = mesh.size

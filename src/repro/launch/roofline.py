@@ -18,17 +18,6 @@ from typing import Dict
 
 from repro.configs.base import ModelConfig, ShapeConfig
 
-
-def cost_analysis_dict(cost) -> Dict[str, float]:
-    """Normalize ``compiled.cost_analysis()`` across jax versions.
-
-    Older jax returns one dict; jax ≥ 0.4.3x returns a LIST with one dict
-    per executable program (a single entry for an unrolled module); either
-    may be None.  Always returns a plain dict."""
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else None
-    return dict(cost) if cost else {}
-
 # TPU v5e hardware constants (per chip)
 PEAK_FLOPS_BF16 = 197e12
 HBM_BW = 819e9

@@ -45,7 +45,6 @@ from repro.launch.dryrun import (
 from repro.launch.mesh import make_production_mesh
 from repro.launch.roofline import (
     collective_bytes_from_hlo,
-    cost_analysis_dict,
     roofline_terms,
 )
 from repro.models.model import active_param_count, build_model, param_count_shape
@@ -64,7 +63,7 @@ ARTIFACTS = Path(__file__).resolve().parents[3] / "benchmarks" / "artifacts" / "
 
 def _measure(lowered):
     compiled = lowered.compile()
-    cost = cost_analysis_dict(compiled.cost_analysis())
+    cost = compiled.cost_analysis()
     coll = collective_bytes_from_hlo(compiled.as_text())
     return {
         "flops": float(cost.get("flops", 0.0)),

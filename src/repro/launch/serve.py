@@ -3,6 +3,12 @@
     PYTHONPATH=src python -m repro.launch.serve --arch llama3.2-1b --smoke \
         --requests 8 --method moirai
 
+Without ``--smoke`` the model is served at its published widths (on a
+TPU).  The serving variant of the config (``ModelConfig.serving``) holds
+per-layer params and attends with the Pallas kernel (interpret mode off the
+TPU).  The planner sees the chips ``jax.devices()`` reports, with the peaks
+of their ``device_kind`` (``core.devices.CHIP_PEAKS``).
+
 The engine runs with the adaptive observe → derate → replan loop closed
 (an observation window every ``--adapt-every`` decode steps; ``0`` disables
 it).  After the run the CLI prints the straggler report, every adaptation
@@ -17,6 +23,9 @@ per-replica engines.  ``--replicas 1`` (the default) is the single-engine
 path above, verbatim.  With replicas the CLI additionally prints the
 service plan, the router's event log (submits, dispatches, drains, replica
 spawns) and the per-tier latency report.
+
+In a run with no fault schedule, a crashed replica or a request that ends
+without all its tokens makes the CLI exit non-zero.
 
 ``--fault-schedule PATH`` replays a chaos scenario
 (:class:`repro.serving.faults.FaultSchedule` JSON — write one with
@@ -34,11 +43,34 @@ import time
 import jax
 
 from repro.configs import get_config
-from repro.core.devices import tpu_slice_cluster
+from repro.core.devices import device_cluster
 from repro.core.placement import PlanConfig
+from repro.launch.compile_cache import setup_compile_cache
 from repro.models.model import build_model
 from repro.serving.adaptation import AdaptationConfig
 from repro.serving.engine import Request, ServingEngine
+
+
+def unserved(reqs):
+    """Requests that did not finish with every token they asked for."""
+    return [
+        r for r in reqs
+        if r.state != "finished" or len(r.out_tokens) != r.max_new_tokens
+    ]
+
+
+def _check_served(args, reqs, crashed: int = 0):
+    """Without a fault schedule every request must be served and no replica
+    may crash: exit non-zero otherwise."""
+    if args.fault_schedule:
+        return
+    bad = unserved(reqs)
+    if bad or crashed:
+        raise SystemExit(
+            f"[serve] FAILED: {len(bad)} request(s) unserved "
+            f"({[(r.rid, r.state, len(r.out_tokens)) for r in bad[:8]]}), "
+            f"{crashed} replica crash(es)"
+        )
 
 
 def _serve_replicas(args, cfg, params, cluster, plan_cfg):
@@ -131,6 +163,7 @@ def _serve_replicas(args, cfg, params, cluster, plan_cfg):
                 f"[chaos]   s{entry['clock']:<4d} {e['kind']:<14s} "
                 f"target={tgt} -> {entry['status']}"
             )
+    _check_served(args, reqs, crashed=stats["counters"]["crashed_replicas"])
 
 
 def main(argv=None):
@@ -142,7 +175,11 @@ def main(argv=None):
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--max-len", type=int, default=256)
     ap.add_argument("--method", default="moirai")
-    ap.add_argument("--heterogeneous", action="store_true", default=True)
+    ap.add_argument(
+        "--heterogeneous", action="store_true",
+        help="plan as if every odd chip ran at half speed (a mixed-"
+        "generation fleet); off by default",
+    )
     ap.add_argument(
         "--adapt-every", type=int, default=16,
         help="decode steps per adaptation observation window (0 = off; "
@@ -253,20 +290,27 @@ def main(argv=None):
     )
     ap.add_argument(
         "--cluster-size", type=int, default=None, metavar="K",
-        help="devices in the modeled cluster (default: number of visible "
-        "accelerators; raise it to plan multi-replica services on clusters "
-        "bigger than this host)",
+        help="chips in the modeled cluster (default: the devices jax "
+        "reports; raise it to plan multi-replica services on a fleet of "
+        "such chips bigger than this host)",
     )
     args = ap.parse_args(argv)
 
+    cache_dir = setup_compile_cache()
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = cfg.smoke()
+    cfg = cfg.serving()
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(0))
-    k = args.cluster_size or max(len(jax.devices()), 1)
-    cluster = tpu_slice_cluster(
-        n_slices=k, heterogeneous=args.heterogeneous
+    devices = jax.devices()
+    cluster = device_cluster(
+        devices, args.cluster_size, heterogeneous=args.heterogeneous
+    )
+    print(
+        f"[serve] devices: {len(devices)}x {devices[0].device_kind} "
+        f"({devices[0].platform}); planning on {cluster.name}; "
+        f"compile cache {cache_dir}"
     )
     plan_cfg = PlanConfig(
         method=args.method, time_limit=20, mip_rel_gap=0.05,
@@ -293,6 +337,7 @@ def main(argv=None):
         draft_cfg = get_config(args.draft)
         if args.smoke:
             draft_cfg = draft_cfg.smoke()
+        draft_cfg = draft_cfg.serving()
         draft_model = build_model(draft_cfg)
         draft_kw = dict(
             draft_cfg=draft_cfg,
@@ -413,6 +458,7 @@ def main(argv=None):
                 f"[chaos]   s{entry['clock']:<4d} {e['kind']:<14s} "
                 f"target={tgt} -> {entry['status']}"
             )
+    _check_served(args, reqs)
 
 
 if __name__ == "__main__":

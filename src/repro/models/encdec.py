@@ -230,7 +230,7 @@ def init_paged_self_cache(cfg: ModelConfig, num_pages: int, page_tokens: int):
     the reserved trash page); the page table is passed per call."""
     hd = cfg.resolved_head_dim
     dt = _dtype(cfg)
-    shape = (cfg.n_layers, num_pages + 1, page_tokens, cfg.n_kv_heads, hd)
+    shape = (cfg.n_layers, cfg.n_kv_heads, num_pages + 1, page_tokens, hd)
     return {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
 
 

@@ -8,7 +8,8 @@ M-RoPE (qwen2-vl), and three implementations:
 * ``naive``   — materializes the [S, S] score matrix (oracle / small tests),
 * ``chunked`` — lax.scan over KV blocks with online softmax (flash-attention
   algorithm in pure jnp; memory-safe at 32k+ and what the dry-run lowers),
-* ``pallas``  — the TPU kernel in repro.kernels (validated in interpret mode).
+* ``pallas``  — the TPU kernel in repro.kernels (interpret mode off the TPU);
+  it specializes on the layer window, which must be a static int.
 
 All three accept **ragged decode batches**: a ``(B,)`` ``cache_pos`` vector
 gives every batch row its own KV write index and causal mask over its own
@@ -226,15 +227,17 @@ def _chunked_attention(q, k, v, q_pos, k_pos, *, causal, window, cap, scale, chu
 def _paged_update(pool_k, pool_v, table, k, v, cache_pos, q_lens):
     """Scatter this call's new K/V into the page pool through ``table``.
 
-    Flat-pool indexing: token ``i`` of row ``b`` lands at physical position
-    ``table[b, pos // P] * P + pos % P`` with ``pos = cache_pos[b] + i``.
+    Pools are kv-head-major, ``[KV, num_pages + 1, P, hd]``.  Flat-pool
+    indexing: token ``i`` of row ``b`` lands at physical position
+    ``table[b, pos // P] * P + pos % P`` of every head's pool, with
+    ``pos = cache_pos[b] + i``.
     Rows/tokens outside their valid span (``i ≥ q_lens[b]``), and any
     unmapped table entry, are redirected to the reserved TRASH page (the
     pool's last page) — the scatter can therefore never corrupt a real
     page, which is what lets idle rows of a fused mixed batch ride along.
     """
     b, sq = k.shape[0], k.shape[1]
-    np1, page_tokens = pool_k.shape[0], pool_k.shape[1]
+    kv, np1, page_tokens, hd = pool_k.shape
     pages_per_slot = table.shape[1]
     trash = np1 - 1
     cp = jnp.broadcast_to(jnp.asarray(cache_pos, jnp.int32), (b,))
@@ -250,11 +253,14 @@ def _paged_update(pool_k, pool_v, table, k, v, cache_pos, q_lens):
     page = jnp.take_along_axis(table, pidx, axis=1)            # [B, Sq]
     page = jnp.where(valid & (page >= 0), page, trash)
     dest = (page * page_tokens + pos % page_tokens).reshape(-1)
-    flat = (np1 * page_tokens,) + pool_k.shape[2:]
-    item = (b * sq,) + pool_k.shape[2:]
-    pk = pool_k.reshape(flat).at[dest].set(k.astype(pool_k.dtype).reshape(item))
-    pv = pool_v.reshape(flat).at[dest].set(v.astype(pool_v.dtype).reshape(item))
-    return pk.reshape(pool_k.shape), pv.reshape(pool_v.shape)
+    flat = (kv, np1 * page_tokens, hd)
+
+    def put(pool, new):
+        # [B, Sq, KV, hd] → [KV, B·Sq, hd], scattered along the token axis
+        item = new.astype(pool.dtype).reshape(b * sq, kv, hd).transpose(1, 0, 2)
+        return pool.reshape(flat).at[:, dest].set(item).reshape(pool.shape)
+
+    return put(pool_k, k), put(pool_v, v)
 
 
 def _paged_view(pool, table):
@@ -263,14 +269,14 @@ def _paged_view(pool, table):
     through the table directly and never materializes this).  Unmapped
     entries resolve to the trash page — garbage, but always causally masked
     (they sit beyond every row's written span)."""
-    np1, page_tokens = pool.shape[0], pool.shape[1]
+    kv, np1, page_tokens, hd = pool.shape
     trash = np1 - 1
     pages_per_slot = table.shape[1]
     t = jnp.arange(pages_per_slot * page_tokens, dtype=jnp.int32)
     pages = jnp.where(table >= 0, table, trash)
     src = pages[:, t // page_tokens] * page_tokens + (t % page_tokens)[None, :]
-    flat = pool.reshape((np1 * page_tokens,) + pool.shape[2:])
-    return jnp.take(flat, src, axis=0)                         # [B, L, KV, D]
+    flat = pool.reshape((kv, np1 * page_tokens, hd))
+    return jnp.take(flat, src, axis=1).transpose(1, 2, 0, 3)   # [B, L, KV, D]
 
 
 def multihead_attention(
@@ -325,7 +331,7 @@ def multihead_attention(
     q = shard_hint(q, "batch", None, "heads", None)
 
     new_cache = None
-    # paged cache: {"k","v": [num_pages+1, P, KV, hd] pool, "table": [B, pps]}
+    # paged cache: {"k","v": [KV, num_pages+1, P, hd] pool, "table": [B, pps]}
     paged = kv_cache is not None and cross_kv is None and "table" in kv_cache
     if paged:
         pool_k, pool_v, table = kv_cache["k"], kv_cache["v"], kv_cache["table"]
@@ -335,7 +341,7 @@ def multihead_attention(
         # logical cache depth = pages_per_slot · page_tokens; reads beyond a
         # row's written span hit stale/trash data that the causal-vs-q_pos
         # mask already zeroes, same as unwritten dense rows
-        k_pos1d = jnp.arange(table.shape[1] * pool_k.shape[1])
+        k_pos1d = jnp.arange(table.shape[1] * pool_k.shape[2])
         causal = True
     elif kv_cache is not None and cross_kv is None:
         # decode / incremental prefill: write new kv at cache_pos
@@ -381,7 +387,7 @@ def multihead_attention(
         if causal is None:
             causal = cross_kv is None
 
-    g = pool_k.shape[2] if paged else k.shape[2]
+    g = pool_k.shape[0] if paged else k.shape[2]
     n_rep = cfg.n_heads // g
     qg = q.reshape(b, sq, g, n_rep, hd)   # GQA grouping — KV is never repeated
 
@@ -389,18 +395,17 @@ def multihead_attention(
     window = layer_window
     impl = cfg.attention_impl
     if impl == "pallas":
-        # the kernel specializes on the window at trace time; a traced
-        # per-layer window (layer-scan xs) cannot be static — fall back to
-        # the pure-jnp path for that call
-        try:
-            static_window = None if window is None else int(window)
-        except (TypeError, jax.errors.TracerArrayConversionError,
-                jax.errors.ConcretizationTypeError):
+        # the kernel specializes on the window at trace time, so it must be a
+        # static int: a traced window (a layer-scan xs) is a caller error
+        if isinstance(window, jax.core.Tracer):
+            raise TypeError(
+                "attention_impl='pallas' needs a static per-layer window; "
+                "got a traced value (serve per-layer params, "
+                "scan_layers=False)"
+            )
+        static_window = None if window is None else int(window)
+        if static_window is not None and static_window <= 0:
             static_window = None
-            impl = "chunked"
-        else:
-            if static_window is not None and static_window <= 0:
-                static_window = None
     if paged and impl != "pallas":
         # naive/chunked reference read path: gather the logical [B, L, KV, hd]
         # view out of the pool once, then reuse the dense mask logic unchanged

@@ -18,7 +18,7 @@ from __future__ import annotations
 
 import math
 from functools import partial
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -72,20 +72,21 @@ def layer_params(key, cfg: ModelConfig) -> Params:
     return p
 
 
-def _layer_windows(cfg: ModelConfig) -> jnp.ndarray:
-    """Per-layer attention window (traced through the layer scan).
+def layer_windows(cfg: ModelConfig) -> List[int]:
+    """Per-layer attention window, one Python int per layer.
 
     gemma2 alternates Local/Global; global layers get a huge window (≡ full
-    attention).  Uniform structure keeps the scan homogeneous."""
+    attention).  The ints stay static for the unrolled stack and the stage
+    executor (the Pallas kernel specializes on them); the layer scan turns
+    them into an int32 ``xs`` array."""
     big = 1 << 30
     if cfg.sliding_window is None:
-        return jnp.full((cfg.n_layers,), big, dtype=jnp.int32)
+        return [big] * cfg.n_layers
     pattern = cfg.local_global_pattern or "LG"
-    win = [
+    return [
         cfg.sliding_window if pattern[i % len(pattern)] == "L" else big
         for i in range(cfg.n_layers)
     ]
-    return jnp.asarray(win, dtype=jnp.int32)
 
 
 def block_apply(
@@ -94,7 +95,8 @@ def block_apply(
     cfg: ModelConfig,
     *,
     positions: jax.Array,
-    window: jax.Array,                         # scalar int32 (traced)
+    window,                                    # int, or int32 scalar traced
+                                               # through the layer scan
     kv_cache: Optional[Dict[str, jax.Array]] = None,
     cache_pos: Optional[jax.Array] = None,
     q_lens: Optional[jax.Array] = None,        # [B] fused-batch valid rows
@@ -206,9 +208,9 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int) -> Params:
 def init_paged_cache(
     cfg: ModelConfig, batch: int, num_pages: int, page_tokens: int
 ) -> Params:
-    """Paged-KV cache: per-layer physical page pools ``[num_pages+1, P, KV,
-    hd]`` (the +1 is the reserved TRASH page absorbing masked/out-of-range
-    writes).  The per-slot page table is NOT part of this pytree — it is
+    """Paged-KV cache: per-layer physical page pools ``[KV, num_pages+1, P,
+    hd]``, kv-head-major (the +1 is the reserved TRASH page absorbing
+    masked/out-of-range writes).  The per-slot page table is NOT part of this pytree — it is
     host-owned (``serving.kv_pool.KVPool``) and rides into each forward call
     as the ``page_table`` operand, so prefix-shared pages can be remapped
     between steps without touching device pools.
@@ -217,7 +219,7 @@ def init_paged_cache(
     the hybrid family pages only its shared-attention KV."""
     dt = _dtype(cfg)
     hd = cfg.resolved_head_dim
-    pool_shape = (num_pages + 1, page_tokens, cfg.n_kv_heads, hd)
+    pool_shape = (cfg.n_kv_heads, num_pages + 1, page_tokens, hd)
 
     if cfg.family == "ssm":
         return init_cache(cfg, batch, 0)
@@ -281,7 +283,7 @@ def _dense_stack(
     params, x, cfg, positions, caches, cache_pos, q_lens=None, page_table=None
 ):
     """Scan (or loop) over transformer layers; returns (x, new_caches, aux)."""
-    windows = _layer_windows(cfg)
+    windows = layer_windows(cfg)
 
     def one(x, layer_p, window, cache):
         # paged KV: the table is one [B, pages_per_slot] array shared by all
@@ -306,9 +308,10 @@ def _dense_stack(
             return x, (new_cache, aux)
 
         body_fn = jax.checkpoint(body) if cfg.remat else body
-        xs = (params["layers"], windows, caches["layers"] if caches else None)
+        win = jnp.asarray(windows, jnp.int32)
+        xs = (params["layers"], win, caches["layers"] if caches else None)
         if caches is None:
-            xs = (params["layers"], windows)
+            xs = (params["layers"], win)
 
             def body_nc(x, xs):
                 layer_p, window = xs
@@ -322,7 +325,6 @@ def _dense_stack(
         return x, {"layers": new_caches}, auxs.sum()
 
     # unrolled python loop (smoke / tiny configs / FD roofline compiles)
-    one_fn = jax.checkpoint(one) if cfg.remat else one
     new_layers = {"k": [], "v": []} if caches else None
     aux_total = jnp.zeros((), jnp.float32)
     for i, layer_p in enumerate(params["layers"]):
@@ -331,7 +333,12 @@ def _dense_stack(
             if caches
             else None
         )
-        x, nc, aux = one_fn(x, layer_p, windows[i], cache_i)
+        # the window is closed over, not an argument: it stays a static int
+        # under remat as well
+        one_fn = lambda x_, p_, c_, w=windows[i]: one(x_, p_, w, c_)
+        if cfg.remat:
+            one_fn = jax.checkpoint(one_fn)
+        x, nc, aux = one_fn(x, layer_p, cache_i)
         aux_total = aux_total + aux
         if caches:
             new_layers["k"].append(nc["k"])
@@ -435,10 +442,9 @@ def _hybrid_stack(
         )
         if cache_i is not None and page_table is not None:
             cache_i["table"] = page_table
-        big = jnp.asarray(1 << 30, jnp.int32)
         u, nc, _ = block_apply(
             params["shared_block"], u, cfg,
-            positions=positions, window=big,
+            positions=positions, window=1 << 30,
             kv_cache=cache_i, cache_pos=attn_pos, q_lens=q_lens,
         )
         x = x + u

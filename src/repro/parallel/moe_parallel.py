@@ -33,24 +33,6 @@ from repro.configs.base import ModelConfig
 from repro.models.moe import router_topk
 from repro.parallel.context import ParallelContext
 
-# jax ≥ 0.6 exposes shard_map at the top level; 0.4.x ships it under
-# jax.experimental.  The replication-check kwarg was renamed check_rep →
-# check_vma in a DIFFERENT release than the top-level promotion, so the
-# kwarg is chosen from the actual signature, not from where the symbol lives.
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-import inspect as _inspect
-
-_NO_REP_CHECK = (
-    {"check_vma": False}
-    if "check_vma" in _inspect.signature(_shard_map).parameters
-    else {"check_rep": False}
-)
-
-
 def _round_up(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
 
@@ -191,11 +173,11 @@ def moe_ep(
         aux = jax.lax.pmean(aux, dp)
         return y_tok.reshape(b_loc, s, d).astype(xl.dtype), aux
 
-    y, aux = _shard_map(
+    y, aux = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(), w_spec, w_spec, w2_spec, x_spec),
         out_specs=(x_spec, P()),
-        **_NO_REP_CHECK,
+        check_vma=False,
     )(p["router"], p["w_gate"], p["w_up"], p["w_down"], x)
     return y, aux

@@ -113,6 +113,11 @@ from .kv_pool import KVPool
 from .stage_executor import StageExecutor, stages_from_placement, stats_from_times
 
 
+class DeviceLost(RuntimeError):
+    """An engine lost the last device it could serve on: the replica is
+    gone, and its router retries its requests elsewhere."""
+
+
 @dataclass
 class Request:
     """One generation request.
@@ -1565,6 +1570,8 @@ class ServingEngine:
         if device_idx in self.failed_devices or not 0 <= device_idx < self.cluster.k:
             raise ValueError(f"bad or already-failed device {device_idx}")
         self.failed_devices.append(device_idx)
+        if len(self.failed_devices) == self.cluster.k:
+            raise DeviceLost(f"device {device_idx} failed: no device left")
         # a dead device needs no derate — drop it from the applied maps AND
         # from the policy, or the next committed factor change would
         # resurrect the dead device's derate into engine state.  Channels

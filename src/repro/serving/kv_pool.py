@@ -28,10 +28,10 @@ Invariants (property-tested in ``tests/test_paged_kv.py``):
   * ``free + lru + in_use`` always partitions the pool.
 
 The device-side layout contract (see ``models/layers.py`` and
-``kernels/flash_attention``): pools are ``[num_pages + 1, page_tokens, KV,
-head_dim]`` per layer — index ``num_pages`` is the reserved TRASH page that
-absorbs masked writes — and the flat token index of logical position ``t``
-of slot ``b`` is ``table[b, t // P] * P + t % P``.
+``kernels/flash_attention``): pools are ``[KV, num_pages + 1, page_tokens,
+head_dim]`` per layer, kv-head-major — page index ``num_pages`` is the
+reserved TRASH page that absorbs masked writes — and the flat token index
+of logical position ``t`` of slot ``b`` is ``table[b, t // P] * P + t % P``.
 """
 
 from __future__ import annotations

@@ -69,7 +69,7 @@ from collections import deque
 from dataclasses import dataclass, replace
 from typing import Any, Callable, Deque, Dict, List, Optional, Sequence, Tuple
 
-from repro.serving.engine import Request, ServingEngine
+from repro.serving.engine import DeviceLost, Request, ServingEngine
 
 
 @dataclass
@@ -569,7 +569,7 @@ class Router:
             target = f"device {dev}"
         try:
             status = rep.engine.apply_fault(local)
-        except Exception as e:   # the fault killed the replica outright
+        except DeviceLost as e:   # the fault killed the replica outright
             self._crash_replica(rep, reason=f"{ev.kind} on {target}: {e}")
             return f"{rep.name}: crashed ({e})"
         self._log(
@@ -594,7 +594,7 @@ class Router:
             return
         try:
             engine = self.engine_factory(sorted(healthy))
-        except Exception as e:  # pool can't host a replica (e.g. memory)
+        except DeviceLost as e:  # the pool lost its devices meanwhile
             self._log("replan_failed", error=str(e), pool=healthy)
             return
         name = f"replica{self._next_replica_id}"
@@ -665,7 +665,7 @@ class Router:
                 continue
             try:
                 rep.engine.step()
-            except Exception as e:   # a mid-step loss the engine can't absorb
+            except DeviceLost as e:   # a mid-step loss the engine can't absorb
                 self._crash_replica(rep, reason=f"engine step failed: {e}")
                 continue
             self._stream(rep)

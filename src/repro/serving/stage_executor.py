@@ -88,7 +88,7 @@ class StageExecutor:
         assert not cfg.scan_layers, "serving executor expects per-layer params"
         self.cfg = cfg
         self.stages = stages
-        self._windows = transformer._layer_windows(cfg)
+        self._windows = transformer.layer_windows(cfg)
         self._place_params(params)
         # bounded: a long-lived executor must not retain every forward's
         # timing forever (the adaptation loop drains these per window anyway).
@@ -126,9 +126,9 @@ class StageExecutor:
     def _stage_fn(self, si: int):
         cfg = self.cfg
         st = self.stages[si]
-        windows = [int(self._windows[i]) for i in st.layer_ids]
+        windows = [self._windows[i] for i in st.layer_ids]
 
-        def run(sp, x, positions, caches, cache_pos, q_lens=None, table=None):
+        def run(sp, x, caches, positions, cache_pos, q_lens=None, table=None):
             new_caches = []
             if st.first:
                 tokens = x
@@ -144,7 +144,7 @@ class StageExecutor:
                 x, nc, _ = transformer.block_apply(
                     layer_p, x, cfg,
                     positions=positions,
-                    window=jnp.asarray(windows[j], jnp.int32),
+                    window=windows[j],   # static: the kernel specializes on it
                     kv_cache=cache_j,
                     cache_pos=cache_pos,
                     q_lens=q_lens,
@@ -160,6 +160,42 @@ class StageExecutor:
 
         # computation follows its (committed) inputs' device placement
         return jax.jit(run)
+
+    def _fn(self, si: int):
+        fn = self._fns.get(si)
+        if fn is None:
+            fn = self._fns[si] = self._stage_fn(si)
+        return fn
+
+    @staticmethod
+    def _step_inputs(shape, cache_pos, q_lens, page_table):
+        """The per-step operands every stage shares: (positions, cache_pos,
+        q_lens, page_table) as device int32 arrays."""
+        b, s = shape[:2]
+        cp = jnp.asarray(0 if cache_pos is None else cache_pos, jnp.int32)
+        # per-row positions: row b decodes at depth cp[b] (scalar cp → all
+        # rows share one depth, the classic lockstep batch)
+        positions = jnp.arange(s, dtype=jnp.int32)[None] + (
+            cp[:, None] if cp.ndim else cp
+        )
+        positions = jnp.broadcast_to(positions, (b, s))
+        ql = None if q_lens is None else jnp.asarray(q_lens, jnp.int32)
+        tbl = None if page_table is None else jnp.asarray(page_table, jnp.int32)
+        return positions, cp, ql, tbl
+
+    def trace_stage(
+        self, si: int, x, caches=None, cache_pos=None, *,
+        q_lens=None, page_table=None,
+    ):
+        """Stage ``si``'s jitted program traced for these step inputs, as
+        :meth:`forward` would run it (``x``: the stage's input, tokens for
+        stage 0).  Its ``.jaxpr`` and ``.lower()`` show what runs, e.g.
+        whether the attention kernel is on the path."""
+        st_caches = caches[si] if caches is not None else None
+        return self._fn(si).trace(
+            self.stage_params[si], x, st_caches,
+            *self._step_inputs(x.shape, cache_pos, q_lens, page_table),
+        )
 
     # ------------------------------------------------------------------
     def init_caches(self, batch: int, max_len: int):
@@ -183,13 +219,13 @@ class StageExecutor:
         return caches
 
     def init_paged_caches(self, num_pages: int, page_tokens: int):
-        """Paged-KV pools: per stage, per layer, ``[num_pages+1, P, KV, hd]``
-        on that stage's device (the +1 is the reserved trash page).  The
-        page table is host-owned (``serving.kv_pool.KVPool``) and rides into
-        :meth:`forward` as ``page_table`` each step."""
+        """Paged-KV pools: per stage, per layer, ``[KV, num_pages+1, P, hd]``
+        (kv-head-major) on that stage's device (the +1 is the reserved trash
+        page).  The page table is host-owned (``serving.kv_pool.KVPool``)
+        and rides into :meth:`forward` as ``page_table`` each step."""
         hd = self.cfg.resolved_head_dim
         dt = jnp.dtype(self.cfg.dtype)
-        shape = (num_pages + 1, page_tokens, self.cfg.n_kv_heads, hd)
+        shape = (self.cfg.n_kv_heads, num_pages + 1, page_tokens, hd)
         caches = []
         for st in self.stages:
             caches.append([
@@ -213,7 +249,10 @@ class StageExecutor:
         out = []
         for st_caches in caches:
             out.append([
-                {key: c[key].at[dst].set(c[key][src]) for key in ("k", "v")}
+                {
+                    key: c[key].at[:, dst].set(c[key][:, src])
+                    for key in ("k", "v")
+                }
                 for c in st_caches
             ])
         return out
@@ -239,34 +278,20 @@ class StageExecutor:
         page_table=None,              # [B, pages_per_slot] int32 — paged-KV
                                       # table (caches hold page pools)
     ):
-        b, s = tokens.shape
         if kind is None:
-            kind = "prefill" if s > 1 else "decode"
+            kind = "prefill" if tokens.shape[1] > 1 else "decode"
         elif kind not in ("decode", "prefill", "fused"):
             raise ValueError(
                 f"kind must be 'decode', 'prefill' or 'fused', got {kind!r}"
             )
-        cp = jnp.asarray(0 if cache_pos is None else cache_pos, jnp.int32)
-        # per-row positions: row b decodes at depth cp[b] (scalar cp → all
-        # rows share one depth, the classic lockstep batch)
-        positions = jnp.arange(s, dtype=jnp.int32)[None] + (
-            cp[:, None] if cp.ndim else cp
-        )
-        positions = jnp.broadcast_to(positions, (b, s))
-        ql = None if q_lens is None else jnp.asarray(q_lens, jnp.int32)
-        tbl = None if page_table is None else jnp.asarray(page_table, jnp.int32)
+        step_in = self._step_inputs(tokens.shape, cache_pos, q_lens, page_table)
         x = tokens
         new_caches = []
         for si, st in enumerate(self.stages):
             t0 = time.perf_counter()
             x = jax.device_put(x, st.device)          # inter-stage data flow
-            fn = self._fns.get(si)
-            if fn is None:
-                fn = self._fns[si] = self._stage_fn(si)
             st_caches = caches[si] if caches is not None else None
-            x, nc = fn(
-                self.stage_params[si], x, positions, st_caches, cp, ql, tbl
-            )
+            x, nc = self._fn(si)(self.stage_params[si], x, st_caches, *step_in)
             x.block_until_ready()
             dt = time.perf_counter() - t0
             if kind == "fused":

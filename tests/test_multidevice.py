@@ -45,7 +45,8 @@ def test_moe_ep_matches_reference():
             n_experts=8, n_experts_padded=8, top_k=2, moe_d_ff=64, d_model=128,
             capacity_factor=8.0,   # no drops → exact match with the oracle
         )
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        from jax.sharding import AxisType
+        mesh = jax.make_mesh((4, 2), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
         ctx = ParallelContext(mesh, default_rules(False), ep_axes=("data",),
                               dp_axes=("data",), tp_axis="model")
         key = jax.random.PRNGKey(0)
@@ -136,7 +137,8 @@ def test_sharded_train_step_runs_on_debug_mesh():
 
         cfg = replace(get_config("llama3.2-1b").smoke(), d_model=128, n_heads=4,
                       n_kv_heads=4, head_dim=32, d_ff=256, vocab_size=512)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from jax.sharding import AxisType
+        mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
         ctx = ParallelContext(mesh, default_rules(False), ep_axes=("data",),
                               dp_axes=("data",), tp_axis="model")
         model = build_model(cfg)
@@ -176,7 +178,8 @@ def test_pure_dp_moe_train_step_runs():
         cfg = replace(get_config("qwen2-moe-a2.7b").smoke(),
                       n_experts=8, n_experts_padded=8, capacity_factor=8.0)
         assert cfg.prefer_pure_dp
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from jax.sharding import AxisType
+        mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
         B = 8
         assert pure_dp_active(cfg, mesh, B)
         ctx = make_context(mesh, cfg, B)
@@ -223,7 +226,8 @@ def test_elastic_resume_across_mesh_sizes(tmp_path):
     """)
     run_with_devices(4, common + f"""
         from repro.train.elastic import elastic_resume
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        from jax.sharding import AxisType
+        mesh = jax.make_mesh((2, 2), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
         step, params, opt = elastic_resume(cfg, mesh, {ckpt!r})
         assert step == 42
         # state is usable: run a forward pass under the new mesh

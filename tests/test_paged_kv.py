@@ -291,6 +291,36 @@ def test_paged_page_tokens_max_len_collapses():
     assert d == p
 
 
+def test_pallas_paged_bit_identical_to_dense_at_64_token_pages():
+    """The dense Pallas kernel walks KV in the same 64-token tiles as a
+    64-token page pool, so the two layouts give bit-identical decode logits
+    across page boundaries (not merely close ones): dense is an exact
+    oracle for the paged kernel."""
+    cfg = get_config("llama3.2-1b").smoke().serving()
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    max_len, page = 192, 64
+    prompt = [int(t) for t in np.random.default_rng(0).integers(1, 500, 60)]
+    batch = {"tokens": jnp.asarray([prompt], jnp.int32)}
+    ld, dense = model.prefill_chunked(params, batch, max_len, chunk=64)
+    pool = KVPool(1, max_len, page, prefix_sharing=False)
+    pool.alloc_sequence(0, prompt, max_len)
+    table = jnp.asarray(pool.table_array())
+    lp, paged = model.prefill_chunked(
+        params, batch, max_len, chunk=64, page_table=table,
+        caches=model.init_paged_cache(pool.num_pages, page, 1),
+    )
+    for pos in range(len(prompt), len(prompt) + 72):   # crosses 64 and 128
+        np.testing.assert_array_equal(np.asarray(ld), np.asarray(lp))
+        t = jnp.argmax(ld[0]).astype(jnp.int32).reshape(1, 1)
+        p = jnp.asarray(pos, jnp.int32)
+        ld, dense = model.decode_step(params, {"tokens": t}, dense, p)
+        lp, paged = model.decode_step(
+            params, {"tokens": t}, paged, p, page_table=table
+        )
+    np.testing.assert_array_equal(np.asarray(ld), np.asarray(lp))
+
+
 @pytest.mark.slow
 @pytest.mark.parametrize("impl", ["naive", "chunked", "pallas"])
 @pytest.mark.parametrize(

@@ -11,6 +11,7 @@ from repro.configs import get_config
 from repro.core.devices import tpu_slice_cluster
 from repro.core.modelgraph import transformer_graph
 from repro.core.placement import PlanConfig, plan
+from repro.models import transformer
 from repro.models.model import build_model
 from repro.serving.engine import Request, ServingEngine
 from repro.serving.stage_executor import StageExecutor, stages_from_placement
@@ -50,6 +51,44 @@ def test_executor_matches_model_path(small_model):
         np.asarray(ld_ref, np.float32), np.asarray(ld_ex[:, -1], np.float32),
         rtol=2e-3, atol=2e-3,
     )
+
+
+@pytest.mark.parametrize("paged", [False, True])
+def test_stage_program_runs_the_pallas_kernel(small_model, paged):
+    """The serving config's stage program holds the Pallas kernel (dense
+    and paged KV): nothing swaps it for a pure-jnp path behind the caller."""
+    cfg, _, params = small_model
+    cfg = cfg.serving()
+    graph = transformer_graph(cfg, seq_len=32, granularity="block")
+    stages = stages_from_placement(
+        graph, {nid: 0 for nid in graph.nodes}, jax.devices(), cfg.n_layers
+    )
+    ex = StageExecutor(cfg, params, stages)
+    table = None
+    if paged:
+        caches = ex.init_paged_caches(num_pages=8, page_tokens=8)
+        table = jnp.asarray([[0, 1, -1, -1], [2, -1, -1, -1]], jnp.int32)
+    else:
+        caches = ex.init_caches(2, 32)
+    traced = ex.trace_stage(
+        0, jnp.zeros((2, 4), jnp.int32), caches,
+        cache_pos=jnp.asarray([0, 3], jnp.int32),
+        q_lens=jnp.asarray([4, 1], jnp.int32), page_table=table,
+    )
+    assert "pallas_call" in str(traced.jaxpr)
+
+
+def test_traced_window_under_pallas_raises(small_model):
+    """The kernel specializes on the window: a traced one is an error, not
+    a reason to fall back to another attention implementation."""
+    cfg, _, params = small_model
+    cfg = cfg.serving()
+    x = jnp.zeros((1, 4, cfg.d_model), jnp.float32)
+    pos = jnp.arange(4, dtype=jnp.int32)[None]
+    step = jax.jit(lambda w: transformer.block_apply(
+        params["layers"][0], x, cfg, positions=pos, window=w)[0])
+    with pytest.raises(TypeError, match="static per-layer window"):
+        step(jnp.int32(1 << 30))
 
 
 def test_engine_serves_batched_requests(small_model):
@@ -198,3 +237,16 @@ def test_serving_placement_simulated_latency_ranks_methods():
     mk_m = evaluate(graph, res_m.placement, cm)
     mk_rr = evaluate(graph, res_rr.placement, cm)
     assert mk_m <= mk_rr * 1.01
+
+
+def test_serve_cli_exits_nonzero_on_unserved_request():
+    """Without a fault schedule an unserved request fails the run: a
+    request that cannot fit max_len is shed, and serve exits non-zero."""
+    from repro.launch import serve
+
+    with pytest.raises(SystemExit, match="1 request"):
+        serve.main([
+            "--arch", "llama3.2-1b", "--smoke", "--requests", "1",
+            "--max-len", "16", "--max-new-tokens", "20",
+            "--oversize", "reject", "--method", "etf", "--adapt-every", "0",
+        ])

@@ -1,0 +1,83 @@
+"""The main-path flash kernels compile for a TPU v5e, at llama3.2-1b widths.
+
+No chip is needed: the TPU compiler compiles for a described ``v5e:2x2``
+topology, and refuses what the chip would refuse (block shapes that break
+the (8, 128) tiling rule, too much VMEM).  Interpret mode never runs here:
+``interpret=False`` is passed explicitly, and every compile must hold the
+kernel as a ``tpu_custom_call``.
+
+The topology is described inside a module fixture, never at import: only
+the test process that runs this file loads the TPU library.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from repro.configs import get_config
+from repro.kernels.flash_attention.ops import flash_attention, flash_attention_paged
+
+CFG = get_config("llama3.2-1b")
+H, KV, D = CFG.n_heads, CFG.n_kv_heads, CFG.resolved_head_dim
+B, MAX_LEN, PAGE = 8, 2048, 64
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip cannot be read back from the persistent
+    # cache without the chip: keep it out of the cache
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", prev)
+
+
+def _spec(sharding, shape, dtype=jnp.bfloat16):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _assert_kernel(compiled):
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("sq", [1, 5, 64])
+def test_dense_flash_kernel_compiles_for_v5e(one_chip, sq):
+    step = jax.jit(lambda q, k, v, qp, ql: flash_attention(
+        q, k, v, qp, None, ql, scale=D ** -0.5, interpret=False))
+    compiled = step.lower(
+        _spec(one_chip, (B, sq, H, D)),
+        _spec(one_chip, (B, MAX_LEN, KV, D)),
+        _spec(one_chip, (B, MAX_LEN, KV, D)),
+        _spec(one_chip, (B, sq), jnp.int32),
+        _spec(one_chip, (B,), jnp.int32),
+    ).compile()
+    _assert_kernel(compiled)
+
+
+@pytest.mark.parametrize("sq", [1, 64])
+def test_paged_flash_kernel_compiles_for_v5e(one_chip, sq):
+    num_pages = B * MAX_LEN // PAGE
+    pool = (KV, num_pages + 1, PAGE, D)          # kv-head-major, + trash page
+    step = jax.jit(lambda q, pk, pv, tbl, qp, ql: flash_attention_paged(
+        q, pk, pv, tbl, qp, ql, scale=D ** -0.5, interpret=False))
+    compiled = step.lower(
+        _spec(one_chip, (B, sq, H, D)),
+        _spec(one_chip, pool),
+        _spec(one_chip, pool),
+        _spec(one_chip, (B, MAX_LEN // PAGE), jnp.int32),
+        _spec(one_chip, (B, sq), jnp.int32),
+        _spec(one_chip, (B,), jnp.int32),
+    ).compile()
+    _assert_kernel(compiled)
