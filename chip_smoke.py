@@ -77,26 +77,6 @@ def log(msg: str):
     print(f"[smoke] {msg}", flush=True)
 
 
-class CompileClock:
-    """Seconds spent in backend compiles (persistent-cache reads included)
-    and persistent-cache hits, from JAX's monitoring events."""
-
-    def __init__(self):
-        self.seconds = 0.0
-        self.hits = 0
-
-        def on_duration(event, duration, **_):
-            if event == "/jax/core/compile/backend_compile_duration":
-                self.seconds += duration
-
-        def on_event(event, **_):
-            if event == "/jax/compilation_cache/cache_hits":
-                self.hits += 1
-
-        jax.monitoring.register_event_duration_secs_listener(on_duration)
-        jax.monitoring.register_event_listener(on_event)
-
-
 def make_prompts(seed: int, vocab: int):
     rng = np.random.default_rng(seed)
     lens = np.linspace(16, 600, N_REQUESTS).astype(int)
@@ -196,9 +176,11 @@ class Run:
         return {r.rid: list(r.out_tokens) for r in self.reqs}
 
 
-def serve(name, eng, reqs, clock, *, force=None) -> Run:
+def serve(name, eng, reqs, *, force=None) -> Run:
     """Serve ``reqs`` to the end.  ``force`` (rid → tokens) feeds the engine
     those tokens in place of its own greedy choices (teacher forcing)."""
+    from repro.serving.tracing import compile_stats
+
     run, last = Run(reqs), {}
     forward = eng.executor.forward
 
@@ -209,7 +191,7 @@ def serve(name, eng, reqs, clock, *, force=None) -> Run:
         return logits, caches
 
     eng.executor.forward = tapped
-    c0, t0 = clock.seconds, time.perf_counter()
+    c0, t0 = compile_stats()[1], time.perf_counter()
     for r in reqs:
         eng.submit(r)
     steps = 0
@@ -234,7 +216,7 @@ def serve(name, eng, reqs, clock, *, force=None) -> Run:
     eng.executor.forward = forward
     toks = sum(len(r.out_tokens) for r in reqs)
     log(f"{name}: {steps} steps, {toks} tokens, {wall:.2f} s wall, "
-        f"compile {clock.seconds - c0:.2f} s, "
+        f"compile {compile_stats()[1] - c0:.2f} s, "
         f"{toks / wall:.1f} tok/s (informational, host clock)")
     check_served(name, reqs)
     return run
@@ -302,32 +284,32 @@ def check_reference(cfg, params, eng, prompts):
         fail(f"first-step logits off the f32 reference: rel L2 {rel:.3e}")
 
 
-def one_chip(cfg, params, prompts, clock, devs):
+def one_chip(cfg, params, prompts, devs):
     dev = devs[:1]
     dense = engine(cfg, params, dev)
     log(f"dense engine: placement={dense.placement_result.method}, "
         f"stages={len(dense.executor.stages)}")
-    ref = serve("dense", dense, requests(prompts), clock)
-    warm = serve("dense warm", dense, requests(prompts), clock)
+    ref = serve("dense", dense, requests(prompts))
+    warm = serve("dense warm", dense, requests(prompts))
     same = check_same("dense warm", warm.reqs, ref.reqs)
     check_kernel_on_path(dense, prompts)
     check_reference(cfg, params, dense, prompts)
     del dense
     paged = engine(cfg, params, dev, page_tokens=PAGE)
-    run = serve("paged", paged, requests(prompts), clock, force=ref.tokens())
+    run = serve("paged", paged, requests(prompts), force=ref.tokens())
     log(f"paged kv pool: {paged._kv_pool.stats()}")
     return check_same_model("paged", run, ref) and same
 
 
-def four_chips(cfg, params, prompts, clock, devs):
+def four_chips(cfg, params, prompts, devs):
     from repro.core.milp import PlacementResult
     from repro.core.modelgraph import transformer_graph
     from repro.serving.router import Replica, Router, RouterConfig
+    from repro.serving.tracing import compile_stats
 
     if len(devs) != 4:
         fail(f"--chips 4 needs 4 devices, found {len(devs)}")
-    ref = serve("one chip", engine(cfg, params, devs[:1]), requests(prompts),
-                clock)
+    ref = serve("one chip", engine(cfg, params, devs[:1]), requests(prompts))
 
     # 4-stage pipeline: layer-graph chain cut into 4 contiguous stages
     graph = transformer_graph(cfg, seq_len=MAX_LEN, granularity="block")
@@ -349,7 +331,7 @@ def four_chips(cfg, params, prompts, clock, devs):
     log("pipeline: 4 stages, params on "
         f"{[str(next(iter(h))) for h in homes]}, layers "
         f"{[st.layer_ids for st in pipe.executor.stages]}")
-    run = serve("4-stage pipeline", pipe, requests(prompts), clock,
+    run = serve("4-stage pipeline", pipe, requests(prompts),
                 force=ref.tokens())
     same = check_same_model("4-stage pipeline", run, ref)
     del pipe
@@ -361,7 +343,7 @@ def four_chips(cfg, params, prompts, clock, devs):
     ]
     router = Router(reps, config=RouterConfig(tiers=1))
     reqs = requests(prompts)
-    c0, t0 = clock.seconds, time.perf_counter()
+    c0, t0 = compile_stats()[1], time.perf_counter()
     for r in reqs:
         router.submit(r)
     router.run_until_drained()
@@ -375,7 +357,7 @@ def four_chips(cfg, params, prompts, clock, devs):
         for r in stats["replicas"]
     }
     log(f"router: {len(reps)} replicas, dispatches {per_rep}, "
-        f"{wall:.2f} s wall, compile {clock.seconds - c0:.2f} s, "
+        f"{wall:.2f} s wall, compile {compile_stats()[1] - c0:.2f} s, "
         f"counters {stats['counters']}")
     if stats["counters"]["crashed_replicas"]:
         fail("a replica crashed")
@@ -398,10 +380,10 @@ def main(argv=None) -> int:
     from repro.configs import get_config
     from repro.launch.compile_cache import setup_compile_cache
     from repro.models.model import build_model, param_count
+    from repro.serving.tracing import compile_stats   # counts from here
 
     log(f"devices: {len(devs)}x {devs[0].device_kind}; compile cache "
         f"{setup_compile_cache()}")
-    clock = CompileClock()
     cfg = get_config(ARCH).serving()
     t0 = time.perf_counter()
     params = jax.jit(build_model(cfg).init)(jax.random.PRNGKey(args.seed))
@@ -418,15 +400,15 @@ def main(argv=None) -> int:
         f"{SLOTS} slots, max_len {MAX_LEN}, chunk {CHUNK}")
 
     phase = four_chips if args.chips == 4 else one_chip
-    same = phase(cfg, params, prompts, clock, devs)
+    same = phase(cfg, params, prompts, devs)
 
     for d in jax.devices():
         stats = d.memory_stats() or {}
         peak = stats.get("peak_bytes_in_use")
         log(f"{d}: peak memory "
             f"{'not reported' if peak is None else f'{peak / 2**30:.2f} GiB'}")
-    log(f"compile total {clock.seconds:.2f} s, persistent-cache hits "
-        f"{clock.hits}")
+    n, sec = compile_stats()
+    log(f"compile total {sec:.2f} s over {n} compiles")
     if not same:
         fail("the engines disagree (MISMATCH lines above)")
     print(json.dumps({"ok": True, "device": {

@@ -223,6 +223,7 @@ def flash_attention_pallas_paged(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
         interpret=interpret,
+        name="flash_attention_paged",
     )(
         q_offsets.astype(jnp.int32),
         q_lens.astype(jnp.int32),
@@ -299,4 +300,5 @@ def flash_attention_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
         interpret=interpret,
+        name="flash_attention",
     )(q_offsets, q_lens, q, k, v)

@@ -111,6 +111,7 @@ from repro.models.speculative import greedy_accept, rolled_back_draft_pos
 from .adaptation import AdaptationConfig, AdaptationEvent, DeratePolicy
 from .kv_pool import KVPool
 from .stage_executor import StageExecutor, stages_from_placement, stats_from_times
+from .tracing import compile_stats, span
 
 
 class DeviceLost(RuntimeError):
@@ -265,6 +266,7 @@ class ServingEngine:
         draft_cfg: Optional[ModelConfig] = None,
         draft_params=None,
     ):
+        self._compiles0 = compile_stats()     # compiles count from here
         self.cfg = cfg
         self.params = params
         self.cluster = cluster
@@ -408,6 +410,11 @@ class ServingEngine:
         self.cluster_effective: ClusterSpec = self._effective_cluster()
         self.replan_history: List[Dict[str, Any]] = []
         self._steps_since_window = 0
+        # operator counters (straggler_report()["counters"])
+        self._counters = dict(
+            steps=0, chunk_steps=0, decode_tokens=0, prefill_tokens=0,
+            admitted=0,
+        )
 
         # chaos-harness state: an optional FaultInjector polled at the top
         # of every step(); injected transient faults stash the pre-fault
@@ -794,6 +801,7 @@ class ServingEngine:
                 req = self.queue.pop(qi)
                 req.started = True
                 self.active[slot] = req
+                self._counters["admitted"] += 1
                 # prompt + out_tokens so a request re-queued by a hot-swap
                 # resumes its greedy decode exactly where it was
                 toks_list = toks_head
@@ -831,13 +839,13 @@ class ServingEngine:
                 toks = jnp.asarray([toks_list], jnp.int32)
                 logits, slot_caches = self._prefill_slot(toks)
                 nxt = int(jnp.argmax(logits[0, -1]))
-                req.out_tokens.append(nxt)
+                self._counters["prefill_tokens"] += len(toks_list)
                 self._write_slot_cache(slot, slot_caches)
                 self.slot_pos[slot] = len(toks_list)
                 # the prefill-produced token can itself finish the request
                 # (EOS, or a re-queued request one token short of budget) —
                 # retire NOW or a decode step would overshoot the budget
-                self._maybe_retire(slot, nxt)
+                self._emit(slot, nxt)
 
     def _ensure_caches(self):
         """Lazily allocate device caches: paged pools (+ trash page) when a
@@ -924,6 +932,7 @@ class ServingEngine:
             chunk, row, cache_pos=int(done), kind="prefill"
         )
         self._write_slot_cache(slot, row)
+        self._counters["prefill_tokens"] += n
         done += n
         self._prefill_done[slot] = done
         # a garbage decode row writes (and is later overwritten) at this
@@ -932,13 +941,11 @@ class ServingEngine:
         if done == len(toks_all):
             del self._prefill_toks[slot]
             del self._prefill_done[slot]
-            req = self.active[slot]
             # the next token comes from the LAST REAL prompt row (row n-1),
             # not the padded tail
             nxt = int(jnp.argmax(logits[0, n - 1]))
-            req.out_tokens.append(nxt)
             # the prefill-produced token can itself finish the request
-            self._maybe_retire(slot, nxt)
+            self._emit(slot, nxt)
         return slot
 
     def _write_slot_cache(self, slot: int, slot_caches):
@@ -988,6 +995,24 @@ class ServingEngine:
             return True
         return False
 
+    def _emit(self, slot: int, tok: int) -> bool:
+        """Append ``tok`` to the output of the request in ``slot``, count
+        it, and retire the request if that finished it
+        (:meth:`_maybe_retire`)."""
+        self.active[slot].out_tokens.append(tok)
+        self._counters["decode_tokens"] += 1
+        return self._maybe_retire(slot, tok)
+
+    def _tick_window(self):
+        """Count a step that decoded toward the observation window; every
+        ``window_steps`` of them, observe and adapt."""
+        ws = self.policy.config.window_steps
+        if ws > 0:
+            self._steps_since_window += 1
+            if self._steps_since_window >= ws:
+                with span("engine.observe"):
+                    self.observe_window()
+
     def step(self) -> int:
         """One engine iteration: admit → advance at most one prefill chunk →
         batched decode → retire → (possibly) close an observation window.
@@ -1015,14 +1040,22 @@ class ServingEngine:
         An attached :class:`~repro.serving.faults.FaultInjector` is polled
         FIRST — scheduled faults land before admission/decode, so a step-N
         fault affects step N, exactly as the schedule says."""
+        self._counters["steps"] += 1
+        with span("engine.step", step=self._counters["steps"]):
+            return self._step()
+
+    def _step(self) -> int:
         if self._injector is not None:
             self._injector.on_step(self)
-        self._admit()
+        with span("engine.admit"):
+            self._admit()
         if self._fused_on():
             if self._spec_on():
                 return self._step_spec()
             return self._step_fused()
         adv_slot = self._advance_prefill() if self._prefill_toks else None
+        if adv_slot is not None:
+            self._counters["chunk_steps"] += 1
         # decode-ready slots: active AND fully prefilled
         idx = [
             i for i, r in enumerate(self.active)
@@ -1039,34 +1072,30 @@ class ServingEngine:
         # mid-prefill slots decode garbage into their own rows — inactive
         # rows are masked at retirement, mid-prefill rows are overwritten
         # by their next chunk)
-        last = [
-            (
-                self.active[i].out_tokens[-1]
-                if self.active[i] and i not in self._prefill_toks
-                else 0
-            )
-            for i in range(self.slots)
-        ]
-        toks = jnp.asarray(last, jnp.int32)[:, None]
-        if self.batching == "lockstep":
-            pos = int(max(self.slot_pos[i] for i in idx))
-        else:
-            pos = np.asarray(self.slot_pos, np.int32)   # one depth per slot
+        with span("engine.batch"):
+            last = [
+                (
+                    self.active[i].out_tokens[-1]
+                    if self.active[i] and i not in self._prefill_toks
+                    else 0
+                )
+                for i in range(self.slots)
+            ]
+            toks = jnp.asarray(last, jnp.int32)[:, None]
+            if self.batching == "lockstep":
+                pos = int(max(self.slot_pos[i] for i in idx))
+            else:
+                pos = np.asarray(self.slot_pos, np.int32)  # a depth per slot
         logits, self.caches = self.executor.forward(
             toks, self.caches, cache_pos=pos, kind="decode"
         )
-        nxt = np.asarray(jnp.argmax(logits[:, -1], axis=-1))
-        for i in idx:
-            req = self.active[i]
-            req.out_tokens.append(int(nxt[i]))
-            self.slot_pos[i] += 1
-            self._maybe_retire(i, int(nxt[i]))
-        # closed loop: every window_steps decode steps, observe and adapt
-        ws = self.policy.config.window_steps
-        if ws > 0:
-            self._steps_since_window += 1
-            if self._steps_since_window >= ws:
-                self.observe_window()
+        with span("engine.sample"):
+            nxt = np.asarray(jnp.argmax(logits[:, -1], axis=-1))
+        with span("engine.retire"):
+            for i in idx:
+                self.slot_pos[i] += 1
+                self._emit(i, int(nxt[i]))
+            self._tick_window()
         return len(progressed)
 
     def _fused_decode_frac(self, n_prefill_rows: int) -> Optional[List[float]]:
@@ -1102,66 +1131,65 @@ class ServingEngine:
         if not idx and not pf_slots:
             return 0
         self._ensure_caches()
-        s = self.prefill_chunk if pf_slots else 1
-        tokens = np.zeros((self.slots, s), dtype=np.int32)
-        q_lens = np.zeros(self.slots, dtype=np.int32)
-        cache_pos = np.zeros(self.slots, dtype=np.int32)
-        for i in idx:
-            tokens[i, 0] = self.active[i].out_tokens[-1]
-            q_lens[i] = 1
-            cache_pos[i] = self.slot_pos[i]
-        pf_n: Dict[int, int] = {}
-        for i in pf_slots:
-            done = self._prefill_done[i]
-            toks_all = self._prefill_toks[i]
-            n = min(self.prefill_chunk, len(toks_all) - done)
-            tokens[i, :n] = toks_all[done : done + n]
-            q_lens[i] = n
-            cache_pos[i] = done
-            pf_n[i] = n
+        with span("engine.batch"):
+            s = self.prefill_chunk if pf_slots else 1
+            tokens = np.zeros((self.slots, s), dtype=np.int32)
+            q_lens = np.zeros(self.slots, dtype=np.int32)
+            cache_pos = np.zeros(self.slots, dtype=np.int32)
+            for i in idx:
+                tokens[i, 0] = self.active[i].out_tokens[-1]
+                q_lens[i] = 1
+                cache_pos[i] = self.slot_pos[i]
+            pf_n: Dict[int, int] = {}
+            for i in pf_slots:
+                done = self._prefill_done[i]
+                toks_all = self._prefill_toks[i]
+                n = min(self.prefill_chunk, len(toks_all) - done)
+                tokens[i, :n] = toks_all[done : done + n]
+                q_lens[i] = n
+                cache_pos[i] = done
+                pf_n[i] = n
+            tokens, q_lens = jnp.asarray(tokens), jnp.asarray(q_lens)
+            table = (
+                self._kv_pool.table_array() if self._kv_pool is not None else None
+            )
+        if pf_slots:
+            self._counters["chunk_steps"] += 1
         logits, self.caches = self.executor.forward(
-            jnp.asarray(tokens),
+            tokens,
             self.caches,
             cache_pos=cache_pos,
             kind="fused",
-            q_lens=jnp.asarray(q_lens),
+            q_lens=q_lens,
             fused_decode_frac=self._fused_decode_frac(len(pf_slots)),
-            page_table=(
-                self._kv_pool.table_array()
-                if self._kv_pool is not None
-                else None
-            ),
+            page_table=table,
         )
-        nxt = np.asarray(jnp.argmax(logits, axis=-1))      # [slots, S]
-        for i in idx:
-            req = self.active[i]
-            req.out_tokens.append(int(nxt[i, 0]))
-            self.slot_pos[i] += 1
-            self._maybe_retire(i, int(nxt[i, 0]))
-        for i in pf_slots:
-            n = pf_n[i]
-            done = self._prefill_done[i] + n
-            self._prefill_done[i] = done
-            self.slot_pos[i] = done
-            if done == len(self._prefill_toks[i]):
-                del self._prefill_toks[i]
-                del self._prefill_done[i]
-                req = self.active[i]
-                if self._kv_pool is not None:
-                    # prompt KV is resident: register its page-aligned
-                    # prefix so later requests can share it (BEFORE any
-                    # retirement — the pages then park in the LRU, reusable)
-                    self._kv_pool.commit_prefix(i, req.prompt)
-                # next token from the last REAL prompt row of the chunk
-                tok = int(nxt[i, n - 1])
-                req.out_tokens.append(tok)
-                self._maybe_retire(i, tok)
-        # closed loop: fused steps that decoded count toward the window
-        ws = self.policy.config.window_steps
-        if idx and ws > 0:
-            self._steps_since_window += 1
-            if self._steps_since_window >= ws:
-                self.observe_window()
+        with span("engine.sample"):
+            nxt = np.asarray(jnp.argmax(logits, axis=-1))      # [slots, S]
+        with span("engine.retire"):
+            for i in idx:
+                self.slot_pos[i] += 1
+                self._emit(i, int(nxt[i, 0]))
+            for i in pf_slots:
+                n = pf_n[i]
+                self._counters["prefill_tokens"] += n
+                done = self._prefill_done[i] + n
+                self._prefill_done[i] = done
+                self.slot_pos[i] = done
+                if done == len(self._prefill_toks[i]):
+                    del self._prefill_toks[i]
+                    del self._prefill_done[i]
+                    if self._kv_pool is not None:
+                        # prompt KV is resident: register its page-aligned
+                        # prefix so later requests can share it (BEFORE any
+                        # retirement — the pages then park in the LRU,
+                        # reusable)
+                        self._kv_pool.commit_prefix(i, self.active[i].prompt)
+                    # next token from the last REAL prompt row of the chunk
+                    self._emit(i, int(nxt[i, n - 1]))
+            # closed loop: fused steps that decoded count toward the window
+            if idx:
+                self._tick_window()
         return len(set(idx) | set(pf_slots))
 
     # ------------------------------------------------------------------
@@ -1296,7 +1324,8 @@ class ServingEngine:
                 kind="fused",
                 q_lens=jnp.asarray(d_qlens),
             )
-            nxt0 = np.asarray(jnp.argmax(logits0, axis=-1))
+            with span("engine.sample"):
+                nxt0 = np.asarray(jnp.argmax(logits0, axis=-1))
             for i, n in feed_n.items():
                 self._draft_pos[i] += n
                 if i in spec_rows:
@@ -1326,7 +1355,8 @@ class ServingEngine:
                 kind="fused",
                 q_lens=jnp.asarray(p_q),
             )
-            nxt1 = np.asarray(jnp.argmax(logits1, axis=-1))
+            with span("engine.sample"):
+                nxt1 = np.asarray(jnp.argmax(logits1, axis=-1))
             for i in spec_rows:
                 proposals[i].append(int(nxt1[i, 0]))
         if self._proposal_hook is not None:
@@ -1335,90 +1365,87 @@ class ServingEngine:
                 assert len(hooked) == k, "proposal hook must keep length k"
                 proposals[i] = [int(t) for t in hooked]
         # ---- target phase: one fused mixed forward ----------------------
-        s = 1
+        with span("engine.batch"):
+            s = 1
+            if pf_slots:
+                s = max(s, self.prefill_chunk)
+            if spec_rows:
+                s = max(s, k + 1)
+            tokens = np.zeros((self.slots, s), dtype=np.int32)
+            q_lens = np.zeros(self.slots, dtype=np.int32)
+            cache_pos = np.zeros(self.slots, dtype=np.int32)
+            for i in dec_rows:
+                tokens[i, 0] = self.active[i].out_tokens[-1]
+                q_lens[i] = 1
+                cache_pos[i] = self.slot_pos[i]
+            for i in spec_rows:
+                tokens[i, 0] = self.active[i].out_tokens[-1]
+                tokens[i, 1 : k + 1] = proposals[i]
+                q_lens[i] = k + 1
+                cache_pos[i] = self.slot_pos[i]
+            pf_n: Dict[int, int] = {}
+            for i in pf_slots:
+                done = self._prefill_done[i]
+                toks_all = self._prefill_toks[i]
+                n = min(self.prefill_chunk, len(toks_all) - done)
+                tokens[i, :n] = toks_all[done : done + n]
+                q_lens[i] = n
+                cache_pos[i] = done
+                pf_n[i] = n
+            tokens, q_lens = jnp.asarray(tokens), jnp.asarray(q_lens)
+            table = (
+                self._kv_pool.table_array() if self._kv_pool is not None else None
+            )
         if pf_slots:
-            s = max(s, self.prefill_chunk)
-        if spec_rows:
-            s = max(s, k + 1)
-        tokens = np.zeros((self.slots, s), dtype=np.int32)
-        q_lens = np.zeros(self.slots, dtype=np.int32)
-        cache_pos = np.zeros(self.slots, dtype=np.int32)
-        for i in dec_rows:
-            tokens[i, 0] = self.active[i].out_tokens[-1]
-            q_lens[i] = 1
-            cache_pos[i] = self.slot_pos[i]
-        for i in spec_rows:
-            tokens[i, 0] = self.active[i].out_tokens[-1]
-            tokens[i, 1 : k + 1] = proposals[i]
-            q_lens[i] = k + 1
-            cache_pos[i] = self.slot_pos[i]
-        pf_n: Dict[int, int] = {}
-        for i in pf_slots:
-            done = self._prefill_done[i]
-            toks_all = self._prefill_toks[i]
-            n = min(self.prefill_chunk, len(toks_all) - done)
-            tokens[i, :n] = toks_all[done : done + n]
-            q_lens[i] = n
-            cache_pos[i] = done
-            pf_n[i] = n
+            self._counters["chunk_steps"] += 1
         logits, self.caches = self.executor.forward(
-            jnp.asarray(tokens),
+            tokens,
             self.caches,
             cache_pos=cache_pos,
             kind="fused",
-            q_lens=jnp.asarray(q_lens),
+            q_lens=q_lens,
             fused_decode_frac=self._fused_decode_frac(len(pf_slots)),
-            page_table=(
-                self._kv_pool.table_array()
-                if self._kv_pool is not None
-                else None
-            ),
+            page_table=table,
         )
-        nxt = np.asarray(jnp.argmax(logits, axis=-1))      # [slots, S]
-        for i in dec_rows:
-            req = self.active[i]
-            req.out_tokens.append(int(nxt[i, 0]))
-            self.slot_pos[i] += 1
-            self._maybe_retire(i, int(nxt[i, 0]))
-        for i in spec_rows:
-            req = self.active[i]
-            # preds[t] = the target's greedy token after the pending token
-            # plus d_1..d_t — row t of the verify span
-            preds = [int(nxt[i, t]) for t in range(k + 1)]
-            accepted, emitted = greedy_accept(proposals[i], preds)
-            self._record_acceptance(req, proposed=k, accepted=accepted)
-            # draft rollback is bookkeeping: keep the accepted prefix of
-            # the proposals it already fed itself
-            self._draft_pos[i] = rolled_back_draft_pos(
-                len(commit[i]), accepted, k
-            )
-            # variable advance, one token at a time: EOS / budget /
-            # capacity truncate the round exactly where plain greedy
-            # decoding would have stopped
-            for tok in emitted:
-                req.out_tokens.append(tok)
+        with span("engine.sample"):
+            nxt = np.asarray(jnp.argmax(logits, axis=-1))      # [slots, S]
+        with span("engine.retire"):
+            for i in dec_rows:
                 self.slot_pos[i] += 1
-                if self._maybe_retire(i, tok):
-                    break
-        for i in pf_slots:
-            n = pf_n[i]
-            done = self._prefill_done[i] + n
-            self._prefill_done[i] = done
-            self.slot_pos[i] = done
-            if done == len(self._prefill_toks[i]):
-                del self._prefill_toks[i]
-                del self._prefill_done[i]
+                self._emit(i, int(nxt[i, 0]))
+            for i in spec_rows:
                 req = self.active[i]
-                if self._kv_pool is not None:
-                    self._kv_pool.commit_prefix(i, req.prompt)
-                tok = int(nxt[i, n - 1])
-                req.out_tokens.append(tok)
-                self._maybe_retire(i, tok)
-        ws = self.policy.config.window_steps
-        if idx and ws > 0:
-            self._steps_since_window += 1
-            if self._steps_since_window >= ws:
-                self.observe_window()
+                # preds[t] = the target's greedy token after the pending
+                # token plus d_1..d_t — row t of the verify span
+                preds = [int(nxt[i, t]) for t in range(k + 1)]
+                accepted, emitted = greedy_accept(proposals[i], preds)
+                self._record_acceptance(req, proposed=k, accepted=accepted)
+                # draft rollback is bookkeeping: keep the accepted prefix of
+                # the proposals it already fed itself
+                self._draft_pos[i] = rolled_back_draft_pos(
+                    len(commit[i]), accepted, k
+                )
+                # variable advance, one token at a time: EOS / budget /
+                # capacity truncate the round exactly where plain greedy
+                # decoding would have stopped
+                for tok in emitted:
+                    self.slot_pos[i] += 1
+                    if self._emit(i, tok):
+                        break
+            for i in pf_slots:
+                n = pf_n[i]
+                self._counters["prefill_tokens"] += n
+                done = self._prefill_done[i] + n
+                self._prefill_done[i] = done
+                self.slot_pos[i] = done
+                if done == len(self._prefill_toks[i]):
+                    del self._prefill_toks[i]
+                    del self._prefill_done[i]
+                    if self._kv_pool is not None:
+                        self._kv_pool.commit_prefix(i, self.active[i].prompt)
+                    self._emit(i, int(nxt[i, n - 1]))
+            if idx:
+                self._tick_window()
         return len(set(idx) | set(pf_slots))
 
     def run_until_drained(self, max_steps: int = 10_000) -> List[Request]:
@@ -2005,6 +2032,7 @@ class ServingEngine:
             s["obs_over_pred"] = (
                 s["p95"] / pred if s["n"] > 0 and pred > 0 else float("nan")
             )
+        n_compiles, compile_s = compile_stats()
         return {
             "stages": stats,
             "median_p95": float(np.median(p95s)) if p95s else float("nan"),
@@ -2029,6 +2057,17 @@ class ServingEngine:
             # before any drain call collected them — nonzero means results
             # were lost to the cap, not silently (satellite: visible loss)
             "overflow": {"unclaimed_finished": self._unclaimed_overflow},
+            # the operator's view of the work since the engine was built:
+            # steps (calls to step(); chunk_steps: those whose forward
+            # carried a prefill chunk), decode_tokens (output tokens
+            # emitted, each request's first included), prefill_tokens
+            # (prompt tokens run through the model; shared-prefix pages are
+            # not rerun), admitted, and backend compiles in this process
+            "counters": dict(
+                self._counters,
+                compiles=n_compiles - self._compiles0[0],
+                compile_s=compile_s - self._compiles0[1],
+            ),
             # paged-KV pool health (None when serving dense rows): page
             # residency plus the sharing counters — prefix hits, COW
             # copies, LRU evictions — so cache behavior is operator-visible

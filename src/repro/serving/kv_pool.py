@@ -248,12 +248,19 @@ class KVPool:
     def can_admit(self, tokens: Sequence[int], total_len: int) -> bool:
         """Would :meth:`alloc_sequence` succeed for a sequence whose cache
         will grow to ``total_len`` tokens?  (Reused full pages cost nothing;
-        everything else — including the COW copy — needs a page.)"""
+        everything else — including the COW copy — needs a page.  A reused
+        page parked in the LRU leaves it, so it no longer counts as
+        evictable.)"""
         total_len = min(int(total_len), self.max_len)
+        P = self.page_tokens
         reuse = min(self.lookup_prefix(tokens), max(len(tokens) - 1, 0))
-        full_reused = reuse // self.page_tokens
-        need = pages_needed(total_len, self.page_tokens) - full_reused
-        return need <= self.available_pages()
+        parked = 0
+        prev: Optional[bytes] = None
+        for i in range(reuse // P):
+            prev = _chain_hash(prev, tokens[i * P : (i + 1) * P])
+            parked += int(self.refcount[self._registry[prev]] == 0)
+        need = pages_needed(max(total_len, len(tokens)), P) - reuse // P
+        return need <= self.available_pages() - parked
 
     # ---------------------------------------------------------- lifecycle
     def alloc_sequence(

@@ -33,6 +33,8 @@ from repro.core.graph import OpGraph
 from repro.models import transformer
 from repro.models.layers import rmsnorm, softcap
 
+from .tracing import span
+
 
 @dataclass
 class Stage:
@@ -244,18 +246,19 @@ class StageExecutor:
         update itself is host-side (KVPool)."""
         if not pairs:
             return caches
-        src = jnp.asarray([s for s, _ in pairs], jnp.int32)
-        dst = jnp.asarray([d for _, d in pairs], jnp.int32)
-        out = []
-        for st_caches in caches:
-            out.append([
-                {
-                    key: c[key].at[:, dst].set(c[key][:, src])
-                    for key in ("k", "v")
-                }
-                for c in st_caches
-            ])
-        return out
+        with span("executor.copy_pages"):
+            src = jnp.asarray([s for s, _ in pairs], jnp.int32)
+            dst = jnp.asarray([d for _, d in pairs], jnp.int32)
+            return [
+                [
+                    {
+                        key: c[key].at[:, dst].set(c[key][:, src])
+                        for key in ("k", "v")
+                    }
+                    for c in st_caches
+                ]
+                for st_caches in caches
+            ]
 
     def forward(
         self,
@@ -284,45 +287,48 @@ class StageExecutor:
             raise ValueError(
                 f"kind must be 'decode', 'prefill' or 'fused', got {kind!r}"
             )
-        step_in = self._step_inputs(tokens.shape, cache_pos, q_lens, page_table)
+        # per stage: executor.put (stage 0 also makes the shared step
+        # inputs), executor.launch (dispatch, or a retrace and compile),
+        # executor.wait (the host blocked on the device); the stage's
+        # recorded time runs from the start of its put to the end of its wait
+        step_in = None
         x = tokens
         new_caches = []
-        for si, st in enumerate(self.stages):
-            t0 = time.perf_counter()
-            x = jax.device_put(x, st.device)          # inter-stage data flow
-            st_caches = caches[si] if caches is not None else None
-            x, nc = self._fn(si)(self.stage_params[si], x, st_caches, *step_in)
-            x.block_until_ready()
-            dt = time.perf_counter() - t0
-            if kind == "fused":
-                # split the single wall-clock sample by the cost model's
-                # predicted decode share so neither op class pollutes the
-                # other's observation window (prefill work scales with the
-                # chunk; scoring it as decode reads as device drift)
-                f = 1.0 if fused_decode_frac is None else float(fused_decode_frac[si])
-                f = min(max(f, 0.0), 1.0)
-                if f > 0.0:
-                    self._stage_times[si].append(("decode", dt * f))
-                if f < 1.0:
-                    self._stage_times[si].append(("prefill", dt * (1.0 - f)))
-            else:
-                self._stage_times[si].append((kind, dt))
-            new_caches.append(nc)
+        with span("executor.forward"):
+            for si, st in enumerate(self.stages):
+                t0 = time.perf_counter()
+                with span("executor.put", stage=si):
+                    if step_in is None:
+                        step_in = self._step_inputs(
+                            tokens.shape, cache_pos, q_lens, page_table
+                        )
+                    x = jax.device_put(x, st.device)   # inter-stage data flow
+                st_caches = caches[si] if caches is not None else None
+                with span("executor.launch", stage=si):
+                    x, nc = self._fn(si)(
+                        self.stage_params[si], x, st_caches, *step_in
+                    )
+                with span("executor.wait", stage=si):
+                    x.block_until_ready()
+                dt = time.perf_counter() - t0
+                if kind == "fused":
+                    # split the single wall-clock sample by the cost model's
+                    # predicted decode share so neither op class pollutes the
+                    # other's observation window (prefill work scales with
+                    # the chunk; scoring it as decode reads as device drift)
+                    f = 1.0 if fused_decode_frac is None else float(fused_decode_frac[si])
+                    f = min(max(f, 0.0), 1.0)
+                    if f > 0.0:
+                        self._stage_times[si].append(("decode", dt * f))
+                    if f < 1.0:
+                        self._stage_times[si].append(("prefill", dt * (1.0 - f)))
+                else:
+                    self._stage_times[si].append((kind, dt))
+                new_caches.append(nc)
         return x, new_caches
 
-    # stage latency stats (straggler detection feed)
     def _times(self, rec, kind: Optional[str]) -> List[float]:
         return [t for k, t in rec if kind is None or k == kind]
-
-    def stage_latency_stats(self, kind: Optional[str] = None) -> List[Dict[str, float]]:
-        """mean/p95/n summary per stage over the RETAINED forward calls —
-        the recorder is a bounded ring (most recent 4096 per stage) that
-        observation windows also drain; the engine's ``straggler_report``
-        keeps its own whole-run history.  ``kind`` filters to "decode" or
-        "prefill" samples (None = all)."""
-        return [
-            stats_from_times(self._times(rec, kind)) for rec in self._stage_times
-        ]
 
     def stage_times(self, kind: Optional[str] = None) -> List[List[float]]:
         """Per-stage wall-clock seconds of recent forward calls (bounded

@@ -6,7 +6,8 @@ Usage in test modules::
     from _hypothesis_compat import HAVE_HYPOTHESIS, hypothesis, st
 
 The fallback supports exactly the subset this repo's tests use —
-``@hypothesis.settings(max_examples=..., deadline=...)`` stacked on
+``@hypothesis.settings(max_examples=..., deadline=...)`` and
+``@hypothesis.example(name=value, ...)`` stacked on
 ``@hypothesis.given(name=st.integers(a, b), ...)`` with ``st.integers``,
 ``st.floats`` and ``st.sampled_from`` strategies.  It draws ``max_examples`` pseudo-random examples
 from a per-test seed derived via crc32 of the test name (stable across runs
@@ -56,8 +57,10 @@ except ImportError:
                 n = getattr(wrapper, "_max_examples", _DEFAULT_MAX_EXAMPLES)
                 seed = zlib.crc32(fn.__qualname__.encode())
                 rng = random.Random(seed)
-                for _ in range(n):
-                    drawn = {k: s.draw(rng) for k, s in strategies.items()}
+                explicit = getattr(wrapper, "_examples", [])
+                for i in range(len(explicit) + n):
+                    drawn = (explicit[i] if i < len(explicit) else
+                             {k: s.draw(rng) for k, s in strategies.items()})
                     try:
                         fn(*args, **drawn, **kwargs)
                     except Exception as e:
@@ -80,7 +83,17 @@ except ImportError:
 
         return deco
 
-    hypothesis = types.SimpleNamespace(given=_given, settings=_settings)
+    def _example(**values):
+        def deco(fn):
+            # @example sits ABOVE @given: given's wrapper runs these first
+            fn._examples = [values] + getattr(fn, "_examples", [])
+            return fn
+
+        return deco
+
+    hypothesis = types.SimpleNamespace(
+        given=_given, settings=_settings, example=_example
+    )
     st = types.SimpleNamespace(
         integers=_integers, floats=_floats, sampled_from=_sampled_from
     )

@@ -209,11 +209,13 @@ def test_pool_eviction_under_pressure():
 
 
 @hypothesis.settings(max_examples=30, deadline=None)
+@hypothesis.example(seed=996048)   # a reused prefix page parked in the LRU
 @hypothesis.given(seed=st.integers(0, 10**6))
 def test_pool_invariants_random_ops(seed):
     """Property: any interleaving of admit/commit/free on a small pool keeps
     the refcount/free-list/LRU partition exact and never corrupts the
-    registry (checked after every op)."""
+    registry (checked after every op); ``can_admit`` says yes exactly when
+    ``alloc_sequence`` succeeds."""
     rng = np.random.default_rng(seed)
     P = int(rng.choice([2, 3, 4]))
     pool = KVPool(3, 12, P, num_pages=int(rng.integers(6, 14)))
@@ -229,6 +231,9 @@ def test_pool_invariants_random_ops(seed):
             if pool.can_admit(toks, total):
                 pool.alloc_sequence(slot, toks, total)
                 live[slot] = toks
+            else:
+                with pytest.raises(RuntimeError):
+                    pool.alloc_sequence(slot, toks, total)
         elif op < 0.75 and live:
             slot = int(rng.choice(list(live)))
             pool.commit_prefix(slot, live[slot])

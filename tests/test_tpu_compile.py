@@ -11,6 +11,7 @@ the test process that runs this file loads the TPU library.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -81,3 +82,33 @@ def test_paged_flash_kernel_compiles_for_v5e(one_chip, sq):
         _spec(one_chip, (B,), jnp.int32),
     ).compile()
     _assert_kernel(compiled)
+
+
+@pytest.mark.parametrize("paged", [False, True])
+def test_kernel_keeps_its_name_under_a_renamed_wrapper(one_chip, paged):
+    """The trace names each kernel call after its ``pallas_call`` name
+    (``flash_attention`` / ``flash_attention_paged``), not after the jitted
+    wrapper around it, so renaming the wrapper cannot rename the kernel."""
+    if paged:
+        num_pages = B * MAX_LEN // PAGE
+        pool = (KV, num_pages + 1, PAGE, D)
+
+        def renamed_wrapper(q, pk, pv, tbl, qp, ql):
+            return flash_attention_paged.__wrapped__(
+                q, pk, pv, tbl, qp, ql, scale=D ** -0.5, interpret=False)
+
+        args = [(B, 64, H, D), pool, pool, ((B, MAX_LEN // PAGE), jnp.int32),
+                ((B, 64), jnp.int32), ((B,), jnp.int32)]
+    else:
+        def renamed_wrapper(q, k, v, qp, ql):
+            return flash_attention.__wrapped__(
+                q, k, v, qp, None, ql, scale=D ** -0.5, interpret=False)
+
+        args = [(B, 5, H, D), (B, MAX_LEN, KV, D), (B, MAX_LEN, KV, D),
+                ((B, 5), jnp.int32), ((B,), jnp.int32)]
+    specs = [_spec(one_chip, *a) if isinstance(a[0], tuple) else _spec(one_chip, a)
+             for a in args]
+    text = jax.jit(renamed_wrapper).lower(*specs).compile().as_text()
+    kernels = re.findall(r"%([\w.]+) = \S+ custom-call\(.*tpu_custom_call", text)
+    want = "flash_attention_paged" if paged else "flash_attention"
+    assert kernels and all(re.sub(r"\.\d+$", "", k) == want for k in kernels)
