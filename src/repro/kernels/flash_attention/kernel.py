@@ -1,6 +1,6 @@
 """Flash-attention Pallas TPU kernel (online softmax, blockwise VMEM tiling).
 
-Grid: (batch·heads, Sq/BQ, Sk/BK).  On TPU the last grid axis runs
+Grid: (batch·kv_heads, rows/BQ, Sk/BK).  On TPU the last grid axis runs
 sequentially per core, so the running max / denominator / accumulator live in
 VMEM scratch across the KV sweep — the classic flash recurrence:
 
@@ -9,20 +9,24 @@ VMEM scratch across the KV sweep — the classic flash recurrence:
   acc' = acc·e^{m−m'} + e^{S−m'}·V
 
 Features: causal masking, sliding window (gemma2 local layers), score
-soft-capping, GQA handled by the ops.py wrapper (KV streamed per group,
-never repeated in memory).  Query/key positions are affine in the block
-indices (pos = block_idx·B + iota + offset); each row's ragged shape rides
-in as **per-row scalar-prefetch operands** ``(q_offsets[bh], q_lens[bh])``:
-``q_offsets`` is the absolute position of query row 0 (the row's cache
-depth), ``q_lens`` the number of VALID query rows.  Mixed fused batches —
-decode rows at ``q_len=1``, prefill chunks at ``q_len=chunk``, idle rows at
-``q_len=0``, every serving slot at its own cache depth — run in ONE kernel
-launch with per-row causal masks; queries beyond a row's ``q_len`` are
-fully masked and produce exact zeros (the fused-batch padding contract).
-BQ=128 query rows per block; KV blocks are BK=64 tokens, one page of a
-64-token paged pool, so the dense and paged kernels walk KV in the same
-tiles and, on the same K/V, return bit-identical attention at that page
-size.  Every block spans the whole head_dim.
+soft-capping, and GQA by stacking: one grid row per (batch, KV head), whose
+query block holds the ``rep = H/KV`` query heads of that head's group —
+folded row ``r`` is query position ``r // rep`` of head ``r % rep`` — so each
+K/V block is DMA'd once per group and KV is never repeated in memory.  Each
+row's ragged shape rides in as **per-row scalar-prefetch operands**
+``(q_offsets[b], q_lens[b])``: ``q_offsets`` is the absolute position of
+query position 0 (the row's cache depth), ``q_lens`` the number of VALID
+query positions.  Mixed fused batches — decode rows at ``q_len=1``, prefill
+chunks at ``q_len=chunk``, idle rows at ``q_len=0``, every serving slot at
+its own cache depth — run in ONE kernel launch with per-row causal masks;
+queries beyond a row's ``q_len`` are fully masked and produce exact zeros
+(the fused-batch padding contract).  The masks compare positions scaled by
+``rep`` (``k ≤ r // rep`` ⇔ ``k·rep ≤ r`` for integer ``k``), so the body
+needs no vector division; at ``rep = 1`` it is the ungrouped kernel, op for
+op.  KV blocks are BK=64 tokens, one page of a 64-token paged pool, so the
+dense and paged kernels walk KV in the same tiles and, on the same K/V,
+return bit-identical attention at that page size.  Every block spans the
+whole head_dim.
 """
 
 from __future__ import annotations
@@ -35,17 +39,19 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-DEFAULT_BQ = 128
+DEFAULT_BQ = 128     # query positions a block holds per query head
+MAX_BQ = 512         # VMEM cap on a block's folded rows (positions × rep)
 DEFAULT_BK = 64
 NEG_INF = -1e30
 
 
 def _flash_kernel(
-    offs_ref,   # scalar-prefetch [BH] — absolute position of query row 0,
-                # per batch·head row (ragged decode: one depth per slot)
-    lens_ref,   # scalar-prefetch [BH] — valid query rows per batch·head row
-                # (fused mixed batch: 1 = decode, chunk = prefill, 0 = idle)
-    q_ref,      # [BQ, D]
+    offs_ref,   # scalar-prefetch [B·KV] — absolute position of query
+                # position 0, per grid row (ragged decode: one depth a slot)
+    lens_ref,   # scalar-prefetch [B·KV] — valid query positions per grid
+                # row (fused mixed batch: 1 = decode, chunk = prefill,
+                # 0 = idle)
+    q_ref,      # [BQ, D] folded rows: position r // rep of head r % rep
     k_ref,      # [BK, D]
     v_ref,      # [BK, D]
     o_ref,      # [BQ, D]
@@ -61,6 +67,7 @@ def _flash_kernel(
     n_kv_blocks: int,
     block_q: int,
     block_k: int,
+    rep: int = 1,         # query heads stacked per grid row (GQA group)
 ):
     bh = pl.program_id(0)
     qi = pl.program_id(1)
@@ -84,18 +91,23 @@ def _flash_kernel(
 
     qrow = qi * block_q + jax.lax.broadcasted_iota(
         jnp.int32, (block_q, block_k), 0
-    )                                        # local query row index
-    qp = qrow + q_offset
+    )                                        # local folded row index
+    # folded row r is query position r // rep; positions are compared scaled
+    # by rep (for integer k, k <= r // rep <=> k·rep <= r): no vector division
+    qp = qrow + (q_offset * rep if rep > 1 else q_offset)
     kp = kj * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
+    kps = kp * rep if rep > 1 else kp
     mask = kp < k_len                        # sequence padding is never visible
-    # per-row ragged length: query rows beyond this row's q_len are fully
-    # masked — their denominator stays 0 and _finalize emits exact zeros,
-    # the deterministic padding output of a fused mixed batch
-    mask &= qrow < q_len
+    # per-row ragged length: query positions beyond this row's q_len are
+    # fully masked — their denominator stays 0 and _finalize emits exact
+    # zeros, the deterministic padding output of a fused mixed batch
+    mask &= qrow < (q_len * rep if rep > 1 else q_len)
     if causal:
-        mask &= kp <= qp
+        mask &= kps <= qp
     if window > 0:
-        mask &= kp > qp - window
+        # a scaled window past int32 masks nothing (qp - kps < 2**31 - 1 for
+        # every int32 scaled position), so saturating it is exact
+        mask &= kps > qp - (min(window * rep, 2**31 - 1) if rep > 1 else window)
     s = jnp.where(mask, s, NEG_INF)
 
     m_prev = m_scr[...][:, 0]
@@ -120,11 +132,11 @@ def _flash_kernel(
 
 
 def _flash_kernel_paged(
-    offs_ref,   # scalar-prefetch [BH] — per-row query offsets (cache depth)
-    lens_ref,   # scalar-prefetch [BH] — valid query rows per batch·head row
+    offs_ref,   # scalar-prefetch [B·KV] — per-row query offsets (cache depth)
+    lens_ref,   # scalar-prefetch [B·KV] — valid query positions per grid row
     tbl_ref,    # scalar-prefetch [B, pages_per_slot] — page table: logical
-                # KV block j of batch row b lives in physical page
-                # tbl[b, j] (invalid entries pre-clamped to the trash page)
+                # KV block j of slot s lives in physical page tbl[s, j]
+                # (invalid entries pre-clamped to the trash page)
     q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
     **kw,
 ):
@@ -143,7 +155,8 @@ def _flash_kernel_paged(
 
 
 def flash_attention_pallas_paged(
-    q: jax.Array,            # [BH, Sq, D] (GQA-folded, row-major (b, kv, rep))
+    q: jax.Array,            # [B·KV, rows, D] (row-major (slot, kv); rows
+                             # folded (position, rep), see module docstring)
     pool_k: jax.Array,       # [KV, num_pages + 1, P, D] — last page = trash
     pool_v: jax.Array,       # [KV, num_pages + 1, P, D]
     table: jax.Array,        # [B, pages_per_slot] int32, trash-clamped (≥ 0)
@@ -152,8 +165,8 @@ def flash_attention_pallas_paged(
     causal: bool = True,
     window: int = 0,
     softcap: float = 0.0,
-    q_offsets: Optional[jax.Array] = None,   # [BH] per-row query offsets
-    q_lens: Optional[jax.Array] = None,      # [BH] valid query rows
+    q_offsets: Optional[jax.Array] = None,   # [B·KV] per-row query offsets
+    q_lens: Optional[jax.Array] = None,      # [B·KV] valid query positions
     kv_heads: int = 1,
     rep: int = 1,
     block_q: int = DEFAULT_BQ,
@@ -162,23 +175,25 @@ def flash_attention_pallas_paged(
     """Flash attention reading K/V *through a page table* — no logical-row
     gather is ever materialized.  ``block_k`` is pinned to the page size so
     each KV grid step maps 1:1 onto one physical page: the K/V index maps
-    read ``table[b, j]`` from the scalar-prefetch operand and point the DMA
-    at that page of the pool (kv-head axis indexed per folded row).
+    read ``table[b // kv_heads, j]`` from the scalar-prefetch operand and
+    point the DMA at that page of the pool, for KV head ``b % kv_heads``.
+    Grid row ``b`` carries the ``rep`` query heads of that KV head, so each
+    page is fetched once per group.
 
     The pool is kv-head-major, so one (head, page) block is a contiguous
     ``[P, D]`` tile: its last two dims are the pool's own, which is what the
     TPU's (8, 128) block rule accepts for any page size and head_dim."""
-    bh, sq, d = q.shape
+    bh, rows, d = q.shape
     page_tokens = pool_k.shape[2]
     pages_per_slot = table.shape[1]
-    assert sq % block_q == 0, (sq, block_q)
-    assert bh == table.shape[0] * kv_heads * rep, (bh, table.shape, kv_heads, rep)
-    n_q = sq // block_q
+    assert rows % block_q == 0, (rows, block_q)
+    assert bh == table.shape[0] * kv_heads, (bh, table.shape, kv_heads)
+    n_q = rows // block_q
     k_len = pages_per_slot * page_tokens
     if q_offsets is None:
         q_offsets = jnp.zeros((bh,), jnp.int32)
     if q_lens is None:
-        q_lens = jnp.full((bh,), sq, jnp.int32)
+        q_lens = jnp.full((bh,), -(-rows // rep), jnp.int32)
 
     kernel = functools.partial(
         _flash_kernel_paged,
@@ -190,6 +205,7 @@ def flash_attention_pallas_paged(
         n_kv_blocks=pages_per_slot,
         block_q=block_q,
         block_k=page_tokens,
+        rep=rep,
     )
 
     def _kv_spec():
@@ -199,7 +215,7 @@ def flash_attention_pallas_paged(
         return pl.BlockSpec(
             (None, None, page_tokens, d),
             lambda b, i, j, offs, lens, tbl: (
-                (b // rep) % kv_heads, tbl[b // (kv_heads * rep), j], 0, 0
+                b % kv_heads, tbl[b // kv_heads, j], 0, 0
             ),
         )
 
@@ -221,7 +237,7 @@ def flash_attention_pallas_paged(
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((bh, rows, d), q.dtype),
         interpret=interpret,
         name="flash_attention_paged",
     )(
@@ -233,28 +249,29 @@ def flash_attention_pallas_paged(
 
 
 def flash_attention_pallas(
-    q: jax.Array,            # [BH, Sq, D]
-    k: jax.Array,            # [BH, Sk, D]
-    v: jax.Array,            # [BH, Sk, D]
+    q: jax.Array,            # [B·KV, rows, D] rows folded (position, rep)
+    k: jax.Array,            # [B·KV, Sk, D]
+    v: jax.Array,            # [B·KV, Sk, D]
     *,
     scale: float,
     causal: bool = True,
     window: int = 0,
     softcap: float = 0.0,
     q_offset: int = 0,
-    q_offsets: Optional[jax.Array] = None,   # [BH] per-row query offsets
+    q_offsets: Optional[jax.Array] = None,   # [B·KV] per-row query offsets
                                              # (overrides scalar q_offset)
-    q_lens: Optional[jax.Array] = None,      # [BH] valid query rows per row
-                                             # (None → all sq rows valid)
+    q_lens: Optional[jax.Array] = None,      # [B·KV] valid query positions
+                                             # (None → every position valid)
     k_len: int = 0,          # 0 → all keys valid
+    rep: int = 1,            # query heads stacked per row (GQA group)
     block_q: int = DEFAULT_BQ,
     block_k: int = DEFAULT_BK,
     interpret: bool = False,
 ) -> jax.Array:
-    bh, sq, d = q.shape
+    bh, rows, d = q.shape
     sk = k.shape[1]
-    assert sq % block_q == 0 and sk % block_k == 0, (sq, sk, block_q, block_k)
-    n_q = sq // block_q
+    assert rows % block_q == 0 and sk % block_k == 0, (rows, sk, block_q, block_k)
+    n_q = rows // block_q
     n_k = sk // block_k
     if q_offsets is None:
         q_offsets = jnp.full((bh,), int(q_offset), jnp.int32)
@@ -262,7 +279,7 @@ def flash_attention_pallas(
         assert q_offsets.shape == (bh,), (q_offsets.shape, bh)
         q_offsets = q_offsets.astype(jnp.int32)
     if q_lens is None:
-        q_lens = jnp.full((bh,), sq, jnp.int32)
+        q_lens = jnp.full((bh,), -(-rows // rep), jnp.int32)
     else:
         assert q_lens.shape == (bh,), (q_lens.shape, bh)
         q_lens = q_lens.astype(jnp.int32)
@@ -277,6 +294,7 @@ def flash_attention_pallas(
         n_kv_blocks=n_k,
         block_q=block_q,
         block_k=block_k,
+        rep=rep,
     )
     # per-row (offset, len) ride in as scalar-prefetch operands (SMEM):
     # available before the body runs, so masks stay affine in block indices
@@ -298,7 +316,7 @@ def flash_attention_pallas(
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((bh, rows, d), q.dtype),
         interpret=interpret,
         name="flash_attention",
     )(q_offsets, q_lens, q, k, v)

@@ -1,7 +1,13 @@
 """jit'd wrapper: model-layout [B,S,H,D] GQA attention on the flash kernel.
 
-Handles GQA head grouping (queries of one KV head's group are folded into
-the batch·kv_head axis) and sequence padding to block multiples; blocks
+Handles GQA head grouping and query padding.  The ``rep = H/KV`` query heads
+of one KV head's group are stacked into one kernel row:
+``[B, Sq, KV, rep, D] → [B, KV, Sq, rep, D] → [B·KV, Sq·rep, D]``, so
+folded row ``i`` is query position ``i // rep`` of head ``i % rep``, and
+each K/V block is read once per group (K/V keep their ``KV`` heads; nothing
+is repeated).  The folded rows are padded to a multiple of 8 and of the
+query block, which holds up to ``DEFAULT_BQ`` positions of every head of the
+group (at most ``MAX_BQ`` rows); keys are padded to the KV block.  Blocks
 span the whole head_dim, which the TPU accepts for any head_dim.  On CPU
 the kernel runs in interpret mode (correctness path); on TPU it compiles
 to the real blockwise kernel (see ``repro.kernels.resolve_interpret``).
@@ -31,9 +37,46 @@ from repro.kernels import resolve_interpret
 from .kernel import (
     DEFAULT_BK,
     DEFAULT_BQ,
+    MAX_BQ,
     flash_attention_pallas,
     flash_attention_pallas_paged,
 )
+
+
+def _fold_queries(q: jax.Array, kv: int) -> tuple[jax.Array, int]:
+    """``[B, Sq, H, D]`` → ``([B·KV, rows, D], block_q)``: each KV head's
+    ``rep`` query heads stacked position-major in one row, padded to a
+    multiple of ``block_q`` (itself a multiple of 8)."""
+    b, sq, h, d = q.shape
+    rep = h // kv
+    rows = sq * rep
+    bq = min(DEFAULT_BQ * rep, MAX_BQ, -(-rows // 8) * 8)
+    qk = q.reshape(b, sq, kv, rep, d).transpose(0, 2, 1, 3, 4).reshape(
+        b * kv, rows, d
+    )
+    pad = (-rows) % bq
+    if pad:
+        qk = jnp.pad(qk, ((0, 0), (0, pad), (0, 0)))
+    return qk, bq
+
+
+def _unfold(out: jax.Array, b: int, sq: int, h: int) -> jax.Array:
+    """Inverse of :func:`_fold_queries` on the kernel's output."""
+    bk, _, d = out.shape
+    kv = bk // b
+    rep = h // kv
+    return out[:, : sq * rep].reshape(b, kv, sq, rep, d).transpose(
+        0, 2, 1, 3, 4
+    ).reshape(b, sq, h, d)
+
+
+def _row_offsets(q_pos: Optional[jax.Array], b: int, default: int) -> jax.Array:
+    """Position of query position 0 per batch row ([B] int32)."""
+    if q_pos is None:
+        return jnp.full((b,), int(default), jnp.int32)
+    if q_pos.ndim == 2:                      # [B, Sq] — ragged rows
+        return q_pos[:, 0].astype(jnp.int32)
+    return jnp.full((b,), q_pos[0].astype(jnp.int32))   # [Sq] shared
 
 
 @functools.partial(
@@ -64,37 +107,19 @@ def flash_attention(
     del k_pos  # affine by construction (cache rows 0..Sk-1); masking uses q_pos
     b, sq, h, d = q.shape
     sk, kv = k.shape[1], k.shape[2]
-    rep = h // kv
 
-    # resolve per-(batch·head) query offsets: position of query row 0 per row
-    if q_pos is None:
-        offs = jnp.full((b,), int(q_offset), jnp.int32)
-    elif q_pos.ndim == 2:                    # [B, Sq] — ragged rows
-        offs = q_pos[:, 0].astype(jnp.int32)
-    else:                                    # [Sq] shared across rows
-        offs = jnp.full((b,), q_pos[0].astype(jnp.int32))
-    offs_bh = jnp.repeat(offs, kv * rep)     # row-major (b, kv, rep) fold below
-    lens_bh = (
-        None if q_lens is None else jnp.repeat(q_lens.astype(jnp.int32), kv * rep)
-    )
+    # per-(batch, KV head) rows: one offset and length per row
+    offs = jnp.repeat(_row_offsets(q_pos, b, q_offset), kv)
+    lens = None if q_lens is None else jnp.repeat(q_lens.astype(jnp.int32), kv)
+    qk, bq = _fold_queries(q, kv)
+    # K/V keep their KV heads: [B·KV, Sk, D], read once per group
+    kk = k.transpose(0, 2, 1, 3).reshape(b * kv, sk, d)
+    vk = v.transpose(0, 2, 1, 3).reshape(b * kv, sk, d)
 
-    # fold GQA groups into the kernel's batch axis: [B·KV·rep, S, D]
-    qk = q.reshape(b, sq, kv, rep, d).transpose(0, 2, 3, 1, 4).reshape(b * kv * rep, sq, d)
-    kk = jnp.broadcast_to(
-        k.transpose(0, 2, 1, 3)[:, :, None], (b, kv, rep, sk, d)
-    ).reshape(b * kv * rep, sk, d)
-    vk = jnp.broadcast_to(
-        v.transpose(0, 2, 1, 3)[:, :, None], (b, kv, rep, sk, d)
-    ).reshape(b * kv * rep, sk, d)
-
-    # pad sequences to block multiples; every block spans the full head_dim
-    # (legal on the TPU for any head_dim), so head_dim is never padded
-    bq = min(DEFAULT_BQ, max(8, sq))
+    # pad keys to the KV block; every block spans the full head_dim (legal on
+    # the TPU for any head_dim), so head_dim is never padded
     bk = min(DEFAULT_BK, max(8, sk))
-    sqp = (-sq) % bq
     skp = (-sk) % bk
-    if sqp:
-        qk = jnp.pad(qk, ((0, 0), (0, sqp), (0, 0)))
     if skp:
         kk = jnp.pad(kk, ((0, 0), (0, skp), (0, 0)))
         vk = jnp.pad(vk, ((0, 0), (0, skp), (0, 0)))
@@ -105,15 +130,15 @@ def flash_attention(
         causal=causal,
         window=int(window or 0),
         softcap=float(softcap or 0.0),
-        q_offsets=offs_bh,
-        q_lens=lens_bh,
+        q_offsets=offs,
+        q_lens=lens,
         k_len=sk,
+        rep=h // kv,
         block_q=bq,
         block_k=bk,
         interpret=interpret,
     )
-    out = out[:, :sq]
-    return out.reshape(b, kv, rep, sq, d).transpose(0, 3, 1, 2, 4).reshape(b, sq, h, d)
+    return _unfold(out, b, sq, h)
 
 
 @functools.partial(
@@ -137,9 +162,9 @@ def flash_attention_paged(
     """Model-layout wrapper for the PAGED flash kernel: K/V live in a
     physical page pool and the per-slot page table rides into the kernel as
     a scalar-prefetch operand — the kernel's K/V index maps dereference it
-    per (row, page) grid step, so no ``[B, max_len]`` logical view is ever
-    gathered (the dense wrapper's KV broadcast across GQA groups is gone
-    too: the kv-head axis is indexed straight out of the pool).
+    per (slot·KV head, page) grid step, so no ``[B, max_len]`` logical view
+    is ever gathered, and each page is fetched once per GQA group (the
+    kv-head axis is indexed straight out of the pool).
 
     Unmapped table entries (−1) are clamped to the reserved trash page
     ``num_pages``; whatever garbage it holds sits at logical positions
@@ -148,32 +173,12 @@ def flash_attention_paged(
     interpret = resolve_interpret(interpret)
     b, sq, h, d = q.shape
     kv = pool_k.shape[0]
-    rep = h // kv
     num_pages = pool_k.shape[1] - 1
     tbl = jnp.where(table >= 0, table, num_pages).astype(jnp.int32)
 
-    if q_pos is None:
-        offs = jnp.zeros((b,), jnp.int32)
-    elif q_pos.ndim == 2:                    # [B, Sq] — ragged rows
-        offs = q_pos[:, 0].astype(jnp.int32)
-    else:                                    # [Sq] shared across rows
-        offs = jnp.full((b,), q_pos[0].astype(jnp.int32))
-    offs_bh = jnp.repeat(offs, kv * rep)
-    lens_bh = (
-        None if q_lens is None else jnp.repeat(q_lens.astype(jnp.int32), kv * rep)
-    )
-
-    # fold GQA groups into the kernel's batch axis: [B·KV·rep, Sq, D]
-    qk = q.reshape(b, sq, kv, rep, d).transpose(0, 2, 3, 1, 4).reshape(
-        b * kv * rep, sq, d
-    )
-
-    # pad queries to a block multiple; head_dim is never padded — every
-    # block spans the full head_dim, so the pools are read in place
-    bq = min(DEFAULT_BQ, max(8, sq))
-    sqp = (-sq) % bq
-    if sqp:
-        qk = jnp.pad(qk, ((0, 0), (0, sqp), (0, 0)))
+    offs = jnp.repeat(_row_offsets(q_pos, b, 0), kv)
+    lens = None if q_lens is None else jnp.repeat(q_lens.astype(jnp.int32), kv)
+    qk, bq = _fold_queries(q, kv)
 
     out = flash_attention_pallas_paged(
         qk, pool_k, pool_v, tbl,
@@ -181,14 +186,11 @@ def flash_attention_paged(
         causal=causal,
         window=int(window or 0),
         softcap=float(softcap or 0.0),
-        q_offsets=offs_bh,
-        q_lens=lens_bh,
+        q_offsets=offs,
+        q_lens=lens,
         kv_heads=kv,
-        rep=rep,
+        rep=h // kv,
         block_q=bq,
         interpret=interpret,
     )
-    out = out[:, :sq]
-    return out.reshape(b, kv, rep, sq, d).transpose(0, 3, 1, 2, 4).reshape(
-        b, sq, h, d
-    )
+    return _unfold(out, b, sq, h)

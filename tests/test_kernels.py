@@ -66,6 +66,129 @@ def test_flash_attention_kernel(case, dtype):
     )
 
 
+# ------------------------------------------- flash attn, GQA-stacked rows
+# ragged serving rows: slot 0 a chunk at a page boundary, slot 1 idle
+# (q_len 0), slot 2 a shorter chunk at the next boundary
+GB, GKV, GD, GPAGE, GPAGES = 3, 2, 32, 16, 8
+
+
+def _grouped_case(rep, sq, seed):
+    """A ragged fused batch: per-slot offsets at page boundaries, q_lens with
+    an idle slot, logical K/V of ``GPAGES`` pages, and the page pool it
+    lives in (pages shuffled; pages beyond a slot's span unmapped, i.e.
+    read from a trash page of large finite garbage)."""
+    r = np.random.default_rng(seed)
+    h, L = GKV * rep, GPAGE * GPAGES
+    offs = np.array([GPAGE, 0, 3 * GPAGE], np.int32)
+    lens = np.array([sq, 0, max(1, sq // 2)], np.int32)
+    q = r.standard_normal((GB, sq, h, GD)).astype(np.float32)
+    k = r.standard_normal((GB, L, GKV, GD)).astype(np.float32)
+    v = r.standard_normal((GB, L, GKV, GD)).astype(np.float32)
+    perm = r.permutation(GB * GPAGES)
+    table = perm.reshape(GB, GPAGES).astype(np.int32)
+    pool_k = np.zeros((GKV, GB * GPAGES + 1, GPAGE, GD), np.float32)
+    pool_v = np.zeros_like(pool_k)
+    for b in range(GB):
+        last = offs[b] + max(lens[b], 1) - 1        # last visible position
+        for j in range(GPAGES):
+            sl = slice(j * GPAGE, (j + 1) * GPAGE)
+            pool_k[:, table[b, j]] = k[b, sl].transpose(1, 0, 2)
+            pool_v[:, table[b, j]] = v[b, sl].transpose(1, 0, 2)
+            if j * GPAGE > last:
+                table[b, j] = -1                    # unmapped → trash page
+    pool_k[:, -1] = 1e4                             # finite trash
+    pool_v[:, -1] = -1e4
+    return offs, lens, q, k, v, table, pool_k, pool_v
+
+
+def _grouped_ref(offs, lens, q, k, v, *, window, cap):
+    from repro.kernels.flash_attention.ref import attention_ref
+
+    b, sq, h, d = q.shape
+    kv = k.shape[2]
+    rep = h // kv
+    out = np.zeros((b, sq, h, d), np.float32)
+    for s in range(b):
+        n = int(lens[s])
+        for hh in range(h if n else 0):
+            g = hh // rep
+            o = attention_ref(
+                jnp.asarray(q[s, :n, hh])[None], jnp.asarray(k[s, :, g])[None],
+                jnp.asarray(v[s, :, g])[None], scale=1.0 / np.sqrt(d),
+                causal=True, window=window, softcap=cap, q_offset=int(offs[s]),
+            )
+            out[s, :n, hh] = np.asarray(o[0])
+    return out
+
+
+def _check_grouped(out, ref, lens):
+    out = np.asarray(out, np.float32)
+    np.testing.assert_allclose(out, ref, rtol=2e-4, atol=2e-4)
+    for s, n in enumerate(lens):                    # padding rows: exact zeros
+        assert not np.any(out[s, n:]), (s, n)
+
+
+@pytest.mark.parametrize("sq", [1, 3, 64])
+@pytest.mark.parametrize("rep", [1, 2, 5, 8])
+@pytest.mark.parametrize("paged", [False, True])
+def test_grouped_flash_kernel_ragged(paged, rep, sq):
+    """The GQA group's ``rep`` query heads stacked in one grid row give the
+    reference's per-head attention on a ragged fused batch: an idle slot,
+    offsets at page boundaries, a trash-clamped page table."""
+    from repro.kernels.flash_attention.ops import (
+        flash_attention, flash_attention_paged,
+    )
+
+    offs, lens, q, k, v, table, pk, pv = _grouped_case(rep, sq, 7 * rep + sq)
+    q_pos = jnp.asarray(offs[:, None] + np.arange(sq)[None], jnp.int32)
+    scale = 1.0 / np.sqrt(GD)
+    if paged:
+        out = flash_attention_paged(
+            jnp.asarray(q), jnp.asarray(pk), jnp.asarray(pv),
+            jnp.asarray(table), q_pos, jnp.asarray(lens),
+            scale=scale, interpret=True,
+        )
+    else:
+        out = flash_attention(
+            jnp.asarray(q), jnp.asarray(k), jnp.asarray(v), q_pos, None,
+            jnp.asarray(lens), scale=scale, interpret=True,
+        )
+    _check_grouped(out, _grouped_ref(offs, lens, q, k, v, window=0, cap=0.0),
+                   lens)
+
+
+@pytest.mark.parametrize("window", [24, 1 << 30])
+@pytest.mark.parametrize("rep", [1, 2, 5, 8])
+@pytest.mark.parametrize("paged", [False, True])
+def test_grouped_flash_kernel_window_softcap(paged, rep, window):
+    """Window and softcap masks stay per position under the stacked rows:
+    gemma2's local layers (a 24-token window across page boundaries) and
+    its global ones (the model's ``1 << 30`` window, whose scaled form
+    would overflow int32)."""
+    from repro.kernels.flash_attention.ops import (
+        flash_attention, flash_attention_paged,
+    )
+
+    sq, cap = 40, 5.0
+    offs, lens, q, k, v, table, pk, pv = _grouped_case(rep, sq, 100 + rep)
+    q_pos = jnp.asarray(offs[:, None] + np.arange(sq)[None], jnp.int32)
+    kw = dict(scale=1.0 / np.sqrt(GD), window=window, softcap=cap,
+              interpret=True)
+    if paged:
+        out = flash_attention_paged(
+            jnp.asarray(q), jnp.asarray(pk), jnp.asarray(pv),
+            jnp.asarray(table), q_pos, jnp.asarray(lens), **kw,
+        )
+    else:
+        out = flash_attention(
+            jnp.asarray(q), jnp.asarray(k), jnp.asarray(v), q_pos, None,
+            jnp.asarray(lens), **kw,
+        )
+    _check_grouped(
+        out, _grouped_ref(offs, lens, q, k, v, window=window, cap=cap), lens,
+    )
+
+
 # ------------------------------------------------------------- grouped gemm
 @pytest.mark.parametrize(
     "m,k,n,e,sizes",

@@ -1,4 +1,5 @@
-"""The main-path flash kernels compile for a TPU v5e, at llama3.2-1b widths.
+"""The main-path flash kernels compile for a TPU v5e, at llama3.2-1b widths and
+at the benchmark configs' widths (Qwen3-14B, Qwen1.5-MoE).
 
 No chip is needed: the TPU compiler compiles for a described ``v5e:2x2``
 topology, and refuses what the chip would refuse (block shapes that break
@@ -112,3 +113,33 @@ def test_kernel_keeps_its_name_under_a_renamed_wrapper(one_chip, paged):
     kernels = re.findall(r"%([\w.]+) = \S+ custom-call\(.*tpu_custom_call", text)
     want = "flash_attention_paged" if paged else "flash_attention"
     assert kernels and all(re.sub(r"\.\d+$", "", k) == want for k in kernels)
+
+
+@pytest.mark.parametrize("arch, slots, pages, sq, rows", [
+    ("qwen3-14b", 16, 40, 1, 8),       # 40/8 heads: 5 of 8 rows valid
+    ("qwen3-14b", 16, 40, 64, 320),    # one 320-row query block
+    ("qwen2-moe-a2.7b", 6, 128, 64, 64),   # 16/16 heads: rep 1, unstacked
+])
+def test_paged_flash_kernel_stacks_the_gqa_group(one_chip, arch, slots, pages,
+                                                 sq, rows):
+    """At a benchmark config's widths the paged kernel compiles with one
+    grid row per (slot, KV head), the group's query heads stacked in its
+    query block (the vector masks compare positions scaled by ``rep``), and
+    keeps its name."""
+    cfg = get_config(arch)
+    h, kv, d = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
+    pool = (kv, slots * pages + 1, PAGE, d)
+    step = jax.jit(lambda q, pk, pv, tbl, qp, ql: flash_attention_paged(
+        q, pk, pv, tbl, qp, ql, scale=d ** -0.5, interpret=False))
+    text = step.lower(
+        _spec(one_chip, (slots, sq, h, d)),
+        _spec(one_chip, pool),
+        _spec(one_chip, pool),
+        _spec(one_chip, (slots, pages), jnp.int32),
+        _spec(one_chip, (slots, sq), jnp.int32),
+        _spec(one_chip, (slots,), jnp.int32),
+    ).compile().as_text()
+    kernels = re.findall(
+        r"%([\w.]+) = (\w+\[[\d,]+\])\S* custom-call\(.*tpu_custom_call", text)
+    assert [(re.sub(r"\.\d+$", "", n), s) for n, s in kernels] == [
+        ("flash_attention_paged", f"bf16[{slots * kv},{rows},{d}]")]
