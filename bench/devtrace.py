@@ -94,6 +94,7 @@ def _union(intervals: List[Tuple[float, float]]) -> List[Tuple[float, float]]:
 class Summary:
     window_s: float
     busy_s: float                       # averaged over active devices
+    busy_by_device: Dict[str, float]    # device plane -> busy seconds
     kernel_s: Dict[str, float]          # pattern -> seconds, summed over devices
     kernel_calls: Dict[str, int]
     device_ops: List[List]              # [name, seconds] by time, top 10
@@ -116,19 +117,18 @@ def summarize(tr: Trace, kernels: Dict[str, str]) -> Summary:
     if not steps:
         raise ValueError("the trace holds no benchmark step span")
     w0, w1 = min(a for a, _ in steps), max(b for _, b in steps)
-    busy_total, n_dev = 0.0, 0
+    busy: Dict[str, float] = {}
     by_name: Dict[str, float] = {}
     gaps: Dict[str, float] = {}
     ksec = {k: 0.0 for k in kernels}
     kcalls = {k: 0 for k in kernels}
     pats = {k: re.compile(p) for k, p in kernels.items()}
-    for ops in tr.devices.values():
+    for dev, ops in tr.devices.items():
         inside = [o for o in ops if o.end > w0 and o.start < w1]
         if not inside:
             continue
-        n_dev += 1
         merged = _union([(max(o.start, w0), min(o.end, w1)) for o in inside])
-        busy_total += sum(b - a for a, b in merged)
+        busy[dev] = sum(b - a for a, b in merged)
         for o in inside:
             d = min(o.end, w1) - max(o.start, w0)
             by_name[o.name] = by_name.get(o.name, 0.0) + d
@@ -141,6 +141,7 @@ def summarize(tr: Trace, kernels: Dict[str, str]) -> Summary:
             if b > a:
                 lab = _host_activity(tr.spans, (a + b) / 2)
                 gaps[lab] = gaps.get(lab, 0.0) + b - a
+    n_dev = len(busy)
     if n_dev == 0:
         raise ValueError("no device operation ran inside the traced window")
     missing = [k for k, c in kcalls.items() if c == 0]
@@ -149,7 +150,8 @@ def summarize(tr: Trace, kernels: Dict[str, str]) -> Summary:
     top = sorted(by_name.items(), key=lambda kv: -kv[1])[:10]
     return Summary(
         window_s=w1 - w0,
-        busy_s=busy_total / n_dev,
+        busy_s=sum(busy.values()) / n_dev,
+        busy_by_device=busy,
         kernel_s=ksec,
         kernel_calls=kcalls,
         device_ops=[[n, s / n_dev] for n, s in top],

@@ -1,67 +1,45 @@
 """A configuration file as the program runs it, the weights the benchmark
 makes for it, and the operations and bytes its work needs.
 
-The configuration files hold the model's published ``config.json`` keys
-(Qwen style).  This module maps them onto the program's ``ModelConfig``
-(``program_config``), lays out the parameter tree the program serves
-(``layout``: the names and shapes are the interface to the system under
-test, the values are the benchmark's own) and counts the work of a step
-from shapes alone.
+The configuration files hold the model's published ``config.json`` keys.
+Everything that depends on the architecture lives in the family module
+that the file's ``model_type`` names (``bench/families/<model_type>.py``,
+see ``bench/families/__init__.py``); the functions here that take a
+configuration hand it to that module.  What is the same for every family
+is here: the leaf specs and the weights made from them (the names and
+shapes are the interface to the system under test, the values are the
+benchmark's own), the parameter count, and the roofline arithmetic.
 """
 
 from __future__ import annotations
 
-import dataclasses
 import math
 from dataclasses import dataclass
+from types import ModuleType
 from typing import Any, Callable, Dict, List
 
 import numpy as np
 
-PROGRAM_EPS = 1e-6      # the program's RMSNorm epsilon, fixed in its code
+from bench import spec
 
 
-def is_moe(conf: Dict) -> bool:
-    return "num_experts" in conf
-
-
-def head_dim(conf: Dict) -> int:
-    return int(conf.get("head_dim") or conf["hidden_size"] // conf["num_attention_heads"])
+def family(conf: Dict) -> ModuleType:
+    """The family module of ``conf["model_type"]``."""
+    return spec.family(conf["model_type"])
 
 
 def program_config(conf: Dict):
-    """The program's ``ModelConfig`` for this file: its registry entry with
-    every size taken from the file, then the serving variant."""
-    from repro.configs import get_config
+    """The program's ``ModelConfig`` for this file."""
+    return family(conf).program_config(conf)
 
-    if conf["rms_norm_eps"] != PROGRAM_EPS or conf["hidden_act"] != "silu":
-        raise ValueError("the program serves RMSNorm eps 1e-6 and SiLU only")
-    f = dict(
-        n_layers=conf["num_hidden_layers"],
-        d_model=conf["hidden_size"],
-        n_heads=conf["num_attention_heads"],
-        n_kv_heads=conf["num_key_value_heads"],
-        head_dim=head_dim(conf),
-        vocab_size=conf["vocab_size"],
-        rope_theta=float(conf["rope_theta"]),
-        tie_embeddings=bool(conf["tie_word_embeddings"]),
-        qk_norm=conf["model_type"] == "qwen3",
-        dtype=conf["torch_dtype"],
-    )
-    if is_moe(conf):
-        f.update(
-            n_experts=conf["num_experts"],
-            top_k=conf["num_experts_per_tok"],
-            moe_d_ff=conf["moe_intermediate_size"],
-            d_ff=conf["moe_intermediate_size"],
-            shared_d_ff=conf["shared_expert_intermediate_size"],
-            n_shared_experts=(conf["shared_expert_intermediate_size"]
-                              // conf["moe_intermediate_size"]),
-            router_norm_topk=bool(conf["norm_topk_prob"]),
-        )
-    else:
-        f["d_ff"] = conf["intermediate_size"]
-    return dataclasses.replace(get_config(conf["arch"]), **f).serving()
+
+def experts_held(conf: Dict, cfg) -> int:
+    """Routed experts the program ``cfg`` holds on a chip (0: dense)."""
+    return family(conf).experts_held(conf, cfg)
+
+
+def head_dim(conf: Dict) -> int:
+    return family(conf).head_dim(conf)
 
 
 # --------------------------------------------------------------------------
@@ -76,40 +54,10 @@ class Leaf:
     kind: str          # "matrix" | "norm"
 
 
-def layout(conf: Dict, experts_held: int) -> Dict[str, Any]:
-    """The parameter tree the program serves, as ``Leaf`` specs.  Norm
-    scales are stored as offsets from 1; ``experts_held`` is the program's
-    padded expert count (the router's width)."""
-    d, v, hd = conf["hidden_size"], conf["vocab_size"], head_dim(conf)
-    h, kv = conf["num_attention_heads"], conf["num_key_value_heads"]
-    dt = conf["torch_dtype"]
-    mat = lambda *s: Leaf(tuple(s), dt, "matrix")
-    norm = lambda n: Leaf((n,), "float32", "norm")
-
-    def mlp(ff):
-        return {"w_gate": mat(d, ff), "w_up": mat(d, ff), "w_down": mat(ff, d)}
-
-    def layer():
-        attn = {"wq": mat(d, h * hd), "wk": mat(d, kv * hd),
-                "wv": mat(d, kv * hd), "wo": mat(h * hd, d)}
-        if conf["model_type"] == "qwen3":
-            attn.update(q_norm=norm(hd), k_norm=norm(hd))
-        p = {"ln_attn": norm(d), "attn": attn, "ln_mlp": norm(d)}
-        if is_moe(conf):
-            e, f = experts_held, conf["moe_intermediate_size"]
-            p["moe"] = {"router": Leaf((d, e), "float32", "matrix"),
-                        "w_gate": mat(e, d, f), "w_up": mat(e, d, f),
-                        "w_down": mat(e, f, d)}
-            p["shared_mlp"] = mlp(conf["shared_expert_intermediate_size"])
-        else:
-            p["mlp"] = mlp(conf["intermediate_size"])
-        return p
-
-    tree = {"embed": mat(v, d), "ln_final": norm(d),
-            "layers": [layer() for _ in range(conf["num_hidden_layers"])]}
-    if not conf["tie_word_embeddings"]:
-        tree["lm_head"] = mat(d, v)
-    return tree
+def layout(conf: Dict, held: int) -> Dict[str, Any]:
+    """The parameter tree the program serves, as ``Leaf`` specs; ``held``
+    is ``experts_held``."""
+    return family(conf).layout(conf, held)
 
 
 def seed_key(seed: int):
@@ -121,7 +69,7 @@ def seed_key(seed: int):
     return jax.random.wrap_key_data(jnp.asarray(words), impl="threefry2x32")
 
 
-def make_params(conf: Dict, experts_held: int, seed: int,
+def make_params(conf: Dict, held: int, seed: int,
                 device_of: Callable[[tuple], Any]):
     """The weights, made on the device from ``seed``: one jitted call per
     device, each leaf on ``device_of(path)``.  A leaf's values depend only
@@ -130,7 +78,7 @@ def make_params(conf: Dict, experts_held: int, seed: int,
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
 
-    tree = layout(conf, experts_held)
+    tree = layout(conf, held)
     paths, leaves = zip(*jax.tree_util.tree_flatten_with_path(
         tree, is_leaf=lambda x: isinstance(x, Leaf))[0])
     std = float(conf["initializer_range"])
@@ -158,20 +106,11 @@ def make_params(conf: Dict, experts_held: int, seed: int,
         out)
 
 
-def param_count(conf: Dict, experts_held: int) -> int:
-    """Parameters the program holds, from the sizes alone."""
-    d, v, hd = conf["hidden_size"], conf["vocab_size"], head_dim(conf)
-    h, kv, n = conf["num_attention_heads"], conf["num_key_value_heads"], conf["num_hidden_layers"]
-    per = d * h * hd * 2 + d * kv * hd * 2 + 2 * d
-    if conf["model_type"] == "qwen3":
-        per += 2 * hd
-    if is_moe(conf):
-        per += d * experts_held + experts_held * 3 * d * conf["moe_intermediate_size"]
-        per += 3 * d * conf["shared_expert_intermediate_size"]
-    else:
-        per += 3 * d * conf["intermediate_size"]
-    top = v * d + d + (0 if conf["tie_word_embeddings"] else d * v)
-    return n * per + top
+def param_count(conf: Dict, held: int) -> int:
+    """Parameters the program holds: the sum over the layout's leaves."""
+    import jax
+
+    return sum(math.prod(lf.shape) for lf in jax.tree.leaves(layout(conf, held)))
 
 
 # --------------------------------------------------------------------------
@@ -180,22 +119,11 @@ def param_count(conf: Dict, experts_held: int) -> int:
 
 
 def active_matmul_params(conf: Dict) -> int:
-    """Weights one token multiplies through: the projections, the MLP (for
-    MoE the router over the published experts, top-k experts and the
-    shared expert) and the head.  The embedding is a gather, not a matmul."""
-    d, v, hd = conf["hidden_size"], conf["vocab_size"], head_dim(conf)
-    h, kv = conf["num_attention_heads"], conf["num_key_value_heads"]
-    per = d * h * hd * 2 + d * kv * hd * 2
-    if is_moe(conf):
-        per += d * conf["num_experts"]
-        per += conf["num_experts_per_tok"] * 3 * d * conf["moe_intermediate_size"]
-        per += 3 * d * conf["shared_expert_intermediate_size"]
-    else:
-        per += 3 * d * conf["intermediate_size"]
-    return conf["num_hidden_layers"] * per + d * v
+    """Weights one token multiplies through (MoE: its top-k experts)."""
+    return family(conf).active_matmul_params(conf)
 
 
-def _ctx_sum(q_lens: np.ndarray, cache_pos: np.ndarray) -> float:
+def ctx_sum(q_lens: np.ndarray, cache_pos: np.ndarray) -> float:
     """Keys seen, summed over every valid query: query i of a row at depth
     c attends c + i + 1 keys."""
     q = q_lens.astype(np.float64)
@@ -203,25 +131,14 @@ def _ctx_sum(q_lens: np.ndarray, cache_pos: np.ndarray) -> float:
 
 
 def step_flops(conf: Dict, q_lens: np.ndarray, cache_pos: np.ndarray) -> float:
-    """Model FLOPs of the valid tokens of one step: 2 per weight a token
-    multiplies through, plus QK^T and PV at each token's context."""
-    h, hd, n = conf["num_attention_heads"], head_dim(conf), conf["num_hidden_layers"]
-    tokens = float(np.sum(q_lens))
-    return 2.0 * active_matmul_params(conf) * tokens + 4.0 * n * h * hd * _ctx_sum(q_lens, cache_pos)
+    """Model FLOPs of the valid tokens of one step."""
+    return family(conf).step_flops(conf, q_lens, cache_pos)
 
 
 def attention_call(conf: Dict, q_lens: np.ndarray, cache_pos: np.ndarray,
                    dtype_bytes: int = 2) -> tuple:
-    """(FLOPs, bytes) the attention of one layer needs in one step: QK^T and
-    PV over each row's valid queries and causal keys; Q, the valid K and V
-    span, and O read or written once."""
-    h, kv, hd = conf["num_attention_heads"], conf["num_key_value_heads"], head_dim(conf)
-    flops = 4.0 * h * hd * _ctx_sum(q_lens, cache_pos)
-    live = q_lens > 0
-    q = q_lens[live].astype(np.float64)
-    span = cache_pos[live].astype(np.float64) + q
-    nbytes = dtype_bytes * float(np.sum(2 * q * h * hd + 2 * span * kv * hd))
-    return flops, nbytes
+    """(FLOPs, bytes) the attention kernel of one layer needs in one step."""
+    return family(conf).attention_call(conf, q_lens, cache_pos, dtype_bytes)
 
 
 def least_time(flops: float, nbytes: float, peak: Dict) -> float:
@@ -235,12 +152,11 @@ def bytes_per_param(conf: Dict) -> int:
 
 
 def kv_bytes_per_token(conf: Dict) -> int:
-    return (2 * conf["num_hidden_layers"] * conf["num_key_value_heads"]
-            * head_dim(conf) * bytes_per_param(conf))
+    return family(conf).kv_bytes_per_token(conf)
 
 
-def describe(conf: Dict, experts_held: int) -> str:
-    n = param_count(conf, experts_held)
+def describe(conf: Dict, held: int) -> str:
+    n = param_count(conf, held)
     return (f"{n / 1e9:.3f} B params ({n * bytes_per_param(conf) / 1e9:.2f} GB), "
             f"{active_matmul_params(conf) / 1e9:.3f} B active matmul params, "
             f"KV {kv_bytes_per_token(conf) / 1024:.0f} KiB/token")

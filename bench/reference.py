@@ -4,6 +4,11 @@ nothing of the program.  It reads the weights the benchmark made, one layer
 at a time (cast to float32 inside that layer's call), so that the bf16 model
 and its float32 copy never sit on the chip together.
 
+The decoder layer is the architecture's own, in the family module that the
+file's ``model_type`` names (``bench/families/<model_type>.py``).  What
+every family shares is here: float8 rounding, the linear layer, RMSNorm,
+RoPE, the embedding, the head in blocks of columns, and the comparison.
+
 Every matmul runs at ``Precision.HIGHEST``.  ``quant=True`` is the control
 of the check: the same forward with every linear layer's weights (per output
 channel) and inputs (per token) rounded to float8 e4m3, the precision below
@@ -16,6 +21,7 @@ reference's logit of that token lies below the reference's best.
 
 from __future__ import annotations
 
+import json
 from functools import partial
 from typing import Dict, List, Sequence
 
@@ -23,7 +29,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench.model import head_dim, is_moe
+from bench.model import family
 
 HI = jax.lax.Precision.HIGHEST
 F8_MAX = 448.0          # largest float8 e4m3fn value
@@ -31,27 +37,27 @@ Q_BLOCK = 512           # query rows per attention block
 V_BLOCK = 16384         # head columns per block
 
 
-def _q8(x, axis):
+def q8(x, axis):
     """Round to float8 e4m3 with one scale per slice along ``axis``."""
     s = jnp.max(jnp.abs(x), axis=axis, keepdims=True) / F8_MAX
     s = jnp.where(s > 0, s, 1.0)
     return (x / s).astype(jnp.float8_e4m3fn).astype(jnp.float32) * s
 
 
-def _linear(x, w, quant):
+def linear(x, w, quant):
     """x [..., din] @ w [din, dout] in float32."""
     w = w.astype(jnp.float32)
     if quant:
-        x, w = _q8(x, -1), _q8(w, 0)
+        x, w = q8(x, -1), q8(w, 0)
     return jnp.matmul(x, w, precision=HI)
 
 
-def _rmsnorm(x, w_offset, eps):
+def rmsnorm(x, w_offset, eps):
     x = x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
     return x * (1.0 + w_offset.astype(jnp.float32))
 
 
-def _rope(x, theta):
+def rope(x, theta):
     """Rotate-half RoPE at positions 0..L-1; x [L, heads, hd]."""
     l, _, hd = x.shape
     inv = 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
@@ -61,66 +67,11 @@ def _rope(x, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
 
 
-def _attention(p, x, conf, quant):
-    """Causal GQA self-attention over one sequence x [L, d]."""
-    l = x.shape[0]
-    h, kv, hd = conf["num_attention_heads"], conf["num_key_value_heads"], head_dim(conf)
-    eps = conf["rms_norm_eps"]
-    q = _linear(x, p["wq"], quant).reshape(l, h, hd)
-    k = _linear(x, p["wk"], quant).reshape(l, kv, hd)
-    v = _linear(x, p["wv"], quant).reshape(l, kv, hd)
-    if conf["model_type"] == "qwen3":          # per-head RMSNorm on q and k
-        q = _rmsnorm(q, p["q_norm"], eps)
-        k = _rmsnorm(k, p["k_norm"], eps)
-    q = _rope(q, conf["rope_theta"]).reshape(l // Q_BLOCK, Q_BLOCK, kv, h // kv, hd)
-    k = _rope(k, conf["rope_theta"])
-    kpos = jnp.arange(l)
-
-    def block(args):
-        qb, i = args                                     # [Q, kv, rep, hd]
-        s = jnp.einsum("qgrd,kgd->grqk", qb, k, precision=HI) / np.sqrt(hd)
-        qpos = i * Q_BLOCK + jnp.arange(Q_BLOCK)
-        s = jnp.where(kpos[None, :] <= qpos[:, None], s, -jnp.inf)
-        w = jax.nn.softmax(s, axis=-1)
-        return jnp.einsum("grqk,kgd->qgrd", w, v, precision=HI)
-
-    o = jax.lax.map(block, (q, jnp.arange(l // Q_BLOCK)))
-    return _linear(o.reshape(l, h * hd), p["wo"], quant)
-
-
-def _mlp(p, x, quant):
-    g = _linear(x, p["w_gate"], quant)
-    u = _linear(x, p["w_up"], quant)
-    return _linear(jax.nn.silu(g) * u, p["w_down"], quant)
-
-
-def _moe(p, x, conf, quant):
-    """Softmax router over the published experts, top-k weights (not
-    renormalised unless the config says so), every expert's SwiGLU weighted
-    by its gate, plus the shared expert."""
-    e, k = conf["num_experts"], conf["num_experts_per_tok"]
-    logits = jnp.matmul(x, p["moe"]["router"][:, :e].astype(jnp.float32), precision=HI)
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_w, top_i = jax.lax.top_k(probs, k)
-    if conf["norm_topk_prob"]:
-        top_w = top_w / jnp.sum(top_w, axis=-1, keepdims=True)
-    gates = jnp.zeros_like(probs).at[jnp.arange(x.shape[0])[:, None], top_i].set(top_w)
-
-    def expert(acc, j):
-        w = {n: p["moe"][n][j] for n in ("w_gate", "w_up", "w_down")}
-        return acc + gates[:, j][:, None] * _mlp(w, x, quant), None
-
-    y, _ = jax.lax.scan(expert, jnp.zeros_like(x), jnp.arange(e))
-    return y + _mlp(p["shared_mlp"], x, quant)
-
-
-@partial(jax.jit, static_argnames=("conf_items", "quant"))
-def _layer(p, x, conf_items, quant):
-    conf = dict(conf_items)
-    eps = conf["rms_norm_eps"]
-    x = x + _attention(p["attn"], _rmsnorm(x, p["ln_attn"], eps), conf, quant)
-    h = _rmsnorm(x, p["ln_mlp"], eps)
-    return x + (_moe(p, h, conf, quant) if is_moe(conf) else _mlp(p["mlp"], h, quant))
+@partial(jax.jit, static_argnames=("layer", "conf_json", "quant"))
+def _layer(p, x, layer, conf_json, quant):
+    """The family's ``layer``; the configuration goes in as JSON text, which
+    ``jax.jit`` can hash."""
+    return layer(p, x, json.loads(conf_json), quant)
 
 
 @jax.jit
@@ -130,7 +81,7 @@ def _embed(table, tokens):
 
 @partial(jax.jit, static_argnames=("eps", "quant"))
 def _logits_block(ln_final, head, x, eps, quant):
-    return _linear(_rmsnorm(x, ln_final, eps), head, quant)
+    return linear(rmsnorm(x, ln_final, eps), head, quant)
 
 
 def _logits(ln_final, head, x, eps, quant):
@@ -143,13 +94,6 @@ def _logits(ln_final, head, x, eps, quant):
     ], axis=-1)
 
 
-def _conf_items(conf: Dict) -> tuple:
-    keys = ("hidden_size", "num_attention_heads", "num_key_value_heads",
-            "head_dim", "rms_norm_eps", "rope_theta", "model_type",
-            "num_experts", "num_experts_per_tok", "norm_topk_prob")
-    return tuple((k, conf[k]) for k in keys if k in conf)
-
-
 def _to(x, tree):
     """``x`` moved to the device that holds ``tree`` (a pipeline's layers
     sit on several chips)."""
@@ -159,10 +103,10 @@ def _to(x, tree):
 
 def hidden(params, conf: Dict, tokens: np.ndarray, quant: bool) -> jax.Array:
     """Final hidden states [L, d] of one padded token sequence."""
-    items = _conf_items(conf)
+    conf_json, layer = json.dumps(conf, sort_keys=True), family(conf).layer
     x = _embed(params["embed"], _to(jnp.asarray(tokens), params["embed"]))
     for lp in params["layers"]:
-        x = _layer(lp, _to(x, lp), items, quant)
+        x = _layer(lp, _to(x, lp), layer, conf_json, quant)
     return _to(x, params["ln_final"])
 
 

@@ -5,7 +5,9 @@
 
 From the root of a checkout.  The cell is an entry of ``BENCHMARK.json``;
 its configuration, traffic mix and metric readers are files under
-``bench/`` found by name (``bench/spec.py``).  The run:
+``bench/`` found by name (``bench/spec.py``), and so is everything that
+depends on the architecture: the family module that the configuration's
+``model_type`` names (``bench/families/<model_type>.py``).  The run:
 
 1. stops with exit code 2 and no result unless JAX's first device is a TPU
    listed in ``bench/peaks.json`` and there are as many as the cell asks for;
@@ -128,7 +130,7 @@ def build(cell: spec.Cell, seed: int, devices, marks: dict):
             return home[path[1].idx]
         return first if top == "embed" else last
 
-    held = cfg.n_experts_padded or cfg.n_experts
+    held = model.experts_held(conf, cfg)
     params = model.make_params(conf, held, seed, device_of)
     jax.block_until_ready(params)
     marks["params"] = time.perf_counter()
@@ -273,8 +275,8 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool, devices,
     in_window = clock.compiles - compiles0
     setup_s = drv.t_start - t_start       # to the window's opening
 
-    peak_bytes = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
-                     for d in devices)
+    peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use", 0) for d in devices]
+    peak_bytes = max(peaks)
     summary = None
     if trace:
         from bench import devtrace
@@ -312,10 +314,14 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool, devices,
     held_open, held_mean = win.slots_held()
     log(f"slots held: {held_open} at the opening, {held_mean:.2f} on average "
         f"over the window, of {sv['slots']}")
-    log(f"peak device memory {peak_bytes / 2**30:.3f} GiB")
+    log(f"peak device memory {peak_bytes / 2**30:.3f} GiB" + (
+        "; by chip " + ", ".join(f"{p / 2**30:.3f}" for p in peaks)
+        if len(peaks) > 1 else ""))
     if summary is not None:
         log(f"trace: window {summary.window_s:.3f} s over {len(win.trace_steps)} "
-            f"steps, busy {summary.busy_s:.3f} s, kernels {summary.kernel_s} "
+            f"steps, busy {summary.busy_s:.3f} s (by chip "
+            + ", ".join(f"{v:.3f}" for v in summary.busy_by_device.values())
+            + f"), kernels {summary.kernel_s} "
             f"calls {summary.kernel_calls}")
     for k, v in metrics.items():
         log(f"{k} = {v['value']:.6g} {v['unit']}")

@@ -9,7 +9,9 @@ found by name, never from a table in code:
   ``bench/metrics/mixed_step_ms.py``.  A reader defines
   ``reduce(run) -> float | None`` (``None``: nothing to read in this run)
   and may define ``KERNELS``, a map of labels to regexes that name device
-  operations in the trace.
+  operations in the trace;
+* architecture family ``<model_type>`` (the configuration's own key):
+  ``bench/families/<model_type>.py`` (see ``bench/families/__init__.py``).
 """
 
 from __future__ import annotations
@@ -61,17 +63,47 @@ class Run:
     trace: Any = None               # bench.devtrace.Summary of a traced run
 
 
+FAMILY_API = ("program_config", "experts_held", "layout", "head_dim",
+              "active_matmul_params", "step_flops", "attention_call",
+              "kv_bytes_per_token", "layer")
+_families: Dict[str, ModuleType] = {}
+
+
+def _load(path: Path, name: str, needs: tuple) -> ModuleType:
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    missing = [f for f in needs if not callable(getattr(mod, f, None))]
+    if missing:
+        raise ValueError(f"{path} defines no {', '.join(missing)}")
+    return mod
+
+
 def _load_reader(bench: Path, name: str) -> ModuleType:
     for stem in (name, name.split(".")[0]):
         path = bench / "metrics" / f"{stem}.py"
         if path.is_file():
-            spec = importlib.util.spec_from_file_location(f"bench_metric_{stem}", path)
-            mod = importlib.util.module_from_spec(spec)
-            spec.loader.exec_module(mod)
-            if not callable(getattr(mod, "reduce", None)):
-                raise ValueError(f"{path} defines no reduce(run)")
-            return mod
+            return _load(path, f"bench_metric_{stem}", ("reduce",))
     raise FileNotFoundError(f"no reader for metric {name!r} under {bench / 'metrics'}")
+
+
+def load_family(bench: Path, model_type: str) -> ModuleType:
+    """The family module ``bench/families/<model_type>.py``, loaded anew;
+    ``family(model_type)`` returns it from then on."""
+    path = Path(bench) / "families" / f"{model_type}.py"
+    if not path.is_file():
+        raise FileNotFoundError(
+            f"no family module for model_type {model_type!r}: {path} does not exist")
+    _families[model_type] = _load(path, f"bench_family_{model_type}", FAMILY_API)
+    return _families[model_type]
+
+
+def family(model_type: str) -> ModuleType:
+    """The family module of ``model_type``: the one last loaded, else the
+    harness's own ``bench/families/<model_type>.py``."""
+    if model_type not in _families:
+        load_family(Path(__file__).resolve().parent, model_type)
+    return _families[model_type]
 
 
 def _json(path: Path) -> Dict[str, Any]:
@@ -93,10 +125,12 @@ def load_cell(root: Path, name: str) -> Cell:
             if name in m.get("workloads", [name]):
                 metrics.append(Metric(m["name"], m["unit"], e2e,
                                       _load_reader(bench, m["name"])))
+    config = _json(bench / "configs" / f"{entry['config']}.json")
+    load_family(bench, config["model_type"])
     return Cell(
         name=name,
         chips=int(entry["chips"]),
-        config=_json(bench / "configs" / f"{entry['config']}.json"),
+        config=config,
         traffic=_json(bench / "traffic" / f"{entry['traffic']}.json"),
         metrics=metrics,
     )

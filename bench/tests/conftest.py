@@ -15,11 +15,13 @@ TINY_CELLS = {
 
 @pytest.fixture
 def tiny_root(tmp_path):
-    """A checkout root holding only data files: the repo's metric readers,
-    the tiny configurations and mixes, and a BENCHMARK.json whose cells and
-    metrics name them.  Nothing in the harness code knows these names."""
+    """A checkout root holding only the files found by name: the repo's
+    metric readers and architecture families, the tiny configurations and
+    mixes, and a BENCHMARK.json whose cells and metrics name them.  Nothing
+    in the harness code knows these names."""
     bench = tmp_path / "bench"
-    shutil.copytree(REPO / "bench" / "metrics", bench / "metrics")
+    for sub in ("metrics", "families"):
+        shutil.copytree(REPO / "bench" / sub, bench / sub)
     for sub in ("configs", "traffic"):
         (bench / sub).mkdir()
     bj = json.loads((REPO / "BENCHMARK.json").read_text())
