@@ -18,6 +18,7 @@ _ARCH_MODULES = {
     "qwen2-vl-7b": "qwen2_vl_7b",
     "mamba2-130m": "mamba2_130m",
     "zamba2-2.7b": "zamba2_2p7b",
+    "moonlight-16b-a3b": "moonlight_16b_a3b",
 }
 
 ARCHS: List[str] = list(_ARCH_MODULES)

@@ -12,7 +12,7 @@ from __future__ import annotations
 
 import dataclasses
 from dataclasses import dataclass, field, replace
-from typing import Optional, Sequence, Tuple
+from typing import Dict, Optional, Sequence, Tuple
 
 
 @dataclass(frozen=True)
@@ -39,6 +39,16 @@ class ModelConfig:
     tie_embeddings: bool = False
     scale_embed: bool = False                # gemma family: x *= sqrt(d_model)
     attn_logit_scale: Optional[float] = None  # override 1/sqrt(head_dim)
+    norm_eps: float = 1e-6                   # every RMSNorm's epsilon
+
+    # --- multi-head latent attention (deepseek-v3 MLA; 0 = off) ---------------
+    # K and V of every head are up-projections of one per-token latent of
+    # ``kv_lora_rank`` (RMS-normed) plus ``qk_rope_head_dim`` rope dims
+    # shared by all heads; the cache holds only [latent, rope] a token
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
 
     # --- MoE ----------------------------------------------------------------
     n_experts: int = 0
@@ -50,6 +60,11 @@ class ModelConfig:
     dense_parallel_ff: bool = False          # arctic: dense FFN residual ∥ MoE
     router_norm_topk: bool = True
     capacity_factor: float = 1.25
+    # deepseek-v3 routing: sigmoid scores, top-k chosen on score + a learned
+    # correction bias, weighted by the unbiased scores times ``routed_scaling``
+    router_scoring: str = "softmax"          # softmax | sigmoid
+    routed_scaling: float = 1.0
+    first_k_dense: int = 0                   # leading layers with a dense MLP of d_ff
 
     # --- enc-dec (seamless) --------------------------------------------------
     n_encoder_layers: int = 0
@@ -72,12 +87,11 @@ class ModelConfig:
     # --- parallelism policy --------------------------------------------------
     # pure DP×EP layout: replicate dense trunk, shard batch over (data, model)
     # and experts over data — right for small-active MoE where TP psums
-    # dominate (see EXPERIMENTS.md §Perf / qwen2-moe iteration 2)
+    # dominate
     prefer_pure_dp: bool = False
     # weight-gathered MoE: slice tokens over the TP axis inside the MoE block
     # and all-gather expert weights instead of running the (identical)
     # all-to-all on every TP rank — wins when tokens·D ≫ expert bytes
-    # (see EXPERIMENTS.md §Perf / arctic iteration)
     moe_gather_weights: bool = False
 
     # --- numerics / impl ---------------------------------------------------------
@@ -91,6 +105,30 @@ class ModelConfig:
     @property
     def resolved_head_dim(self) -> int:
         return self.head_dim if self.head_dim is not None else self.d_model // self.n_heads
+
+    @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def latent_cache_width(self) -> int:
+        """MLA: lanes a cached token takes, ``[latent, rope]`` zero-padded to
+        a multiple of 128 (an unaligned row makes XLA lay the pool out
+        page-minor and copy it back for the kernel at every call)."""
+        return -(-(self.kv_lora_rank + self.qk_rope_head_dim) // 128) * 128
+
+    def is_moe_layer(self, i: int) -> bool:
+        """Layer ``i`` routes over experts (the first ``first_k_dense``
+        layers of a MoE model run a dense MLP of ``d_ff``)."""
+        return bool(self.family == "moe" or self.n_experts) and i >= self.first_k_dense
+
+    def cache_widths(self) -> Dict[str, Tuple[int, int]]:
+        """Per-layer KV cache entries as (heads, width) a token: K and V of
+        every KV head, or MLA's one latent row of ``[latent, rope]``."""
+        if self.is_mla:
+            return {"kv": (1, self.latent_cache_width)}
+        hd = self.resolved_head_dim
+        return {"k": (self.n_kv_heads, hd), "v": (self.n_kv_heads, hd)}
 
     @property
     def is_attention_free(self) -> bool:
@@ -142,6 +180,11 @@ class ModelConfig:
             ssm_headdim=16 if self.ssm_state else 64,
             ssm_chunk=16 if self.ssm_state else 128,
             shared_attn_every=min(self.shared_attn_every, 2) if self.shared_attn_every else 0,
+            kv_lora_rank=32 if self.is_mla else 0,
+            qk_nope_head_dim=16 if self.is_mla else 0,
+            qk_rope_head_dim=16 if self.is_mla else 0,
+            v_head_dim=16 if self.is_mla else 0,
+            first_k_dense=min(self.first_k_dense, 1),
             attn_chunk=64,
             dtype="float32",
             scan_layers=False,

@@ -61,6 +61,50 @@ def _elt(g, op, x_in, elems, dtype=BF16, extra_inputs=(), params=0.0):
 # --------------------------------------------------------------------------
 
 
+def _attention_counts(cfg: ModelConfig, s: int) -> Tuple[float, float, float, float]:
+    """One layer's attention over ``s`` tokens: (FLOPs, their quadratic
+    score/context part, param bytes, cached bytes).
+
+    GQA: Q/K/V/O projections, QK^T and PV at ``head_dim``, K and V of the
+    KV heads cached.  MLA (absorbed, as served): ``wq``, ``wkv_a``, each
+    head's query folded through its key up-projection and its context
+    lifted by the value one (the two halves of ``wkv_b``, so as many
+    weights a token), ``wo``; scores over the latent + rope width, context
+    over the latent; one latent row a token cached, at the lanes the
+    program holds."""
+    d, h = cfg.d_model, cfg.n_heads
+    if cfg.is_mla:
+        r, dn, dr, dv = (cfg.kv_lora_rank, cfg.qk_nope_head_dim,
+                         cfg.qk_rope_head_dim, cfg.v_head_dim)
+        weights = d * h * (dn + dr) + d * (r + dr) + r * h * (dn + dv) + h * dv * d
+        quad = 2.0 * s * s * h * ((r + dr) + r)
+        return (2.0 * s * weights + quad, quad, (weights + r) * BF16,
+                1.0 * s * cfg.latent_cache_width * BF16)
+    hd, kv = cfg.resolved_head_dim, cfg.n_kv_heads
+    quad = 4.0 * s * s * h * hd
+    flops = 2.0 * s * d * (h * hd + 2 * kv * hd) + quad + 2.0 * s * h * hd * d
+    params = (d * (h + 2 * kv) * hd + h * hd * d) * BF16
+    return flops, quad, params, 2.0 * s * kv * hd * BF16
+
+
+def _ffn_counts(cfg: ModelConfig, s: int, layer: int) -> Tuple[float, float]:
+    """Layer ``layer``'s feed-forward part over ``s`` tokens: (FLOPs, param
+    bytes); a MoE layer counts its top-k and shared experts, a leading
+    dense layer of a MoE model its MLP of ``d_ff``."""
+    d = cfg.d_model
+    if not cfg.is_moe_layer(layer):
+        return 6.0 * s * d * cfg.d_ff, 3.0 * d * cfg.d_ff * BF16
+    ff_flops = 6.0 * s * d * cfg.moe_d_ff * cfg.top_k
+    ff_params = 3.0 * d * cfg.moe_d_ff * (cfg.n_experts_padded or cfg.n_experts) * BF16
+    if cfg.dense_parallel_ff:
+        ff_flops += 6.0 * s * d * cfg.d_ff
+        ff_params += 3 * d * cfg.d_ff * BF16
+    if cfg.n_shared_experts:
+        ff_flops += 6.0 * s * d * cfg.shared_d_ff
+        ff_params += 3 * d * cfg.shared_d_ff * BF16
+    return ff_flops, ff_params
+
+
 def transformer_graph(
     cfg: ModelConfig, *, seq_len: int, granularity: str = "fine"
 ) -> OpGraph:
@@ -80,18 +124,16 @@ def transformer_graph(
     )
     x = embed
 
-    # per-layer KV-cache residency: K and V tensors for the full sequence,
-    # kept alive per in-flight request (multiplied by serving slots in Eq. 5)
-    layer_kv_bytes = 2.0 * s * kv * hd * BF16
+    # per-layer KV-cache residency: K and V tensors (MLA: the latent rows)
+    # for the full sequence, kept alive per in-flight request (multiplied by
+    # serving slots in Eq. 5)
+    attn_flops, attn_quad, attn_params, layer_kv_bytes = _attention_counts(cfg, s)
 
     if granularity in ("layer", "block"):
         for i in range(cfg.n_layers):
-            # the 4·s²·h·hd score/context term is quadratic in the attended
-            # span; recorded in meta so token rescaling (chunked prefill
-            # costing) can bill it queries × keys instead of linearly
-            attn_quad = 4.0 * s * s * h * hd
-            attn_flops = 2.0 * s * d * (h * hd + 2 * kv * hd) + attn_quad + 2.0 * s * h * hd * d
-            attn_params = (d * (h + 2 * kv) * hd + h * hd * d) * BF16
+            # the score/context term is quadratic in the attended span;
+            # recorded in meta so token rescaling (chunked prefill costing)
+            # can bill it queries × keys instead of linearly
             a = g.add(
                 "attention",
                 inputs=[x],
@@ -102,19 +144,7 @@ def transformer_graph(
                 output_bytes=elems * BF16,
                 meta={"quad_flops": attn_quad},
             )
-            if cfg.n_experts:
-                e_act = cfg.top_k
-                ff_flops = 6.0 * s * d * cfg.moe_d_ff * e_act
-                ff_params = 3.0 * d * cfg.moe_d_ff * (cfg.n_experts_padded or cfg.n_experts) * BF16
-                if cfg.dense_parallel_ff:
-                    ff_flops += 6.0 * s * d * cfg.d_ff
-                    ff_params += 3 * d * cfg.d_ff * BF16
-                if cfg.n_shared_experts:
-                    ff_flops += 6.0 * s * d * cfg.shared_d_ff
-                    ff_params += 3 * d * cfg.shared_d_ff * BF16
-            else:
-                ff_flops = 6.0 * s * d * cfg.d_ff
-                ff_params = 3.0 * d * cfg.d_ff * BF16
+            ff_flops, ff_params = _ffn_counts(cfg, s, i)
             if granularity == "block":
                 # fold attention + FFN into one placeable block
                 g.remove_node(a)
@@ -130,7 +160,7 @@ def transformer_graph(
                 )
             else:
                 f = g.add(
-                    "moe" if cfg.n_experts else "mlp",
+                    "moe" if cfg.is_moe_layer(i) else "mlp",
                     inputs=[a],
                     flops=ff_flops,
                     bytes_accessed=4 * elems * BF16 + ff_params,
@@ -153,15 +183,33 @@ def transformer_graph(
         return g
 
     # ---- fine granularity --------------------------------------------------
+    # score and context widths a head: GQA hd and hd; MLA (absorbed) the
+    # latent + rope and the latent
+    kw, vw = (cfg.kv_lora_rank + cfg.qk_rope_head_dim, cfg.kv_lora_rank) if cfg.is_mla else (hd, hd)
     for i in range(cfg.n_layers):
         ln1 = _elt(g, "rmsnorm", x, elems, params=d * 4)
-        q = _matmul(g, f"L{i}.wq", ln1, s, d, h * hd)
-        # the K/V projections produce the cached tensors: each carries half the
-        # layer's per-request KV residency
-        k = _matmul(g, f"L{i}.wk", ln1, s, d, kv * hd, kv_bytes=layer_kv_bytes / 2)
-        v = _matmul(g, f"L{i}.wv", ln1, s, d, kv * hd, kv_bytes=layer_kv_bytes / 2)
-        qr = _elt(g, "rope", q, s * h * hd)
-        kr = _elt(g, "rope", k, s * kv * hd)
+        if cfg.is_mla:
+            r, dn, dr = cfg.kv_lora_rank, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+            q = _matmul(g, f"L{i}.wq", ln1, s, d, h * (dn + dr))
+            # the latent projection produces the cached row (K and V at once)
+            k = _matmul(g, f"L{i}.wkv_a", ln1, s, d, r + dr, kv_bytes=layer_kv_bytes)
+            k = v = _elt(g, "rmsnorm", k, s * r, params=r * 4)
+            q = g.add(  # each head's q_nope folded through its key up-projection
+                "matmul", inputs=[q], flops=2.0 * s * h * dn * r,
+                bytes_accessed=(s * h * (dn + r) + h * dn * r) * BF16,
+                param_bytes=h * dn * r * BF16, output_bytes=s * h * kw * BF16,
+                meta={"name": f"L{i}.absorb_k"},
+            )
+            qr = _elt(g, "rope", q, s * h * dr)
+            kr = _elt(g, "rope", k, s * dr)
+        else:
+            q = _matmul(g, f"L{i}.wq", ln1, s, d, h * hd)
+            # the K/V projections produce the cached tensors: each carries
+            # half the layer's per-request KV residency
+            k = _matmul(g, f"L{i}.wk", ln1, s, d, kv * hd, kv_bytes=layer_kv_bytes / 2)
+            v = _matmul(g, f"L{i}.wv", ln1, s, d, kv * hd, kv_bytes=layer_kv_bytes / 2)
+            qr = _elt(g, "rope", q, s * h * hd)
+            kr = _elt(g, "rope", k, s * kv * hd)
         # score/context matmuls and the mask/softmax between them are
         # quadratic in the attended span — meta records each node's
         # quadratic flops/bytes share so token rescaling bills them
@@ -169,13 +217,13 @@ def transformer_graph(
         scores = g.add(
             "matmul",  # q·kᵀ
             inputs=[qr, kr],
-            flops=2.0 * s * s * h * hd,
-            bytes_accessed=(2 * s * h * hd + s * s * h) * BF16,
+            flops=2.0 * s * s * h * kw,
+            bytes_accessed=(2 * s * h * kw + s * s * h) * BF16,
             output_bytes=s * s * h * BF16,
             # quad_out_bytes: the s×s output payload itself is quadratic, so
             # a stage cut right after this node bills its comm queries × keys
             # too (scale_edge_bytes), not linearly in the chunk
-            meta={"quad_flops": 2.0 * s * s * h * hd,
+            meta={"quad_flops": 2.0 * s * s * h * kw,
                   "quad_bytes": s * s * h * BF16,
                   "quad_out_bytes": s * s * h * BF16},
         )
@@ -190,17 +238,27 @@ def transformer_graph(
         ctx = g.add(
             "matmul",  # probs·V
             inputs=[sm, v],
-            flops=2.0 * s * s * h * hd,
-            bytes_accessed=(s * s * h + 2 * s * h * hd) * BF16,
-            output_bytes=s * h * hd * BF16,
-            meta={"quad_flops": 2.0 * s * s * h * hd,
+            flops=2.0 * s * s * h * vw,
+            bytes_accessed=(s * s * h + 2 * s * h * vw) * BF16,
+            output_bytes=s * h * vw * BF16,
+            meta={"quad_flops": 2.0 * s * s * h * vw,
                   "quad_bytes": s * s * h * BF16},
         )
-        wo = _matmul(g, f"L{i}.wo", ctx, s, h * hd, d)
+        if cfg.is_mla:
+            dv = cfg.v_head_dim
+            ctx = g.add(  # each head's latent context lifted by its value projection
+                "matmul", inputs=[ctx], flops=2.0 * s * h * vw * dv,
+                bytes_accessed=(s * h * (vw + dv) + h * vw * dv) * BF16,
+                param_bytes=h * vw * dv * BF16, output_bytes=s * h * dv * BF16,
+                meta={"name": f"L{i}.lift_v"},
+            )
+            wo = _matmul(g, f"L{i}.wo", ctx, s, h * dv, d)
+        else:
+            wo = _matmul(g, f"L{i}.wo", ctx, s, h * hd, d)
         res1 = _elt(g, "add", wo, elems, extra_inputs=(x,))
 
         ln2 = _elt(g, "rmsnorm", res1, elems, params=d * 4)
-        if cfg.n_experts:
+        if cfg.is_moe_layer(i):
             router = _matmul(g, f"L{i}.router", ln2, s, d, cfg.n_experts)
             branches = []
             e_pad = cfg.n_experts_padded or cfg.n_experts

@@ -53,11 +53,11 @@ def _flash_kernel(
                 # 0 = idle)
     q_ref,      # [BQ, D] folded rows: position r // rep of head r % rep
     k_ref,      # [BK, D]
-    v_ref,      # [BK, D]
-    o_ref,      # [BQ, D]
+    v_ref,      # [BK, Dv] (Dv = D but for MLA's latent)
+    o_ref,      # [BQ, Dv]
     m_scr,      # VMEM [BQ, 1]    running max
     l_scr,      # VMEM [BQ, 1]    running denominator
-    acc_scr,    # VMEM [BQ, D]    running numerator
+    acc_scr,    # VMEM [BQ, Dv]   running numerator
     *,
     scale: float,
     causal: bool,
@@ -170,6 +170,8 @@ def flash_attention_pallas_paged(
     kv_heads: int = 1,
     rep: int = 1,
     block_q: int = DEFAULT_BQ,
+    v_dim: Optional[int] = None,             # V's width (None: V's own)
+    name: str = "flash_attention_paged",
     interpret: bool = False,
 ) -> jax.Array:
     """Flash attention reading K/V *through a page table* — no logical-row
@@ -182,8 +184,14 @@ def flash_attention_pallas_paged(
 
     The pool is kv-head-major, so one (head, page) block is a contiguous
     ``[P, D]`` tile: its last two dims are the pool's own, which is what the
-    TPU's (8, 128) block rule accepts for any page size and head_dim."""
+    TPU's (8, 128) block rule accepts for any page size and head_dim.
+
+    ``v_dim`` narrower than ``pool_v``'s width reads V as the leading
+    ``v_dim`` columns of each page (a multiple of 128): MLA passes its one
+    latent pool as both K (latent + rope) and V (latent), so a page is
+    fetched from one buffer for both."""
     bh, rows, d = q.shape
+    dv = pool_v.shape[-1] if v_dim is None else v_dim
     page_tokens = pool_k.shape[2]
     pages_per_slot = table.shape[1]
     assert rows % block_q == 0, (rows, block_q)
@@ -208,12 +216,12 @@ def flash_attention_pallas_paged(
         rep=rep,
     )
 
-    def _kv_spec():
-        # block (1, 1, P, D): index maps pick (kv head, physical page, 0, 0)
-        # — tbl is the third scalar-prefetch ref, available at
+    def _kv_spec(width):
+        # block (1, 1, P, width): index maps pick (kv head, physical page,
+        # 0, 0) — tbl is the third scalar-prefetch ref, available at
         # DMA-schedule time exactly like the (offs, lens) rows
         return pl.BlockSpec(
-            (None, None, page_tokens, d),
+            (None, None, page_tokens, width),
             lambda b, i, j, offs, lens, tbl: (
                 b % kv_heads, tbl[b // kv_heads, j], 0, 0
             ),
@@ -224,22 +232,22 @@ def flash_attention_pallas_paged(
         grid=(bh, n_q, pages_per_slot),
         in_specs=[
             pl.BlockSpec((None, block_q, d), lambda b, i, j, *_: (b, i, 0)),
-            _kv_spec(),
-            _kv_spec(),
+            _kv_spec(d),
+            _kv_spec(dv),
         ],
-        out_specs=pl.BlockSpec((None, block_q, d), lambda b, i, j, *_: (b, i, 0)),
+        out_specs=pl.BlockSpec((None, block_q, dv), lambda b, i, j, *_: (b, i, 0)),
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, dv), jnp.float32),
         ],
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((bh, rows, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((bh, rows, dv), q.dtype),
         interpret=interpret,
-        name="flash_attention_paged",
+        name=name,
     )(
         q_offsets.astype(jnp.int32),
         q_lens.astype(jnp.int32),

@@ -143,7 +143,7 @@ def flash_attention(
 
 @functools.partial(
     jax.jit,
-    static_argnames=("causal", "window", "softcap", "scale", "interpret"),
+    static_argnames=("causal", "window", "softcap", "scale", "v_dim", "interpret"),
 )
 def flash_attention_paged(
     q: jax.Array,            # [B, Sq, H, D]
@@ -157,6 +157,7 @@ def flash_attention_paged(
     causal: bool = True,
     window: Optional[int] = None,
     softcap: Optional[float] = None,
+    v_dim: Optional[int] = None,
     interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Model-layout wrapper for the PAGED flash kernel: K/V live in a
@@ -169,7 +170,14 @@ def flash_attention_paged(
     Unmapped table entries (−1) are clamped to the reserved trash page
     ``num_pages``; whatever garbage it holds sits at logical positions
     beyond every row's written span, where the causal mask already
-    guarantees exact zero attention weight."""
+    guarantees exact zero attention weight.
+
+    ``v_dim`` below the pool's width reads V as each page's leading
+    ``v_dim`` columns: MLA's absorbed form passes its one latent pool
+    ``[1, num_pages + 1, P, W]`` (rows ``[c, k_rope]``) as both K and V, so
+    per-head K and V are never built, and its 16 query heads stack on the
+    one latent "KV head".  That call's kernel is named
+    ``flash_attention_paged_latent``.  Returns ``[B, Sq, H, v_dim or D]``."""
     interpret = resolve_interpret(interpret)
     b, sq, h, d = q.shape
     kv = pool_k.shape[0]
@@ -179,6 +187,7 @@ def flash_attention_paged(
     offs = jnp.repeat(_row_offsets(q_pos, b, 0), kv)
     lens = None if q_lens is None else jnp.repeat(q_lens.astype(jnp.int32), kv)
     qk, bq = _fold_queries(q, kv)
+    latent = v_dim not in (None, d)
 
     out = flash_attention_pallas_paged(
         qk, pool_k, pool_v, tbl,
@@ -192,5 +201,7 @@ def flash_attention_paged(
         rep=h // kv,
         block_q=bq,
         interpret=interpret,
+        v_dim=v_dim,
+        name="flash_attention_paged_latent" if latent else "flash_attention_paged",
     )
     return _unfold(out, b, sq, h)

@@ -121,6 +121,8 @@ def softcap(x: jax.Array, cap: Optional[float]) -> jax.Array:
 
 
 def attention_params(key, cfg: ModelConfig, dtype) -> Params:
+    if cfg.is_mla:
+        return latent_attention_params(key, cfg, dtype)
     hd = cfg.resolved_head_dim
     ks = jax.random.split(key, 4)
     p = {
@@ -217,27 +219,29 @@ def _chunked_attention(q, k, v, q_pos, k_pos, *, causal, window, cap, scale, chu
 
     m0 = jnp.full((b, g, r, sq), -jnp.inf, dtype=jnp.float32)
     l0 = jnp.zeros((b, g, r, sq), dtype=jnp.float32)
-    a0 = jnp.zeros((b, g, r, sq, d), dtype=jnp.float32)
+    a0 = jnp.zeros((b, g, r, sq, v.shape[-1]), dtype=jnp.float32)
     (m_f, l_f, acc), _ = jax.lax.scan(body, (m0, l0, a0), (kc, vc, pc))
     out = acc / jnp.maximum(l_f[..., None], 1e-30)
     # [B,G,R,Sq,D] -> [B,Sq,G,R,D]
     return out.transpose(0, 3, 1, 2, 4).astype(q.dtype)
 
 
-def _paged_update(pool_k, pool_v, table, k, v, cache_pos, q_lens):
-    """Scatter this call's new K/V into the page pool through ``table``.
+def _paged_update(pools, table, news, cache_pos, q_lens):
+    """Scatter this call's new cache rows into their page pools through
+    ``table``; returns the updated pools.
 
-    Pools are kv-head-major, ``[KV, num_pages + 1, P, hd]``.  Flat-pool
-    indexing: token ``i`` of row ``b`` lands at physical position
-    ``table[b, pos // P] * P + pos % P`` of every head's pool, with
-    ``pos = cache_pos[b] + i``.
+    Each pool is head-major, ``[heads, num_pages + 1, P, width]``, and
+    ``news`` holds its ``[B, Sq, heads, width]`` rows (GQA: K and V; MLA:
+    one latent row).  Flat-pool indexing: token ``i`` of row ``b`` lands
+    at physical position ``table[b, pos // P] * P + pos % P`` of every
+    head's pool, with ``pos = cache_pos[b] + i``.
     Rows/tokens outside their valid span (``i ≥ q_lens[b]``), and any
     unmapped table entry, are redirected to the reserved TRASH page (the
     pool's last page) — the scatter can therefore never corrupt a real
     page, which is what lets idle rows of a fused mixed batch ride along.
     """
-    b, sq = k.shape[0], k.shape[1]
-    kv, np1, page_tokens, hd = pool_k.shape
+    b, sq = news[0].shape[0], news[0].shape[1]
+    _, np1, page_tokens, _ = pools[0].shape
     pages_per_slot = table.shape[1]
     trash = np1 - 1
     cp = jnp.broadcast_to(jnp.asarray(cache_pos, jnp.int32), (b,))
@@ -253,14 +257,49 @@ def _paged_update(pool_k, pool_v, table, k, v, cache_pos, q_lens):
     page = jnp.take_along_axis(table, pidx, axis=1)            # [B, Sq]
     page = jnp.where(valid & (page >= 0), page, trash)
     dest = (page * page_tokens + pos % page_tokens).reshape(-1)
-    flat = (kv, np1 * page_tokens, hd)
 
     def put(pool, new):
-        # [B, Sq, KV, hd] → [KV, B·Sq, hd], scattered along the token axis
-        item = new.astype(pool.dtype).reshape(b * sq, kv, hd).transpose(1, 0, 2)
+        # [B, Sq, heads, w] → [heads, B·Sq, w], scattered along the token axis
+        heads, _, _, w = pool.shape
+        item = new.astype(pool.dtype).reshape(b * sq, heads, w).transpose(1, 0, 2)
+        flat = (heads, np1 * page_tokens, w)
         return pool.reshape(flat).at[:, dest].set(item).reshape(pool.shape)
 
-    return put(pool_k, k), put(pool_v, v)
+    return tuple(put(pool, new) for pool, new in zip(pools, news))
+
+
+def _dense_cache_write(cache, new, cache_pos, q_lens, ragged):
+    """Write this call's rows ``new[name]`` ``[B, Sq, heads, w]`` into the
+    dense cache rows ``cache[name]`` ``[B, Smax, heads, w]`` at
+    ``cache_pos``; returns the updated dict."""
+    sq = next(iter(new.values())).shape[1]
+    if ragged and q_lens is not None:
+        # fused mixed batch: row b writes new[b, :q_lens[b]] at its own
+        # depth and NOTHING else.  dynamic_update_slice cannot express
+        # this — it clamps start indices, so a short row near max_len
+        # would slide backwards and corrupt valid KV — so scatter via a
+        # masked gather-from-source instead: cache slot t of row b takes
+        # chunk token (t - cache_pos[b]) iff that lands in [0, q_lens[b]).
+        smax = next(iter(cache.values())).shape[1]
+        src = jnp.arange(smax, dtype=jnp.int32)[None, :] - cache_pos[:, None]
+        valid = (src >= 0) & (src < q_lens[:, None])           # [B, Smax]
+        idx = jnp.clip(src, 0, sq - 1)[:, :, None, None]
+        w4 = valid[:, :, None, None]
+
+        def put(c, n):
+            return jnp.where(w4, jnp.take_along_axis(n.astype(c.dtype), idx, axis=1), c)
+    elif ragged:
+        # each row writes at its own depth (per-slot KV write index)
+        upd = lambda c, n, pos: jax.lax.dynamic_update_slice_in_dim(c, n, pos, axis=0)
+
+        def put(c, n):
+            return jax.vmap(upd)(c, n.astype(c.dtype), cache_pos)
+    else:
+        def put(c, n):
+            return jax.lax.dynamic_update_slice_in_dim(
+                c, n.astype(c.dtype), cache_pos, axis=1
+            )
+    return {name: put(cache[name], n) for name, n in new.items()}
 
 
 def _paged_view(pool, table):
@@ -309,9 +348,9 @@ def multihead_attention(
         k, v = cross_kv
 
     if cfg.qk_norm:
-        q = rmsnorm(q, p["q_norm"])
+        q = rmsnorm(q, p["q_norm"], cfg.norm_eps)
         if cross_kv is None:
-            k = rmsnorm(k, p["k_norm"])
+            k = rmsnorm(k, p["k_norm"], cfg.norm_eps)
 
     # RoPE (self-attention only; seamless cross-attn has no rope on kv)
     # q_pos: [Sq] shared across rows, or [B, Sq] per-row (ragged decode —
@@ -336,7 +375,7 @@ def multihead_attention(
     if paged:
         pool_k, pool_v, table = kv_cache["k"], kv_cache["v"], kv_cache["table"]
         cp = cache_pos if cache_pos is not None else 0
-        pool_k, pool_v = _paged_update(pool_k, pool_v, table, k, v, cp, q_lens)
+        pool_k, pool_v = _paged_update((pool_k, pool_v), table, (k, v), cp, q_lens)
         new_cache = {"k": pool_k, "v": pool_v, "table": table}
         # logical cache depth = pages_per_slot · page_tokens; reads beyond a
         # row's written span hit stale/trash data that the causal-vs-q_pos
@@ -345,35 +384,10 @@ def multihead_attention(
         causal = True
     elif kv_cache is not None and cross_kv is None:
         # decode / incremental prefill: write new kv at cache_pos
-        kcache, vcache = kv_cache["k"], kv_cache["v"]
-        if ragged and q_lens is not None:
-            # fused mixed batch: row b writes k[b, :q_lens[b]] at its own
-            # depth and NOTHING else.  dynamic_update_slice cannot express
-            # this — it clamps start indices, so a short row near max_len
-            # would slide backwards and corrupt valid KV — so scatter via a
-            # masked gather-from-source instead: cache slot t of row b takes
-            # chunk token (t - cache_pos[b]) iff that lands in [0, q_lens[b]).
-            smax = kcache.shape[1]
-            src = jnp.arange(smax, dtype=jnp.int32)[None, :] - cache_pos[:, None]
-            valid = (src >= 0) & (src < q_lens[:, None])           # [B, Smax]
-            idx = jnp.clip(src, 0, sq - 1)[:, :, None, None]
-            kg = jnp.take_along_axis(k.astype(kcache.dtype), idx, axis=1)
-            vg = jnp.take_along_axis(v.astype(vcache.dtype), idx, axis=1)
-            w4 = valid[:, :, None, None]
-            kcache = jnp.where(w4, kg, kcache)
-            vcache = jnp.where(w4, vg, vcache)
-        elif ragged:
-            # each row writes at its own depth (per-slot KV write index)
-            upd = lambda c, new, pos: jax.lax.dynamic_update_slice_in_dim(
-                c, new, pos, axis=0
-            )
-            kcache = jax.vmap(upd)(kcache, k.astype(kcache.dtype), cache_pos)
-            vcache = jax.vmap(upd)(vcache, v.astype(vcache.dtype), cache_pos)
-        else:
-            kcache = jax.lax.dynamic_update_slice_in_dim(kcache, k.astype(kcache.dtype), cache_pos, axis=1)
-            vcache = jax.lax.dynamic_update_slice_in_dim(vcache, v.astype(vcache.dtype), cache_pos, axis=1)
-        new_cache = {"k": kcache, "v": vcache}
-        k, v = kcache, vcache
+        new_cache = _dense_cache_write(
+            kv_cache, {"k": k, "v": v}, cache_pos, q_lens, ragged
+        )
+        k, v = new_cache["k"], new_cache["v"]
         k_pos1d = jnp.arange(k.shape[1])
         # the causal test against q_pos also masks unwritten cache slots
         # (per ROW in the ragged case: row b sees only its own ≤ cache_pos[b])
@@ -447,6 +461,124 @@ def multihead_attention(
         )
     out = out @ p["wo"]
     out = shard_hint(out, "batch", None, "embed")
+    return out, new_cache
+
+
+# --------------------------------------------------------------------------
+# multi-head latent attention (deepseek-v3 MLA), absorbed form
+# --------------------------------------------------------------------------
+
+
+def latent_attention_params(key, cfg: ModelConfig, dtype) -> Params:
+    """MLA without a query low-rank: ``wq`` → per head [nope, rope];
+    ``wkv_a`` → [latent, rope] a token (the rope part shared by every
+    head); ``kv_norm`` on the latent; ``wkv_b`` → per head [k_nope, v]."""
+    h, r = cfg.n_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    ks = jax.random.split(key, 4)
+    return {
+        "wq": dense_init(ks[0], cfg.d_model, h * (dn + dr), dtype),
+        "wkv_a": dense_init(ks[1], cfg.d_model, r + dr, dtype),
+        "kv_norm": rmsnorm_init(r),
+        "wkv_b": dense_init(ks[2], r, h * (dn + dv), dtype),
+        "wo": dense_init(ks[3], h * dv, cfg.d_model, dtype),
+    }
+
+
+def _latent_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
+    """RoPE on MLA's rope dims, which rotate in interleaved pairs (2i,
+    2i+1) (deepseek-v3's ``rope_interleave``): the dims are gathered to
+    [evens, odds], as the published model does, so rotate-half turns the
+    same pairs; q and k are permuted alike, so scores are unchanged."""
+    x = jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
+    return apply_rope(x, positions, theta)
+
+
+def latent_attention(
+    p: Params,
+    x: jax.Array,                     # [B, Sq, D_model]
+    cfg: ModelConfig,
+    *,
+    positions: jax.Array,             # [B, Sq]
+    kv_cache: Optional[Dict[str, jax.Array]] = None,   # {"kv": latent rows}
+    cache_pos: Optional[jax.Array] = None,             # scalar, or [B] per-row
+    layer_window: Optional[int] = None,
+    q_lens: Optional[jax.Array] = None,
+) -> Tuple[jax.Array, Optional[Dict[str, jax.Array]]]:
+    """MLA in the absorbed form.  Each token caches one row ``[c, k_rope]``
+    (``c`` the RMS-normed latent, ``k_rope`` its roped shared key part,
+    zero-padded to ``cfg.latent_cache_width``).  A head's query is folded
+    through its key up-projection, ``q_lat = q_nope · W_uk_h``, so scores
+    are ``[q_lat, q_rope] · [c, k_rope]`` (scaled by the published
+    1/sqrt(nope + rope)) and the context ``p · c`` is lifted by ``W_uv_h``
+    afterwards: per-head K and V are never built for any token."""
+    del layer_window                  # MLA models use full attention
+    b, sq, _ = x.shape
+    h, r = cfg.n_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    width = cfg.latent_cache_width
+    ragged = cache_pos is not None and jnp.ndim(cache_pos) > 0
+    q_pos = positions if ragged else positions[0]
+
+    q = (x @ p["wq"]).reshape(b, sq, h, dn + dr)
+    kva = x @ p["wkv_a"]                                       # [B, Sq, r + dr]
+    c = rmsnorm(kva[..., :r], p["kv_norm"], cfg.norm_eps)
+    k_rope = _latent_rope(kva[..., None, r:], positions, cfg.rope_theta)
+    wkv_b = p["wkv_b"].reshape(r, h, dn + dv)
+    q_lat = jnp.einsum("bshn,rhn->bshr", q[..., :dn], wkv_b[..., :dn])
+    pad = width - r - dr
+    qc = jnp.concatenate(
+        [q_lat, _latent_rope(q[..., dn:], positions, cfg.rope_theta),
+         jnp.zeros((b, sq, h, pad), q.dtype)], axis=-1)        # [B, Sq, H, W]
+    row = jnp.concatenate(
+        [c[:, :, None, :], k_rope.astype(c.dtype),
+         jnp.zeros((b, sq, 1, pad), c.dtype)], axis=-1)        # [B, Sq, 1, W]
+    qc = shard_hint(qc, "batch", None, "heads", None)
+    scale = 1.0 / math.sqrt(dn + dr)
+
+    paged = kv_cache is not None and "table" in kv_cache
+    if paged:
+        table = kv_cache["table"]
+        cp = cache_pos if cache_pos is not None else 0
+        (pool,) = _paged_update((kv_cache["kv"],), table, (row,), cp, q_lens)
+        new_cache = {"kv": pool, "table": table}
+    elif kv_cache is not None:
+        new_cache = _dense_cache_write(kv_cache, {"kv": row}, cache_pos, q_lens, ragged)
+        lat = new_cache["kv"]
+    else:
+        new_cache, lat = None, row
+
+    if paged and cfg.attention_impl == "pallas":
+        from repro.kernels.flash_attention import ops as fa_ops
+
+        out = fa_ops.flash_attention_paged(
+            qc, pool, pool, table, q_pos, q_lens, scale=scale, v_dim=r,
+        )
+    else:
+        if paged:                     # reference read path: the logical view
+            lat = _paged_view(pool, table)
+        k_pos = q_pos if kv_cache is None and q_pos.ndim == 1 else jnp.arange(lat.shape[1])
+        qg = qc.reshape(b, sq, 1, h, width)
+        if cfg.attention_impl == "chunked" and lat.shape[1] > cfg.attn_chunk and sq > 1:
+            out = _chunked_attention(
+                qg, lat, lat[..., :r], q_pos, k_pos, causal=True, window=None,
+                cap=None, scale=scale, chunk=cfg.attn_chunk,
+            )
+        else:
+            out = _naive_attention(
+                qg, lat, lat[..., :r], q_pos, k_pos, causal=True, window=None,
+                cap=None, scale=scale,
+            ).astype(x.dtype)
+        out = out.reshape(b, sq, h, r)
+
+    out = jnp.einsum("bshr,rhv->bshv", out, wkv_b[..., dn:]).reshape(b, sq, h * dv)
+    if q_lens is not None:
+        # fused-batch padding contract: rows beyond a row's q_len are zeros
+        out = jnp.where(
+            jnp.arange(sq, dtype=jnp.int32)[None, :, None] < q_lens[:, None, None],
+            out, jnp.zeros_like(out),
+        )
+    out = shard_hint(out @ p["wo"], "batch", None, "embed")
     return out, new_cache
 
 

@@ -140,6 +140,7 @@ def active_param_count(cfg: ModelConfig) -> int:
         return total
     e_pad = cfg.n_experts_padded or cfg.n_experts
     per_expert = 3 * cfg.d_model * cfg.moe_d_ff
-    routed_total = e_pad * per_expert * cfg.n_layers
-    routed_active = cfg.top_k * per_expert * cfg.n_layers
+    moe_layers = sum(cfg.is_moe_layer(i) for i in range(cfg.n_layers))
+    routed_total = e_pad * per_expert * moe_layers
+    routed_active = cfg.top_k * per_expert * moe_layers
     return total - routed_total + routed_active

@@ -43,7 +43,28 @@ def moe_params(key, cfg: ModelConfig, dtype) -> Params:
         "w_up": (jax.random.normal(ks[2], (e, d, f), jnp.float32) * scale_in).astype(dtype),
         "w_down": (jax.random.normal(ks[3], (e, f, d), jnp.float32) * scale_out).astype(dtype),
     }
+    if cfg.router_scoring == "sigmoid":
+        p["e_score_correction_bias"] = jnp.zeros((e,), jnp.float32)
     return p
+
+
+def sigmoid_topk(
+    p_router: jax.Array, bias: jax.Array, x: jax.Array, cfg: ModelConfig
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """DeepSeek-V3 routing (``noaux_tc``): scores ``s = sigmoid(x·W_r)`` in
+    f32; experts are chosen on ``s + bias`` in one group (DeepSeek-V3's
+    group limit is not served), weighted by their unbiased ``s``,
+    renormalised over the chosen (``norm_topk``), times ``routed_scaling``.
+    Returns (weights [T,k], experts [T,k], 0)."""
+    # the published gate runs in float32; at default precision a TPU would
+    # round the f32 operands to bf16 and flip near-tied selections
+    s = jax.nn.sigmoid(jnp.matmul(x.astype(jnp.float32), p_router,
+                                  precision=jax.lax.Precision.HIGHEST))  # [T, E]
+    _, experts = jax.lax.top_k(s + bias.astype(jnp.float32), cfg.top_k)  # [T, k]
+    weights = jnp.take_along_axis(s, experts, axis=-1)
+    if cfg.router_norm_topk:
+        weights = weights / (weights.sum(-1, keepdims=True) + 1e-20)
+    return weights * cfg.routed_scaling, experts, jnp.zeros((), jnp.float32)
 
 
 def router_topk(
@@ -72,7 +93,11 @@ def moe_reference(p: Params, x: jax.Array, cfg: ModelConfig) -> Tuple[jax.Array,
     """Dense dispatch oracle.  x: [B, S, D] → (y, aux_loss)."""
     b, s, d = x.shape
     xt = x.reshape(b * s, d)
-    weights, experts, aux = router_topk(p["router"], xt, cfg)
+    if cfg.router_scoring == "sigmoid":
+        weights, experts, aux = sigmoid_topk(
+            p["router"], p["e_score_correction_bias"], xt, cfg)
+    else:
+        weights, experts, aux = router_topk(p["router"], xt, cfg)
     e = cfg.n_experts_padded or cfg.n_experts
     onehot = jax.nn.one_hot(experts, e, dtype=x.dtype)     # [T, k, E]
     comb = (onehot * weights[..., None].astype(x.dtype)).sum(1)  # [T, E]
@@ -89,7 +114,10 @@ def moe_apply(p: Params, x: jax.Array, cfg: ModelConfig) -> Tuple[jax.Array, jax
     """Dispatch to the EP path when a parallel context is active, else reference."""
     ctx = current_context()
     e_pad = cfg.n_experts_padded or cfg.n_experts
-    if ctx is not None and ctx.ep_axes and ctx.mesh is not None:
+    # the EP path routes with softmax only; sigmoid routing takes the
+    # reference dispatch
+    if (ctx is not None and ctx.ep_axes and ctx.mesh is not None
+            and cfg.router_scoring == "softmax"):
         ep = ctx.axis_size(ctx.ep_axes)
         if e_pad % ep == 0:
             from repro.parallel.moe_parallel import moe_ep

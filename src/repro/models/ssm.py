@@ -272,7 +272,7 @@ def mamba2_block(
     y = y + xs.astype(y.dtype) * p["D"][None, None, :, None].astype(y.dtype)
     y = y.reshape(b, s, dims["d_inner"])
     # gated RMSNorm (mamba2's norm-before-out_proj, gated by z)
-    y = rmsnorm(y * jax.nn.silu(z), p["norm_w"])
+    y = rmsnorm(y * jax.nn.silu(z), p["norm_w"], cfg.norm_eps)
     out = y @ p["out_proj"]
     return out, new_state
 

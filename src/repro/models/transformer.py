@@ -30,6 +30,7 @@ from .layers import (
     attention_params,
     dense_init,
     embed_init,
+    latent_attention,
     mlp,
     mlp_params,
     multihead_attention,
@@ -50,7 +51,9 @@ def _dtype(cfg: ModelConfig):
     return jnp.dtype(cfg.dtype)
 
 
-def layer_params(key, cfg: ModelConfig) -> Params:
+def layer_params(key, cfg: ModelConfig, layer: int = 0) -> Params:
+    """Block ``layer``'s params: attention (MLA when ``cfg.is_mla``), then a
+    MoE (``cfg.is_moe_layer(layer)``) or a dense MLP of ``d_ff``."""
     dt = _dtype(cfg)
     ks = jax.random.split(key, 4)
     p: Params = {
@@ -61,7 +64,7 @@ def layer_params(key, cfg: ModelConfig) -> Params:
     if cfg.post_block_norm:
         p["ln_attn_post"] = rmsnorm_init(cfg.d_model)
         p["ln_mlp_post"] = rmsnorm_init(cfg.d_model)
-    if cfg.family == "moe" or cfg.n_experts:
+    if cfg.is_moe_layer(layer):
         p["moe"] = moe_params(ks[1], cfg, dt)
         if cfg.dense_parallel_ff and cfg.d_ff:
             p["mlp"] = mlp_params(ks[2], cfg.d_model, cfg.d_ff, cfg.activation, dt)
@@ -102,8 +105,10 @@ def block_apply(
     q_lens: Optional[jax.Array] = None,        # [B] fused-batch valid rows
 ) -> Tuple[jax.Array, Optional[Dict[str, jax.Array]], jax.Array]:
     """One transformer block; returns (x, new_cache, aux_loss)."""
-    h = rmsnorm(x, p["ln_attn"])
-    attn_out, new_cache = multihead_attention(
+    eps = cfg.norm_eps
+    h = rmsnorm(x, p["ln_attn"], eps)
+    attention = latent_attention if cfg.is_mla else multihead_attention
+    attn_out, new_cache = attention(
         p["attn"], h, cfg,
         positions=positions,
         kv_cache=kv_cache,
@@ -112,10 +117,10 @@ def block_apply(
         q_lens=q_lens,
     )
     if cfg.post_block_norm:
-        attn_out = rmsnorm(attn_out, p["ln_attn_post"])
+        attn_out = rmsnorm(attn_out, p["ln_attn_post"], eps)
     x = x + attn_out
 
-    h = rmsnorm(x, p["ln_mlp"])
+    h = rmsnorm(x, p["ln_mlp"], eps)
     aux = jnp.zeros((), jnp.float32)
     if "moe" in p:
         moe_out, aux = moe_apply(p["moe"], h, cfg)
@@ -127,7 +132,7 @@ def block_apply(
     else:
         ff_out = mlp(p["mlp"], h, cfg.activation)
     if cfg.post_block_norm:
-        ff_out = rmsnorm(ff_out, p["ln_mlp_post"])
+        ff_out = rmsnorm(ff_out, p["ln_mlp_post"], eps)
     x = x + ff_out
     return x, new_cache, aux
 
@@ -162,9 +167,14 @@ def init_params(key, cfg: ModelConfig) -> Params:
     else:
         lkeys = jax.random.split(keys[2], cfg.n_layers)
         if cfg.scan_layers:
+            if cfg.first_k_dense:
+                raise ValueError(
+                    f"{cfg.name}: layers of two kinds cannot be stacked for "
+                    "the layer scan; use scan_layers=False"
+                )
             p["layers"] = jax.vmap(lambda k: layer_params(k, cfg))(lkeys)
         else:
-            p["layers"] = [layer_params(k, cfg) for k in lkeys]
+            p["layers"] = [layer_params(k, cfg, i) for i, k in enumerate(lkeys)]
     return p
 
 
@@ -174,6 +184,8 @@ def init_params(key, cfg: ModelConfig) -> Params:
 
 
 def init_cache(cfg: ModelConfig, batch: int, max_len: int) -> Params:
+    """Dense caches: per layer ``[B, max_len, heads, width]`` for each of
+    ``cfg.cache_widths()`` (K and V, or MLA's one latent row)."""
     dt = _dtype(cfg)
     hd = cfg.resolved_head_dim
     kv_shape = (batch, max_len, cfg.n_kv_heads, hd)
@@ -199,8 +211,8 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int) -> Params:
         }
     return {
         "layers": {
-            "k": jnp.zeros((cfg.n_layers,) + kv_shape, dt),
-            "v": jnp.zeros((cfg.n_layers,) + kv_shape, dt),
+            name: jnp.zeros((cfg.n_layers, batch, max_len, heads, w), dt)
+            for name, (heads, w) in cfg.cache_widths().items()
         }
     }
 
@@ -219,7 +231,7 @@ def init_paged_cache(
     the hybrid family pages only its shared-attention KV."""
     dt = _dtype(cfg)
     hd = cfg.resolved_head_dim
-    pool_shape = (cfg.n_kv_heads, num_pages + 1, page_tokens, hd)
+    pool_shape = (cfg.n_kv_heads, num_pages + 1, page_tokens, hd)   # hybrid
 
     if cfg.family == "ssm":
         return init_cache(cfg, batch, 0)
@@ -237,8 +249,8 @@ def init_paged_cache(
         }
     return {
         "layers": {
-            "k": jnp.zeros((cfg.n_layers,) + pool_shape, dt),
-            "v": jnp.zeros((cfg.n_layers,) + pool_shape, dt),
+            name: jnp.zeros((cfg.n_layers, heads, num_pages + 1, page_tokens, w), dt)
+            for name, (heads, w) in cfg.cache_widths().items()
         }
     }
 
@@ -272,7 +284,7 @@ def _embed_in(params: Params, batch: Dict[str, jax.Array], cfg: ModelConfig):
 
 
 def _logits_out(params: Params, x: jax.Array, cfg: ModelConfig) -> jax.Array:
-    x = rmsnorm(x, params["ln_final"])
+    x = rmsnorm(x, params["ln_final"], cfg.norm_eps)
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     logits = x @ head
     logits = softcap(logits, cfg.logit_softcap)
@@ -289,7 +301,7 @@ def _dense_stack(
         # paged KV: the table is one [B, pages_per_slot] array shared by all
         # layers (each layer has its own pool, same page ids) — inject it at
         # the per-layer cache dict, strip it from the per-layer result so the
-        # scan carry / stacked pytree stays {"k","v"}
+        # scan carry / stacked pytree holds only the cache arrays
         if cache is not None and page_table is not None:
             cache = dict(cache, table=page_table)
         x, nc, aux = block_apply(
@@ -298,7 +310,7 @@ def _dense_stack(
             kv_cache=cache, cache_pos=cache_pos, q_lens=q_lens,
         )
         if nc is not None and "table" in nc:
-            nc = {"k": nc["k"], "v": nc["v"]}
+            nc = {name: a for name, a in nc.items() if name != "table"}
         return x, nc, aux
 
     if cfg.scan_layers:
@@ -325,11 +337,11 @@ def _dense_stack(
         return x, {"layers": new_caches}, auxs.sum()
 
     # unrolled python loop (smoke / tiny configs / FD roofline compiles)
-    new_layers = {"k": [], "v": []} if caches else None
+    new_layers = {name: [] for name in caches["layers"]} if caches else None
     aux_total = jnp.zeros((), jnp.float32)
     for i, layer_p in enumerate(params["layers"]):
         cache_i = (
-            {"k": caches["layers"]["k"][i], "v": caches["layers"]["v"][i]}
+            {name: a[i] for name, a in caches["layers"].items()}
             if caches
             else None
         )
@@ -341,10 +353,10 @@ def _dense_stack(
         x, nc, aux = one_fn(x, layer_p, cache_i)
         aux_total = aux_total + aux
         if caches:
-            new_layers["k"].append(nc["k"])
-            new_layers["v"].append(nc["v"])
+            for name, a in nc.items():
+                new_layers[name].append(a)
     new_caches = (
-        {"layers": {"k": jnp.stack(new_layers["k"]), "v": jnp.stack(new_layers["v"])}}
+        {"layers": {name: jnp.stack(a) for name, a in new_layers.items()}}
         if caches
         else None
     )
@@ -359,7 +371,7 @@ def _ssm_stack(params, x, cfg, caches, cache_pos=None, q_lens=None):
     cont = caches is not None and cache_pos is not None
 
     def one(x, layer_p, state):
-        h = rmsnorm(x, layer_p["ln"])
+        h = rmsnorm(x, layer_p["ln"], cfg.norm_eps)
         out, new_state = mamba2_block(
             layer_p["mamba"], h, cfg, state=state if cont else None,
             seq_lens=q_lens if cont else None,
@@ -402,7 +414,7 @@ def _hybrid_stack(
         def body(x, xs):
             layer_p, state = xs
             out, new_state = mamba2_block(
-                layer_p["mamba"], rmsnorm(x, layer_p["ln"]), cfg,
+                layer_p["mamba"], rmsnorm(x, layer_p["ln"], cfg.norm_eps), cfg,
                 state=state if cont else None,
                 seq_lens=q_lens if cont else None,
             )

@@ -890,7 +890,7 @@ class ServingEngine:
         masked KV scatter touches only the written span in place."""
         return [
             [
-                {key: layer[key][slot : slot + 1] for key in ("k", "v")}
+                {key: a[slot : slot + 1] for key, a in layer.items()}
                 for layer in st_caches
             ]
             for st_caches in self.caches
@@ -953,9 +953,9 @@ class ServingEngine:
             self.caches = self.executor.init_caches(self.slots, self.max_len)
         for si, st_caches in enumerate(slot_caches):
             for li, layer_cache in enumerate(st_caches):
-                for key in ("k", "v"):
+                for key, rows in layer_cache.items():
                     self.caches[si][li][key] = (
-                        self.caches[si][li][key].at[slot].set(layer_cache[key][0])
+                        self.caches[si][li][key].at[slot].set(rows[0])
                     )
 
     # ------------------------------------------------------------------

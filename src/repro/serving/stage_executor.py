@@ -152,10 +152,10 @@ class StageExecutor:
                     q_lens=q_lens,
                 )
                 if nc is not None and "table" in nc:
-                    nc = {"k": nc["k"], "v": nc["v"]}
+                    nc = {k: v for k, v in nc.items() if k != "table"}
                 new_caches.append(nc)
             if st.last:
-                x = rmsnorm(x, sp["ln_final"])
+                x = rmsnorm(x, sp["ln_final"], cfg.norm_eps)
                 head = sp["embed"].T if cfg.tie_embeddings else sp["lm_head"]
                 x = softcap(x @ head, cfg.logit_softcap)
             return x, new_caches
@@ -200,50 +200,41 @@ class StageExecutor:
         )
 
     # ------------------------------------------------------------------
-    def init_caches(self, batch: int, max_len: int):
-        hd = self.cfg.resolved_head_dim
+    def _zero_caches(self, shape_of):
+        """Per stage, per layer, one zero array for each of the model's
+        cache entries (``cfg.cache_widths()``: K and V, or MLA's latent),
+        of ``shape_of(heads, width)``, on that stage's device."""
         dt = jnp.dtype(self.cfg.dtype)
-        caches = []
-        for st in self.stages:
-            caches.append([
+        widths = self.cfg.cache_widths()
+        return [
+            [
                 {
-                    "k": jax.device_put(
-                        jnp.zeros((batch, max_len, self.cfg.n_kv_heads, hd), dt),
-                        st.device,
-                    ),
-                    "v": jax.device_put(
-                        jnp.zeros((batch, max_len, self.cfg.n_kv_heads, hd), dt),
-                        st.device,
-                    ),
+                    name: jax.device_put(jnp.zeros(shape_of(heads, w), dt), st.device)
+                    for name, (heads, w) in widths.items()
                 }
                 for _ in st.layer_ids
-            ])
-        return caches
+            ]
+            for st in self.stages
+        ]
+
+    def init_caches(self, batch: int, max_len: int):
+        return self._zero_caches(lambda heads, w: (batch, max_len, heads, w))
 
     def init_paged_caches(self, num_pages: int, page_tokens: int):
-        """Paged-KV pools: per stage, per layer, ``[KV, num_pages+1, P, hd]``
-        (kv-head-major) on that stage's device (the +1 is the reserved trash
-        page).  The page table is host-owned (``serving.kv_pool.KVPool``)
-        and rides into :meth:`forward` as ``page_table`` each step."""
-        hd = self.cfg.resolved_head_dim
-        dt = jnp.dtype(self.cfg.dtype)
-        shape = (self.cfg.n_kv_heads, num_pages + 1, page_tokens, hd)
-        caches = []
-        for st in self.stages:
-            caches.append([
-                {
-                    "k": jax.device_put(jnp.zeros(shape, dt), st.device),
-                    "v": jax.device_put(jnp.zeros(shape, dt), st.device),
-                }
-                for _ in st.layer_ids
-            ])
-        return caches
+        """Paged pools: per stage, per layer, ``[heads, num_pages+1, P, w]``
+        (head-major) for each cache entry — K and V of the KV heads, or
+        MLA's one latent pool — on that stage's device (the +1 is the
+        reserved trash page).  The page table is host-owned
+        (``serving.kv_pool.KVPool``) and rides into :meth:`forward` as
+        ``page_table`` each step."""
+        return self._zero_caches(
+            lambda heads, w: (heads, num_pages + 1, page_tokens, w))
 
     def copy_pages(self, caches, pairs):
         """Copy-on-write support: materialize page copies ``(src, dst)`` in
-        every stage's every layer pool (K and V).  Called at admission when a
-        request's prompt diverges inside a shared prefix page; the table
-        update itself is host-side (KVPool)."""
+        every stage's every layer pool (K and V, or the latent pool).
+        Called at admission when a request's prompt diverges inside a shared
+        prefix page; the table update itself is host-side (KVPool)."""
         if not pairs:
             return caches
         with span("executor.copy_pages"):
@@ -251,10 +242,7 @@ class StageExecutor:
             dst = jnp.asarray([d for _, d in pairs], jnp.int32)
             return [
                 [
-                    {
-                        key: c[key].at[:, dst].set(c[key][:, src])
-                        for key in ("k", "v")
-                    }
+                    {key: pool.at[:, dst].set(pool[:, src]) for key, pool in c.items()}
                     for c in st_caches
                 ]
                 for st_caches in caches

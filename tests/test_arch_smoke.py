@@ -74,6 +74,7 @@ def test_one_train_step(arch):
     [
         "llama3.2-1b", "gemma2-27b", "qwen3-14b", "gemma-7b", "arctic-480b",
         "qwen2-moe-a2.7b", "mamba2-130m", "zamba2-2.7b", "seamless-m4t-large-v2",
+        "moonlight-16b-a3b",
     ],
 )
 def test_prefill_decode_matches_teacher_forcing(arch):

@@ -1,5 +1,6 @@
 """The main-path flash kernels compile for a TPU v5e, at llama3.2-1b widths and
-at the benchmark configs' widths (Qwen3-14B, Qwen1.5-MoE).
+at the benchmark configs' widths (Qwen3-14B, Qwen1.5-MoE, and the latent
+kernel at Moonlight-16B-A3B's).
 
 No chip is needed: the TPU compiler compiles for a described ``v5e:2x2``
 topology, and refuses what the chip would refuse (block shapes that break
@@ -143,3 +144,29 @@ def test_paged_flash_kernel_stacks_the_gqa_group(one_chip, arch, slots, pages,
         r"%([\w.]+) = (\w+\[[\d,]+\])\S* custom-call\(.*tpu_custom_call", text)
     assert [(re.sub(r"\.\d+$", "", n), s) for n, s in kernels] == [
         ("flash_attention_paged", f"bf16[{slots * kv},{rows},{d}]")]
+
+
+@pytest.mark.parametrize("sq, rows", [(1, 16), (64, 1024)])
+def test_latent_kernel_compiles_at_moonlight_widths(one_chip, sq, rows):
+    """MLA's absorbed kernel at the Moonlight cell's shapes (32 slots of
+    128 pages, 16 heads on one latent pool of 640-lane rows, V its leading
+    512 lanes): one grid row per slot, the heads stacked in the query
+    block, under its own name, with no copy of the pool."""
+    cfg = get_config("moonlight-16b-a3b")
+    slots, pages, w = 32, 128, cfg.latent_cache_width
+    step = jax.jit(lambda q, pool, tbl, qp, ql: flash_attention_paged(
+        q, pool, pool, tbl, qp, ql, scale=(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5,
+        v_dim=cfg.kv_lora_rank, interpret=False))
+    compiled = step.lower(
+        _spec(one_chip, (slots, sq, cfg.n_heads, w)),
+        _spec(one_chip, (1, slots * pages + 1, PAGE, w)),
+        _spec(one_chip, (slots, pages), jnp.int32),
+        _spec(one_chip, (slots, sq), jnp.int32),
+        _spec(one_chip, (slots,), jnp.int32),
+    ).compile()
+    kernels = re.findall(
+        r"%([\w.]+) = (\w+\[[\d,]+\])\S* custom-call\(.*tpu_custom_call",
+        compiled.as_text())
+    assert [(re.sub(r"\.\d+$", "", n), s) for n, s in kernels] == [
+        ("flash_attention_paged_latent", f"bf16[{slots},{rows},{cfg.kv_lora_rank}]")]
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**20
