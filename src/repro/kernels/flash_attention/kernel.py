@@ -27,6 +27,20 @@ op.  KV blocks are BK=64 tokens, one page of a 64-token paged pool, so the
 dense and paged kernels walk KV in the same tiles and, on the same K/V,
 return bit-identical attention at that page size.  Every block spans the
 whole head_dim.
+
+Only live steps compute.  A (query block ``i``, KV block ``j``) step of grid
+row ``b`` is live when the block holds a live folded row and ``j`` lies in
+the span of KV blocks those rows can see (:func:`_live_span`, from the
+row's scalar-prefetched offset and length and the static causal, window,
+``rep`` and block sizes).  A dead step skips the score, softmax and
+accumulate work, and the K/V index maps clamp ``j`` to the live span, so a
+dead step repeats the previous block index and fetches nothing.  Skipping
+is exact: on a block whose mask is all false the recurrence leaves ``m``,
+``l`` and ``acc`` as they were, so on finite K/V the output is
+bit-identical to walking every block, and K/V outside the live span
+(pages past a slot's written depth, the trash page) is never read, even
+when it is not finite.  ``_init`` and ``_finalize`` run on every row, so a
+row with no visible key still ends as exact zeros.
 """
 
 from __future__ import annotations
@@ -36,6 +50,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -43,6 +58,42 @@ DEFAULT_BQ = 128     # query positions a block holds per query head
 MAX_BQ = 512         # VMEM cap on a block's folded rows (positions × rep)
 DEFAULT_BK = 64
 NEG_INF = -1e30
+MAX_WINDOW = 1 << 30  # a window this wide masks nothing (positions stay below)
+
+
+def _live_span(q_offset, q_len, qi, *, causal, window, k_len, block_q,
+               block_k, rep, xp=jnp):
+    """``(rows_live, lo, hi)``: whether query block ``qi`` of a grid row
+    holds a live folded row, and the first and last KV block holding a key
+    that one of its live rows can see.  Step ``(qi, j)`` computes exactly
+    when ``rows_live and lo <= j <= hi``: a block outside that span has an
+    all-false mask.  ``xp`` is ``jnp`` for the kernel's scalars and its
+    index maps, ``numpy`` for the host's count.  Every division has a
+    non-negative numerator, so the kernel's truncating division (cheaper
+    on the scalar unit than floor division) agrees with the host's."""
+    div = jax.lax.div if xp is jnp else np.floor_divide
+    r_lo = qi * block_q
+    n_rows = q_len * rep
+    rows_live = r_lo < n_rows
+    # last live row of the block (clamped at 0 for a block with none)
+    r_hi = xp.maximum(xp.minimum(r_lo + block_q, n_rows) - 1, 0)
+    hi = (k_len - 1) // block_k                   # sequence padding
+    if causal:   # the last live row, position q_offset + r_hi // rep, sees
+        hi = xp.minimum(hi, div(q_offset + div(r_hi, rep), block_k))
+    lo = 0
+    if window > 0:   # the first live row sees keys past its position - window
+        first = q_offset + div(r_lo, rep) - min(window, MAX_WINDOW) + 1
+        lo = div(xp.maximum(first, 0), block_k)
+    return rows_live, lo, hi
+
+
+def _kv_block(j, q_offset, q_len, qi, **kw):
+    """KV block index map: ``j`` clamped to the live span, so the steps
+    outside it repeat a block index and fetch nothing.  A query block with
+    no live row stays on ``hi`` for every ``j``: the last block the row's
+    last live query sees, where the previous query block ended."""
+    rows_live, lo, hi = _live_span(q_offset, q_len, qi, **kw)
+    return jnp.where(rows_live, jnp.minimum(jnp.maximum(j, lo), hi), hi)
 
 
 def _flash_kernel(
@@ -74,6 +125,10 @@ def _flash_kernel(
     kj = pl.program_id(2)
     q_offset = offs_ref[bh]
     q_len = lens_ref[bh]
+    rows_live, lo, hi = _live_span(
+        q_offset, q_len, qi, causal=causal, window=window, k_len=k_len,
+        block_q=block_q, block_k=block_k, rep=rep,
+    )
 
     @pl.when(kj == 0)
     def _init():
@@ -81,49 +136,57 @@ def _flash_kernel(
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[...].astype(jnp.float32)
-    k = k_ref[...].astype(jnp.float32)
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale                                             # [BQ, BK]
-    if softcap > 0.0:
-        s = softcap * jnp.tanh(s / softcap)
+    # a step outside the live span would add nothing: skip it (exact)
+    @pl.when(rows_live & (kj >= lo) & (kj <= hi))
+    def _step():
+        q = q_ref[...].astype(jnp.float32)
+        k = k_ref[...].astype(jnp.float32)
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        ) * scale                                         # [BQ, BK]
+        if softcap > 0.0:
+            s = softcap * jnp.tanh(s / softcap)
 
-    qrow = qi * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0
-    )                                        # local folded row index
-    # folded row r is query position r // rep; positions are compared scaled
-    # by rep (for integer k, k <= r // rep <=> k·rep <= r): no vector division
-    qp = qrow + (q_offset * rep if rep > 1 else q_offset)
-    kp = kj * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-    kps = kp * rep if rep > 1 else kp
-    mask = kp < k_len                        # sequence padding is never visible
-    # per-row ragged length: query positions beyond this row's q_len are
-    # fully masked — their denominator stays 0 and _finalize emits exact
-    # zeros, the deterministic padding output of a fused mixed batch
-    mask &= qrow < (q_len * rep if rep > 1 else q_len)
-    if causal:
-        mask &= kps <= qp
-    if window > 0:
-        # a scaled window past int32 masks nothing (qp - kps < 2**31 - 1 for
-        # every int32 scaled position), so saturating it is exact
-        mask &= kps > qp - (min(window * rep, 2**31 - 1) if rep > 1 else window)
-    s = jnp.where(mask, s, NEG_INF)
+        qrow = qi * block_q + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 0
+        )                                    # local folded row index
+        # folded row r is query position r // rep; positions are compared
+        # scaled by rep (for integer k, k <= r // rep <=> k·rep <= r): no
+        # vector division
+        qp = qrow + (q_offset * rep if rep > 1 else q_offset)
+        kp = kj * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 1
+        )
+        kps = kp * rep if rep > 1 else kp
+        mask = kp < k_len                    # sequence padding is never visible
+        # per-row ragged length: query positions beyond this row's q_len are
+        # fully masked — their denominator stays 0 and _finalize emits exact
+        # zeros, the deterministic padding output of a fused mixed batch
+        mask &= qrow < (q_len * rep if rep > 1 else q_len)
+        if causal:
+            mask &= kps <= qp
+        if window > 0:
+            # a scaled window past int32 masks nothing (qp - kps < 2**31 - 1
+            # for every int32 scaled position), so saturating it is exact
+            mask &= kps > qp - (
+                min(window * rep, 2**31 - 1) if rep > 1 else window
+            )
+        s = jnp.where(mask, s, NEG_INF)
 
-    m_prev = m_scr[...][:, 0]
-    l_prev = l_scr[...][:, 0]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.where(mask, jnp.exp(s - m_new[:, None]), 0.0)
-    l_new = l_prev * alpha + jnp.sum(p, axis=-1)
+        m_prev = m_scr[...][:, 0]
+        l_prev = l_scr[...][:, 0]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.where(mask, jnp.exp(s - m_new[:, None]), 0.0)
+        l_new = l_prev * alpha + jnp.sum(p, axis=-1)
 
-    v = v_ref[...].astype(jnp.float32)
-    pv = jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    acc_scr[...] = acc_scr[...] * alpha[:, None] + pv
-    m_scr[...] = m_new[:, None]
-    l_scr[...] = l_new[:, None]
+        v = v_ref[...].astype(jnp.float32)
+        pv = jax.lax.dot_general(
+            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        )
+        acc_scr[...] = acc_scr[...] * alpha[:, None] + pv
+        m_scr[...] = m_new[:, None]
+        l_scr[...] = l_new[:, None]
 
     @pl.when(kj == n_kv_blocks - 1)
     def _finalize():
@@ -203,27 +266,28 @@ def flash_attention_pallas_paged(
     if q_lens is None:
         q_lens = jnp.full((bh,), -(-rows // rep), jnp.int32)
 
+    span_kw = dict(causal=causal, window=int(window or 0), k_len=k_len,
+                   block_q=block_q, block_k=page_tokens, rep=rep)
     kernel = functools.partial(
         _flash_kernel_paged,
         scale=scale,
-        causal=causal,
-        window=int(window or 0),
         softcap=float(softcap or 0.0),
-        k_len=k_len,
         n_kv_blocks=pages_per_slot,
-        block_q=block_q,
-        block_k=page_tokens,
-        rep=rep,
+        **span_kw,
     )
 
     def _kv_spec(width):
         # block (1, 1, P, width): index maps pick (kv head, physical page,
         # 0, 0) — tbl is the third scalar-prefetch ref, available at
-        # DMA-schedule time exactly like the (offs, lens) rows
+        # DMA-schedule time exactly like the (offs, lens) rows; the logical
+        # page is clamped to the block's live span, so dead steps fetch
+        # nothing
         return pl.BlockSpec(
             (None, None, page_tokens, width),
             lambda b, i, j, offs, lens, tbl: (
-                b % kv_heads, tbl[b // kv_heads, j], 0, 0
+                b % kv_heads,
+                tbl[b // kv_heads, _kv_block(j, offs[b], lens[b], i, **span_kw)],
+                0, 0,
             ),
         )
 
@@ -292,27 +356,31 @@ def flash_attention_pallas(
         assert q_lens.shape == (bh,), (q_lens.shape, bh)
         q_lens = q_lens.astype(jnp.int32)
 
+    span_kw = dict(causal=causal, window=int(window or 0),
+                   k_len=int(k_len) if k_len else sk, block_q=block_q,
+                   block_k=block_k, rep=rep)
     kernel = functools.partial(
         _flash_kernel,
         scale=scale,
-        causal=causal,
-        window=int(window or 0),
         softcap=float(softcap or 0.0),
-        k_len=int(k_len) if k_len else sk,
         n_kv_blocks=n_k,
-        block_q=block_q,
-        block_k=block_k,
-        rep=rep,
+        **span_kw,
     )
     # per-row (offset, len) ride in as scalar-prefetch operands (SMEM):
     # available before the body runs, so masks stay affine in block indices
+    # and the K/V index maps can clamp to each block's live span
+    kv_spec = pl.BlockSpec(
+        (None, block_k, d),
+        lambda b, i, j, offs, lens: (
+            b, _kv_block(j, offs[b], lens[b], i, **span_kw), 0),
+    )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(bh, n_q, n_k),
         in_specs=[
             pl.BlockSpec((None, block_q, d), lambda b, i, j, *_: (b, i, 0)),
-            pl.BlockSpec((None, block_k, d), lambda b, i, j, *_: (b, j, 0)),
-            pl.BlockSpec((None, block_k, d), lambda b, i, j, *_: (b, j, 0)),
+            kv_spec,
+            kv_spec,
         ],
         out_specs=pl.BlockSpec((None, block_q, d), lambda b, i, j, *_: (b, i, 0)),
         scratch_shapes=[

@@ -22,15 +22,20 @@ Fused mixed prefill/decode batches additionally carry ``q_lens`` ([B]): the
 number of VALID query rows per batch row (decode rows 1, prefill chunks
 ``chunk``, idle rows 0).  Queries beyond a row's ``q_lens`` are fully
 masked inside the kernel and output exact zeros.
+
+The kernels compute only the (query block, KV block) steps that a live
+query of the row can see; :func:`paged_live_blocks` counts them for one
+paged call on the host, by the kernel's own rule.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.kernels import resolve_interpret
 
@@ -38,9 +43,16 @@ from .kernel import (
     DEFAULT_BK,
     DEFAULT_BQ,
     MAX_BQ,
+    _live_span,
     flash_attention_pallas,
     flash_attention_pallas_paged,
 )
+
+
+def _block_q(rows: int, rep: int) -> int:
+    """Folded rows a query block holds: ``DEFAULT_BQ`` positions of each of
+    the ``rep`` heads, at most ``MAX_BQ``, a multiple of 8."""
+    return min(DEFAULT_BQ * rep, MAX_BQ, -(-rows // 8) * 8)
 
 
 def _fold_queries(q: jax.Array, kv: int) -> tuple[jax.Array, int]:
@@ -50,7 +62,7 @@ def _fold_queries(q: jax.Array, kv: int) -> tuple[jax.Array, int]:
     b, sq, h, d = q.shape
     rep = h // kv
     rows = sq * rep
-    bq = min(DEFAULT_BQ * rep, MAX_BQ, -(-rows // 8) * 8)
+    bq = _block_q(rows, rep)
     qk = q.reshape(b, sq, kv, rep, d).transpose(0, 2, 1, 3, 4).reshape(
         b * kv, rows, d
     )
@@ -167,10 +179,16 @@ def flash_attention_paged(
     is ever gathered, and each page is fetched once per GQA group (the
     kv-head axis is indexed straight out of the pool).
 
+    A (query block, page) step computes only when a live query of the row
+    can see a key of that page (causal, window and ``q_lens``); every other
+    step skips its work, and the K/V index maps clamp the page to the live
+    span, so it fetches nothing.  Pages past a row's written depth, mapped
+    or not, are therefore never read.
+
     Unmapped table entries (−1) are clamped to the reserved trash page
-    ``num_pages``; whatever garbage it holds sits at logical positions
-    beyond every row's written span, where the causal mask already
-    guarantees exact zero attention weight.
+    ``num_pages``; it sits at logical positions beyond every row's written
+    span, outside the live span, so whatever it holds, NaN included, gets
+    exactly zero attention weight.
 
     ``v_dim`` below the pool's width reads V as each page's leading
     ``v_dim`` columns: MLA's absorbed form passes its one latent pool
@@ -205,3 +223,27 @@ def flash_attention_paged(
         name="flash_attention_paged_latent" if latent else "flash_attention_paged",
     )
     return _unfold(out, b, sq, h)
+
+
+def paged_live_blocks(
+    q_lens, cache_pos, sq: int, *, rep: int, kv_heads: int,
+    pages_per_slot: int, page_tokens: int, window: Optional[int] = None,
+) -> Tuple[int, int]:
+    """``(live_blocks, grid_blocks)`` of one causal
+    :func:`flash_attention_paged` call on a ``[slots, sq]`` batch with
+    per-slot ``q_lens`` and ``cache_pos`` (host arrays): the grid's
+    (slot·KV head, query block, page) steps that compute, by the kernel's
+    own rule, and all of them."""
+    rows = sq * rep
+    bq = _block_q(rows, rep)
+    n_q = -(-rows // bq)
+    rows_live, lo, hi = _live_span(
+        np.asarray(cache_pos, np.int64)[:, None],
+        np.asarray(q_lens, np.int64)[:, None],
+        np.arange(n_q)[None], causal=True, window=int(window or 0),
+        k_len=pages_per_slot * page_tokens, block_q=bq, block_k=page_tokens,
+        rep=rep, xp=np,
+    )
+    live = np.where(rows_live, np.maximum(hi - lo + 1, 0), 0).sum()
+    slots = np.shape(q_lens)[0]
+    return int(live) * kv_heads, slots * kv_heads * n_q * pages_per_slot

@@ -1113,6 +1113,25 @@ class ServingEngine:
             fracs.append(d / (d + p) if d + p > 0 else 1.0)
         return fracs
 
+    def _count_attn_blocks(self, batch_span, q_lens: np.ndarray,
+                           cache_pos: np.ndarray, s: int) -> None:
+        """Put on a fused step's ``engine.batch`` span the grid blocks of
+        one full-attention layer's paged flash call that compute
+        (``attn_live``) and all of them (``attn_blocks``).  Nothing while
+        no trace is open, or without the paged Pallas kernel."""
+        cfg, pool = self.cfg, self._kv_pool
+        if (pool is None or cfg.attention_impl != "pallas"
+                or cfg.is_attention_free or not span.is_enabled()):
+            return
+        from repro.kernels.flash_attention.ops import paged_live_blocks
+
+        kv = 1 if cfg.is_mla else cfg.n_kv_heads    # MLA: one latent pool
+        live, blocks = paged_live_blocks(
+            q_lens, cache_pos, s, rep=cfg.n_heads // kv, kv_heads=kv,
+            pages_per_slot=pool.pages_per_slot, page_tokens=pool.page_tokens,
+        )
+        batch_span.set_metadata(attn_live=live, attn_blocks=blocks)
+
     def _step_fused(self) -> int:
         """One FUSED engine iteration: decode-ready slots, mid-prefill
         slots, and idle slots ride one ``[slots, S]`` forward with per-row
@@ -1131,7 +1150,7 @@ class ServingEngine:
         if not idx and not pf_slots:
             return 0
         self._ensure_caches()
-        with span("engine.batch"):
+        with span("engine.batch") as batch_span:
             s = self.prefill_chunk if pf_slots else 1
             tokens = np.zeros((self.slots, s), dtype=np.int32)
             q_lens = np.zeros(self.slots, dtype=np.int32)
@@ -1149,6 +1168,7 @@ class ServingEngine:
                 q_lens[i] = n
                 cache_pos[i] = done
                 pf_n[i] = n
+            self._count_attn_blocks(batch_span, q_lens, cache_pos, s)
             tokens, q_lens = jnp.asarray(tokens), jnp.asarray(q_lens)
             table = (
                 self._kv_pool.table_array() if self._kv_pool is not None else None
@@ -1365,7 +1385,7 @@ class ServingEngine:
                 assert len(hooked) == k, "proposal hook must keep length k"
                 proposals[i] = [int(t) for t in hooked]
         # ---- target phase: one fused mixed forward ----------------------
-        with span("engine.batch"):
+        with span("engine.batch") as batch_span:
             s = 1
             if pf_slots:
                 s = max(s, self.prefill_chunk)
@@ -1392,6 +1412,7 @@ class ServingEngine:
                 q_lens[i] = n
                 cache_pos[i] = done
                 pf_n[i] = n
+            self._count_attn_blocks(batch_span, q_lens, cache_pos, s)
             tokens, q_lens = jnp.asarray(tokens), jnp.asarray(q_lens)
             table = (
                 self._kv_pool.table_array() if self._kv_pool is not None else None

@@ -189,6 +189,180 @@ def test_grouped_flash_kernel_window_softcap(paged, rep, window):
     )
 
 
+# ------------------------------- flash attn, paged: only live steps compute
+# slot 0 a chunk starting mid-page that crosses page boundaries, slot 1 idle
+# (no page mapped), slot 2 a decode row deep in its pages, slot 3 a short
+# chunk at 0; every slot but the idle one has pages mapped past its depth,
+# as admission maps them up to prompt + max_new
+DEAD_OFFS = np.array([GPAGE + 5, 0, 5 * GPAGE + 3, 0], np.int32)
+
+
+def _dead_lens(sq):
+    return np.array([sq, 0, 1, max(1, sq // 2)], np.int32)
+
+
+def _visible_pages(off, n, window):
+    """The pages holding a key that one of a row's live queries sees."""
+    if n == 0:
+        return range(0)
+    first = max(0, off - window + 1) if window else 0
+    return range(first // GPAGE, (off + n - 1) // GPAGE + 1)
+
+
+@pytest.mark.parametrize("kind", ["gqa", "window", "latent"])
+@pytest.mark.parametrize("sq", [1, 64])
+@pytest.mark.parametrize("rep", [1, 5, 16])
+def test_paged_kernel_never_computes_dead_pages(rep, sq, kind):
+    """Pages outside every live query's view (mapped pages past a slot's
+    depth, pages before a 24-token window, the trash page) filled with NaN
+    leave the output finite and bit-equal to the same call with them
+    zeroed: the kernel neither computes nor weighs them.  ``latent`` reads
+    one pool as K and its leading ``v_dim`` lanes as V, as MLA does."""
+    from repro.kernels.flash_attention.ops import flash_attention_paged
+
+    window = 24 if kind == "window" else 0
+    kv, d = (1, 48) if kind == "latent" else (GKV, GD)
+    v_dim = 32 if kind == "latent" else None
+    lens = _dead_lens(sq)
+    nb = len(DEAD_OFFS)
+    r = np.random.default_rng(1000 + 10 * rep + sq)
+    q = r.standard_normal((nb, sq, kv * rep, d)).astype(np.float32)
+    table = r.permutation(nb * GPAGES).reshape(nb, GPAGES).astype(np.int32)
+    table[1] = -1                                  # the idle slot: unmapped
+    table[0, -2:] = -1                             # and past slot 0's head
+    pools = [r.standard_normal((kv, nb * GPAGES + 1, GPAGE, d)).astype(np.float32)
+             for _ in range(1 if kind == "latent" else 2)]
+    dead = np.ones(nb * GPAGES + 1, bool)          # the trash page among them
+    for b in range(nb):
+        for j in _visible_pages(int(DEAD_OFFS[b]), int(lens[b]), window):
+            dead[table[b, j]] = False
+    q_pos = jnp.asarray(DEAD_OFFS[:, None] + np.arange(sq)[None], jnp.int32)
+
+    def run(fill):
+        filled = []
+        for pool in pools:
+            pool = pool.copy()
+            pool[:, dead] = fill
+            filled.append(jnp.asarray(pool))
+        pk, pv = (filled * 2)[:2]
+        return np.asarray(flash_attention_paged(
+            jnp.asarray(q), pk, pv, jnp.asarray(table), q_pos,
+            jnp.asarray(lens), scale=1.0 / np.sqrt(d), window=window or None,
+            v_dim=v_dim, interpret=True,
+        ))
+
+    out = run(np.nan)
+    assert np.isfinite(out).all()
+    np.testing.assert_array_equal(out, run(0.0))
+    for b, n in enumerate(lens):                   # padding rows: exact zeros
+        assert not np.any(out[b, n:]), (b, n)
+
+
+def _brute_live_blocks(offs, lens, sq, rep, kv, pages, page, window):
+    """The paged kernel's grid blocks whose mask, built as the kernel
+    builds it, has a true entry; and all its grid blocks."""
+    from repro.kernels.flash_attention.ops import _block_q
+
+    rows = sq * rep
+    bq = _block_q(rows, rep)
+    n_q = -(-rows // bq)
+    k_len = pages * page
+    kp = np.arange(k_len, dtype=np.int64)[None]
+    kps = kp * rep
+    live = 0
+    for off, n in zip(offs, lens):
+        for i in range(n_q):
+            qrow = i * bq + np.arange(bq, dtype=np.int64)[:, None]
+            qp = qrow + int(off) * rep
+            mask = (kp < k_len) & (qrow < int(n) * rep) & (kps <= qp)
+            if window:
+                mask &= kps > qp - (min(window * rep, 2**31 - 1) if rep > 1
+                                    else window)
+            live += int(mask.reshape(bq, pages, page).any(axis=(0, 2)).sum())
+    return live * kv, len(offs) * kv * n_q * pages
+
+
+def _moonlight_step(sq, seed):
+    """A fused step of the Moonlight cell's shape: 32 slots of 128 pages,
+    prefilling, decoding and idle slots at depths up to 8k."""
+    r = np.random.default_rng(seed)
+    kind = r.choice(3, size=32, p=[0.35, 0.6, 0.05])
+    lens = np.where(kind == 0, sq, np.where(kind == 1, 1, 0)).astype(np.int32)
+    offs = np.where(lens > 0, r.integers(0, 128 * 64 - sq, 32), 0)
+    return offs.astype(np.int32), lens
+
+
+@pytest.mark.parametrize("case", [
+    # rep, sq, kv, pages, page, window, step
+    (1, 1, GKV, GPAGES, GPAGE, 0, "ragged"),
+    (5, 64, GKV, GPAGES, GPAGE, 0, "ragged"),
+    (16, 64, 1, GPAGES, GPAGE, 0, "ragged"),
+    (5, 64, GKV, GPAGES, GPAGE, 24, "ragged"),
+    (16, 1, 1, GPAGES, GPAGE, 24, "ragged"),
+    (5, 64, GKV, GPAGES, GPAGE, 1 << 30, "ragged"),   # the serving window
+    (16, 64, 1, 128, 64, 0, "moonlight"),              # rows 1024, block 512
+    (16, 1, 1, 128, 64, 0, "moonlight"),
+])
+def test_paged_live_blocks_counts_the_kernels_live_steps(case):
+    """The host count of a paged call's computing blocks equals a brute
+    count of the blocks whose kernel mask has any true entry."""
+    from repro.kernels.flash_attention.ops import paged_live_blocks
+
+    rep, sq, kv, pages, page, window, step = case
+    if step == "ragged":
+        offs, lens = DEAD_OFFS, _dead_lens(sq)
+    else:
+        offs, lens = _moonlight_step(sq, seed=sq)
+    got = paged_live_blocks(lens, offs, sq, rep=rep, kv_heads=kv,
+                            pages_per_slot=pages, page_tokens=page,
+                            window=window)
+    want = _brute_live_blocks(offs, lens, sq, rep, kv, pages, page, window)
+    assert got == want
+    assert 0 < got[0] < got[1]
+
+
+@pytest.mark.parametrize("case", [
+    # rep, sq, pages, page, window, step
+    (1, 1, GPAGES, GPAGE, 0, "ragged"),
+    (16, 64, GPAGES, GPAGE, 0, "ragged"),
+    (5, 64, GPAGES, GPAGE, 24, "ragged"),
+    (16, 64, 128, 64, 0, "moonlight"),
+])
+def test_paged_index_map_fetches_only_live_pages(case):
+    """Over a grid row's sweep, a query block's K/V index map visits exactly
+    the pages its live rows see, each once and in order; a query block with
+    no live row stays on the page where the previous block ended, so it
+    fetches nothing."""
+    from repro.kernels.flash_attention.kernel import _kv_block
+    from repro.kernels.flash_attention.ops import _block_q
+
+    rep, sq, pages, page, window, step = case
+    if step == "ragged":
+        offs, lens = DEAD_OFFS, _dead_lens(sq)
+    else:
+        offs, lens = _moonlight_step(sq, seed=sq + 7)
+    bq = _block_q(sq * rep, rep)
+    n_q = -(-sq * rep // bq)
+    j = jnp.arange(pages)
+    for off, n in zip(offs, lens):
+        prev = None
+        for i in range(n_q):
+            got = np.asarray(_kv_block(
+                j, jnp.int32(off), jnp.int32(n), jnp.int32(i), causal=True,
+                window=window, k_len=pages * page, block_q=bq, block_k=page,
+                rep=rep))
+            rows = range(i * bq, min((i + 1) * bq, int(n) * rep))
+            if rows:
+                pos = [int(off) + r // rep for r in rows]
+                first = max(0, pos[0] - window + 1) if window else 0
+                want = list(range(first // page, pos[-1] // page + 1))
+                assert sorted(set(got.tolist())) == want, (off, n, i)
+                assert np.all(np.diff(got) >= 0)
+            else:
+                assert set(got.tolist()) == {prev if prev is not None else got[0]}
+            prev = int(got[-1])
+
+
 # ------------------------------------------------------------- grouped gemm
 @pytest.mark.parametrize(
     "m,k,n,e,sizes",

@@ -55,6 +55,23 @@ def _assert_kernel(compiled):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def _index_map_prims(fn, *specs):
+    """The primitives of each block's index map in ``fn``'s one
+    ``pallas_call`` (in its in- then out-spec order)."""
+    def calls(jaxpr):
+        for e in jaxpr.eqns:
+            if e.primitive.name == "pallas_call":
+                yield e
+            for v in e.params.values():
+                inner = getattr(v, "jaxpr", None)
+                if inner is not None:
+                    yield from calls(getattr(inner, "jaxpr", inner))
+
+    (call,) = calls(jax.make_jaxpr(fn)(*specs).jaxpr)
+    return [{e.primitive.name for e in bm.index_map_jaxpr.jaxpr.eqns}
+            for bm in call.params["grid_mapping"].block_mappings]
+
+
 @pytest.mark.parametrize("sq", [1, 5, 64])
 def test_dense_flash_kernel_compiles_for_v5e(one_chip, sq):
     step = jax.jit(lambda q, k, v, qp, ql: flash_attention(
@@ -151,19 +168,25 @@ def test_latent_kernel_compiles_at_moonlight_widths(one_chip, sq, rows):
     """MLA's absorbed kernel at the Moonlight cell's shapes (32 slots of
     128 pages, 16 heads on one latent pool of 640-lane rows, V its leading
     512 lanes): one grid row per slot, the heads stacked in the query
-    block, under its own name, with no copy of the pool."""
+    block, under its own name, with no copy of the pool.  Its K and V
+    index maps clamp each page to the block's live span, and compile so."""
     cfg = get_config("moonlight-16b-a3b")
     slots, pages, w = 32, 128, cfg.latent_cache_width
     step = jax.jit(lambda q, pool, tbl, qp, ql: flash_attention_paged(
         q, pool, pool, tbl, qp, ql, scale=(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5,
         v_dim=cfg.kv_lora_rank, interpret=False))
-    compiled = step.lower(
+    specs = (
         _spec(one_chip, (slots, sq, cfg.n_heads, w)),
         _spec(one_chip, (1, slots * pages + 1, PAGE, w)),
         _spec(one_chip, (slots, pages), jnp.int32),
         _spec(one_chip, (slots, sq), jnp.int32),
         _spec(one_chip, (slots,), jnp.int32),
-    ).compile()
+    )
+    q_map, k_map, v_map, o_map = _index_map_prims(step, *specs)
+    clamp = {"min", "max"}
+    assert clamp <= k_map and clamp <= v_map
+    assert not q_map & clamp and not o_map & clamp
+    compiled = step.lower(*specs).compile()
     kernels = re.findall(
         r"%([\w.]+) = (\w+\[[\d,]+\])\S* custom-call\(.*tpu_custom_call",
         compiled.as_text())

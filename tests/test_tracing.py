@@ -8,6 +8,7 @@ import functools
 import glob
 
 import jax
+import pytest
 
 from repro.configs import get_config
 from repro.core.devices import tpu_slice_cluster
@@ -110,6 +111,37 @@ def test_spans_land_in_the_profiler_trace_nested(tmp_path):
         assert sum(1 for s in spans if s[0] == stage_span) == \
             n * len(eng.executor.stages)
     assert sum(1 for s in spans if s[0] == "executor.copy_pages") == 1
+
+
+@pytest.mark.parametrize("spec_tokens", [0, 2])
+def test_batch_span_counts_the_paged_kernels_live_blocks(tmp_path, spec_tokens):
+    """On the paged Pallas kernel, each fused step's ``engine.batch`` span
+    carries the grid blocks of its paged flash call that compute
+    (``attn_live``) and all of them (``attn_blocks``), in plain and in
+    speculative serving.  One 19-token prompt in 8-token chunks on 2 slots
+    of 8 pages: without a draft the traced steps are the chunks at depths 8
+    and 16, then decodes at 19 and 20, which see 2, 3, 3 and 3 pages of one
+    slot; the idle slot sees none."""
+    cfg = get_config("llama3.2-1b").smoke().serving()
+    params = build_model(cfg).init(jax.random.PRNGKey(0))
+    draft = dict(draft_cfg=cfg, draft_params=params) if spec_tokens else {}
+    eng = ServingEngine(cfg, params, tpu_slice_cluster(n_slices=1), eos_id=-1,
+                        max_len=64, slots=2, prefill_chunk=CHUNK,
+                        plan_cfg=PlanConfig(method="etf", kv_page_tokens=PAGE,
+                                            spec_tokens=spec_tokens), **draft)
+    eng.submit(Request(rid=0, prompt=list(range(1, 20)), max_new_tokens=3))
+    eng.step()                                   # compile outside the trace
+    with jax.profiler.trace(str(tmp_path)):
+        while eng.step():
+            pass
+    batch = [s[3] for s in _read_spans(tmp_path) if s[0] == "engine.batch"]
+    kv = cfg.n_kv_heads
+    assert {m["attn_blocks"] for m in batch} == {2 * kv * 8}   # one query block
+    live = [m["attn_live"] for m in batch]
+    if spec_tokens:
+        assert live and all(2 * kv <= n <= 3 * kv for n in live)
+    else:
+        assert live == [2 * kv, 3 * kv, 3 * kv, 3 * kv]
 
 
 def test_counters_after_a_known_run():
