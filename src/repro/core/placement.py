@@ -91,15 +91,15 @@ class PlanConfig:
         (default) keeps the decode-only scoring.
     prefill_chunk:
         Tokens per prefill chunk for that scoring AND the serving engine's
-        interleaved prefill state machine (the engine reads it off its
-        ``plan_cfg``); ``None`` means whole-prompt (blocking) prefill.
+        fused step (the engine reads it off its ``plan_cfg`` and needs a
+        positive int); ``None`` scores whole-prompt prefill, which no
+        engine serves.
     fused_prefill:
         ``True`` (default) scores prefill chunks at the fused mixed-batch
         marginal rate — the serving engine packs prompt chunks into the
         live decode batch, so a chunk pays no second weight stream or
-        kernel launch — and tells the engine to serve that way.  ``False``
-        restores standalone per-chunk costing and the legacy interleaved
-        engine path.
+        kernel launch.  ``False`` scores standalone per-chunk forwards,
+        which no engine serves: ``ServingEngine`` refuses such a plan.
     coarsen:
         Apply GCOF fusion coarsening before solving (paper Fig. 10 c/d vs
         a/b).
@@ -172,14 +172,12 @@ class PlanConfig:
     # MILP busy accumulators) charge the implied chunked-prefill work per
     # request; 0 keeps decode-only scoring
     prompt_len: int = 0
-    # prefill chunk size for that scoring and for the engine's interleaved
-    # prefill state machine; None = whole-prompt (blocking) prefill
+    # prefill chunk size for that scoring and for the engine's fused step;
+    # None scores whole-prompt prefill (the engine refuses it)
     prefill_chunk: Optional[int] = 64
-    # score prefill chunks at the fused mixed-batch marginal rate (the
-    # engine's default: chunks packed into the live decode batch share its
-    # weight stream and kernel launch).  The engine also reads this to pick
-    # its serving path — fused one-program steps (True) vs the legacy
-    # interleaved per-slot prefill forwards (False)
+    # score prefill chunks at the fused mixed-batch marginal rate (chunks
+    # packed into the live decode batch share its weight stream and kernel
+    # launch); False scores standalone chunk forwards (the engine refuses it)
     fused_prefill: bool = True
     # ---- paged KV cache (serving engine + Eq. 5 accounting) -------------
     # tokens per KV page: set to page the serving engine's KV cache (fixed

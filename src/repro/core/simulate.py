@@ -25,11 +25,9 @@ generalizes the event loop to N requests:
 
 * each request is an independent copy of the task graph (its own precedence
   edges), released when the request arrives (and, with ``max_in_flight``,
-  admitted only when a serving slot frees — continuous batching).  The
-  ``batching`` mode mirrors the serving engine: ``"ragged"`` refills a freed
-  slot immediately (per-slot cache positions), ``"lockstep"`` admits cohort
-  waves that must fully drain first (the seed engine's shared-position
-  constraint); ``decode_batch`` scores ops with the batch-aware cost model;
+  admitted only when a serving slot frees — continuous batching: like the
+  serving engine's ragged batches, a freed slot is refilled immediately);
+  ``decode_batch`` scores ops with the batch-aware cost model;
 * devices and channels are SHARED across requests with the exact same
   semantics as the single-query simulator: one op at a time per device
   (Eq. 6), serialized flows per directed channel (Eq. 8), zero-cost
@@ -667,7 +665,6 @@ def simulate_pipeline(
     arrival=None,
     *,
     max_in_flight: Optional[int] = None,
-    batching: str = "ragged",
     decode_batch: int = 1,
     prompt_len: Union[None, int, Sequence[int]] = None,
     prefill_chunk: Optional[int] = None,
@@ -683,19 +680,9 @@ def simulate_pipeline(
 
     ``max_in_flight`` caps concurrency (serving slots): a request is admitted
     — its root tasks released — only once fewer than ``max_in_flight``
-    requests are unfinished, at ``max(arrival, slot-free time)``.
-
-    ``batching`` selects the admission model, matching the two serving-engine
-    modes:
-
-    * ``"ragged"`` (default) — admit-on-retire: any freed slot is refilled
-      immediately (the engine's per-slot cache positions make this the real
-      runtime behavior);
-    * ``"lockstep"`` — cohort waves: up to ``max_in_flight`` requests are
-      admitted together, and the next wave opens only after EVERY request of
-      the current wave completes (the seed engine's shared-``cache_pos``
-      constraint with mixed-depth requests — the model planner objectives
-      scored before ragged batching landed).
+    requests are unfinished, at ``max(arrival, slot-free time)``.  Admission
+    is admit-on-retire, as in the serving engine: any freed slot is refilled
+    immediately.
 
     ``decode_batch > 1`` applies the batch-aware cost model: each op is
     charged its amortized per-request time at that decode batch size
@@ -720,10 +707,6 @@ def simulate_pipeline(
     pass, so :func:`validate_pipeline_schedule` applies as-is."""
     if n_requests < 1:
         raise ValueError("n_requests must be >= 1")
-    if batching not in ("ragged", "lockstep"):
-        raise ValueError(
-            f"batching must be 'ragged' or 'lockstep', got {batching!r}"
-        )
     aug = aug or augment(graph)
     arrivals = _resolve_arrivals(n_requests, arrival)
     if arrivals != sorted(arrivals):
@@ -849,26 +832,9 @@ def simulate_pipeline(
     slots = max_in_flight if max_in_flight is not None else n_requests
     if slots < 1:
         raise ValueError("max_in_flight must be >= 1")
-    next_admit = 0
-    wave_open = 0            # unfinished requests of the current lockstep wave
-
-    def admit_wave(now: float) -> None:
-        """Release the next cohort of up to ``slots`` requests (lockstep):
-        each member enters at max(its arrival, the wave-open time), and the
-        NEXT wave opens only once every member of this one completes."""
-        nonlocal next_admit, wave_open
-        take = min(slots, n_requests - next_admit)
-        for rid in range(next_admit, next_admit + take):
-            push_event(max(now, arrivals[rid]), ("admit", rid))
-        next_admit += take
-        wave_open = take
-
-    if batching == "lockstep":
-        admit_wave(0.0)
-    else:
-        next_admit = min(slots, n_requests)
-        for rid in range(next_admit):
-            push_event(arrivals[rid], ("admit", rid))
+    next_admit = min(slots, n_requests)
+    for rid in range(next_admit):
+        push_event(arrivals[rid], ("admit", rid))
 
     makespan = 0.0
     while events:
@@ -894,12 +860,8 @@ def simulate_pipeline(
             else:
                 completions[rid] = t
                 completed_requests += 1
-                if batching == "lockstep":
-                    wave_open -= 1
-                    if wave_open == 0 and next_admit < n_requests:
-                        admit_wave(t)
-                elif next_admit < n_requests:
-                    # ragged admit-on-retire: the freed slot is refilled NOW
+                if next_admit < n_requests:
+                    # admit-on-retire: the freed slot is refilled NOW
                     push_event(max(t, arrivals[next_admit]), ("admit", next_admit))
                     next_admit += 1
         for dep in fanout.get(task, []):

@@ -109,8 +109,7 @@ def _serve_replicas(args, cfg, params, cluster, plan_cfg):
         slots=args.slots, max_len=args.max_len, plan_cfg=plan_cfg,
         config=RouterConfig(dispatch=args.dispatch),
         eos_id=-1,
-        admission=args.admission, batching=args.batching,
-        oversize=args.oversize,
+        admission=args.admission, oversize=args.oversize,
     )
     injector = None
     if args.fault_schedule:
@@ -190,22 +189,10 @@ def main(argv=None):
         help="KV-aware admission: hold requests in queue or reject them",
     )
     ap.add_argument(
-        "--batching", choices=("ragged", "lockstep"), default="ragged",
-        help="ragged = per-slot cache positions (continuous admission); "
-        "lockstep = seed-engine equal-depth cohorts (benchmark baseline)",
-    )
-    ap.add_argument(
         "--prefill-chunk", type=int, default=64, metavar="TOKENS",
-        help="interleave prompt prefill with decode in chunks of this many "
-        "tokens (0 = blocking whole-prompt prefill at admission); also the "
-        "chunk size the planner's prefill-aware throughput scoring assumes",
-    )
-    ap.add_argument(
-        "--no-fused-prefill", dest="fused_prefill", action="store_false",
-        help="serve prefill chunks as standalone batch-1 forwards between "
-        "decode steps (the legacy interleaved path) instead of packing them "
-        "into the decode batch's single fused forward per step; the planner "
-        "scores prefill at the matching rate",
+        help="stream prompts in chunks of this many tokens, packed into the "
+        "decode batch's one fused forward per step; also the chunk size the "
+        "planner's prefill-aware throughput scoring assumes",
     )
     ap.add_argument(
         "--kv-page-tokens", type=int, default=0, metavar="TOKENS",
@@ -295,6 +282,8 @@ def main(argv=None):
         "such chips bigger than this host)",
     )
     args = ap.parse_args(argv)
+    if args.prefill_chunk <= 0:
+        ap.error("--prefill-chunk must be a positive int")
 
     cache_dir = setup_compile_cache()
     cfg = get_config(args.arch)
@@ -319,9 +308,8 @@ def main(argv=None):
         # prefill-aware scoring (--prompt-len) only exists there
         objective="throughput" if args.slots > 1 else "latency",
         serving_slots=args.slots,
-        prefill_chunk=args.prefill_chunk or None,
+        prefill_chunk=args.prefill_chunk,
         prompt_len=args.prompt_len,
-        fused_prefill=args.fused_prefill,
         kv_page_tokens=args.kv_page_tokens or None,
         prefix_sharing=args.prefix_sharing,
         kv_residency=args.kv_residency,
@@ -359,7 +347,6 @@ def main(argv=None):
             state_path=args.derate_state,
         ),
         admission=args.admission,
-        batching=args.batching,
         oversize=args.oversize,
     )
     injector = None
@@ -377,9 +364,7 @@ def main(argv=None):
         f"[serve] {args.arch}: placement={engine.placement_result.method} "
         f"stages={len(engine.executor.stages)} devices={len(engine.devices)} "
         f"adapt_every={args.adapt_every or 'off'} "
-        "prefill_chunk="
-        f"{engine.prefill_chunk if engine._chunked_prefill_on() else 'blocking'}"
-        f" step={'fused' if engine._fused_on() else 'interleaved'}"
+        f"prefill_chunk={engine.prefill_chunk}"
         + (
             f" kv=paged({engine.kv_page_tokens}"
             f"{',shared' if engine.prefix_sharing else ''})"

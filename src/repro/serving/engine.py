@@ -2,16 +2,12 @@
 
 * fixed decode slots (classic continuous batching: a finished sequence frees
   its slot for the next queued request; prefill happens into the slot),
-* **ragged batches** (default): every slot carries its own cache position —
-  the decode batch hands the executor a ``(slots,)`` ``cache_pos`` vector, so
-  each row writes KV at its own depth and masks over its own valid length.
+* **ragged batches**: every slot carries its own cache position — each step
+  hands the executor a ``(slots,)`` ``cache_pos`` vector, so each row
+  writes KV at its own depth and masks over its own valid length.
   Admission is therefore *continuous*: any free slot is filled immediately,
   regardless of the other slots' depths (mixed prompt lengths, hot-swap
-  re-queues mid-generation — no cohort waves).  ``batching="lockstep"``
-  keeps the seed engine's shared-``cache_pos`` behavior, where admission
-  must hold a request until every active slot sits at exactly its resume
-  depth — retained as the benchmark baseline
-  (``benchmarks/ragged_batching.py``),
+  re-queues mid-generation),
 * Moirai placement computed once at startup from the layer-level OpGraph and
   the cluster spec (and re-computed by ``on_device_failure`` — elastic).
   With more than one decode slot the engine serves a *pipeline* of requests,
@@ -42,34 +38,19 @@
   residency of ``active+1`` concurrent sequences still fits every planned
   device (runtime Eq. 5) — plan-time ``serving_slots`` sizing is necessary
   but not sufficient after failures/derates shrink the effective cluster,
-* **chunked prefill interleaved with ragged decode** (default in ragged
-  mode): an admitted request's prompt is consumed ``prefill_chunk`` tokens
-  at a time — each chunk is one batch-1 forward into that slot's cache row
-  at its ``cache_pos``, run BETWEEN batched decode steps (at most one chunk
-  per engine step, round-robin over mid-prefill slots), so a single long
-  prompt can no longer head-of-line-block decode on every active slot the
-  way the inline whole-prompt prefill did.  ``prefill_chunk=None`` restores
-  the blocking whole-prompt prefill (and lockstep batching always uses it —
-  the seed baseline).  Re-queued hot-swap requests re-prefill
-  prompt+generated through the same chunked state machine.  Prefill
-  forwards are tagged so observation windows feed the derate calibrator
-  decode samples only — a burst of long prompts must not read as device
-  drift,
-* **fused mixed prefill/decode steps** (default when chunking is on): the
-  pending prefill chunks are packed INTO the batched ragged decode forward
-  as rows of the same ``[slots, S]`` batch — per-row ``(cache_pos, q_len)``
-  gives decode rows ``q_len=1``, prefill rows ``q_len=chunk``, idle rows
-  ``q_len=0``; every row writes KV / advances SSM state over exactly its
-  valid span at its own depth.  ONE compiled program serves the whole step
-  (S = ``prefill_chunk`` when any prefill is pending, else 1 — two compiled
-  shapes total), every mid-prefill slot advances every step (no round-robin
-  serialization), and the per-slot cache rows are written in place (the
-  legacy interleaved path's O(max_len/chunk) full-row gather/scatter per
-  chunk is gone).  Each fused forward's wall time is split into decode and
-  prefill shares by the cost model's predicted per-stage fractions before
-  it is recorded, so observation-window hygiene is preserved.
-  ``fused=False`` restores the PR-5 interleaved path (one batch-1 chunk
-  between decode steps),
+* **one fused forward per step**: an admitted request's prompt (plus, after
+  a hot swap, its generated tokens) streams in ``prefill_chunk`` tokens at
+  a time, packed INTO the batched ragged decode forward as rows of the same
+  ``[slots, S]`` batch — per-row ``(cache_pos, q_len)`` gives decode rows
+  ``q_len=1``, prefill rows ``q_len=chunk``, idle rows ``q_len=0``; every
+  row writes KV / advances SSM state over exactly its valid span at its own
+  depth.  S is ``prefill_chunk`` when any prefill is pending, else 1 (two
+  compiled shapes in all), every mid-prefill slot advances every step, and
+  a long prompt never stalls the decoding slots.  Each fused forward's wall
+  time is split into decode and prefill shares by the cost model's
+  predicted per-stage fractions before it is recorded, so observation
+  windows feed the derate calibrator decode samples only — a burst of long
+  prompts must not read as device drift,
 * **speculative draft/target serving** (``draft_cfg``): the per-slot step
   contract generalizes from "decode rows advance exactly one token" to
   "rows advance a variable ``accepted`` count" — a second stage pipeline
@@ -196,24 +177,11 @@ class ServingEngine:
         admission: ``"queue"`` (default) holds requests in the queue while
             their KV residency would overflow a planned device;
             ``"reject"`` retires them immediately with ``rejected=True``.
-        batching: ``"ragged"`` (default) decodes every slot at its own cache
-            position (continuous admission into any free slot);
-            ``"lockstep"`` shares one position across the batch and admits
-            only equal-depth cohorts (the seed-engine behavior, kept as the
-            benchmark baseline).
-        prefill_chunk: tokens consumed per interleaved prefill chunk;
-            ``None`` = blocking whole-prompt prefill at admission (the
-            pre-ISSUE-5 behavior).  Defaults to the plan config's
+        prefill_chunk: prompt tokens a mid-prefill slot consumes per step
+            (a positive int).  Defaults to the plan config's
             ``prefill_chunk`` so the planner scores the prefill schedule
-            the engine actually runs.  Chunking engages in ragged batching
-            only — lockstep keeps the seed engine's blocking prefill.
-        fused: pack pending prefill chunks INTO the batched ragged decode
-            forward (per-row ``(cache_pos, q_len)``) so one compiled
-            program serves the whole step.  Defaults to the plan config's
-            ``fused_prefill`` ("score what the engine runs"); only engages
-            when chunked prefill is on (ragged batching + a chunk size).
-            ``False`` restores the PR-5 interleaved path: one batch-1
-            chunk forward between decode steps.
+            the engine actually runs; for the same reason a plan config
+            with ``fused_prefill=False`` is refused.
         oversize: what to do with a request whose ``prompt +
             max_new_tokens`` cannot fit a ``max_len`` cache row:
             ``"truncate"`` (default) drops the OLDEST prompt tokens to fit
@@ -235,8 +203,8 @@ class ServingEngine:
             JOINTLY over the merged draft+target graph
             (:mod:`repro.core.spec_plan`): shared Eq. 5 memory,
             per-device busy summed across both models at the plan's
-            ``acceptance_rate``.  Requires the fused ragged path and
-            ``draft_params``; incompatible with ``placement_result``.
+            ``acceptance_rate``.  Requires ``draft_params``; incompatible
+            with ``placement_result``.
         draft_params: the draft model's parameters (placed onto the draft
             stages' devices at build).
     """
@@ -258,9 +226,7 @@ class ServingEngine:
         straggler_factor: float = 4.0,
         adapt: Optional[AdaptationConfig] = None,
         admission: str = "queue",
-        batching: str = "ragged",
         prefill_chunk: Any = _FROM_PLAN,
-        fused: Any = _FROM_PLAN,
         oversize: str = "truncate",
         placement_result: Optional[PlacementResult] = None,
         draft_cfg: Optional[ModelConfig] = None,
@@ -278,11 +244,6 @@ class ServingEngine:
         if admission not in ("queue", "reject"):
             raise ValueError(f"admission must be 'queue' or 'reject', got {admission!r}")
         self.admission = admission
-        if batching not in ("ragged", "lockstep"):
-            raise ValueError(
-                f"batching must be 'ragged' or 'lockstep', got {batching!r}"
-            )
-        self.batching = batching
         if oversize not in ("truncate", "reject"):
             raise ValueError(
                 f"oversize must be 'truncate' or 'reject', got {oversize!r}"
@@ -307,29 +268,26 @@ class ServingEngine:
             plan_cfg = dataclasses.replace(plan_cfg, serving_slots=slots)
         self.plan_cfg = plan_cfg
 
-        # interleaved prefill: chunk size comes from the plan config unless
-        # overridden, so "score what the engine runs" holds by construction
+        # the chunk size comes from the plan config unless overridden, and
+        # the plan must score the fused step, so "score what the engine
+        # runs" holds by construction
         if prefill_chunk is ServingEngine._FROM_PLAN:
             prefill_chunk = self.plan_cfg.prefill_chunk
-        if prefill_chunk is not None and int(prefill_chunk) <= 0:
+        if prefill_chunk is None or int(prefill_chunk) <= 0:
             raise ValueError(
-                f"prefill_chunk must be a positive int or None, got {prefill_chunk!r}"
+                f"prefill_chunk must be a positive int, got {prefill_chunk!r}"
             )
-        self.prefill_chunk = None if prefill_chunk is None else int(prefill_chunk)
-
-        # fused mixed-batch stepping follows the plan config unless
-        # overridden — same "score what the engine runs" contract as
-        # prefill_chunk (the planner's fused_prefill flag and this engage
-        # together by default)
-        if fused is ServingEngine._FROM_PLAN:
-            fused = getattr(self.plan_cfg, "fused_prefill", True)
-        self.fused = bool(fused)
+        self.prefill_chunk = int(prefill_chunk)
+        if not getattr(self.plan_cfg, "fused_prefill", True):
+            raise ValueError(
+                "plan_cfg.fused_prefill=False scores prefill chunks as "
+                "standalone forwards, but the engine serves them fused"
+            )
 
         # paged KV cache (PlanConfig.kv_page_tokens): fixed-size page pools
         # per stage device + a host-owned per-slot page table (KVPool), with
-        # optional hash-based prefix sharing.  Paged serving rides the fused
-        # ragged path — every KV write is span-masked through the table, so
-        # the legacy full-row gather/scatter paths never see pools
+        # optional hash-based prefix sharing; every KV write is span-masked
+        # through the table
         self.kv_page_tokens = getattr(self.plan_cfg, "kv_page_tokens", None)
         self.prefix_sharing = bool(
             getattr(self.plan_cfg, "prefix_sharing", True)
@@ -340,28 +298,17 @@ class ServingEngine:
                 raise ValueError(
                     f"kv_page_tokens must be positive, got {self.kv_page_tokens}"
                 )
-            if not (self.batching == "ragged" and self.prefill_chunk and self.fused):
-                raise ValueError(
-                    "paged KV (kv_page_tokens) requires ragged batching with "
-                    "chunked + fused prefill"
-                )
 
         # speculative decoding (variable-advance steps): a draft model
         # proposes plan_cfg.spec_tokens greedy tokens per ready slot between
         # target steps; ONE fused target forward verifies them as q_len=k+1
         # rows and each slot advances by its accepted count + the bonus
-        # token.  Spec rides the fused ragged path — the verify row IS a
-        # mixed-batch row with a bigger q_len — so it requires it.
+        # token: the verify row IS a mixed-batch row with a bigger q_len.
         self.draft_cfg = draft_cfg
         self.draft_params = draft_params
         if draft_cfg is not None:
             if draft_params is None:
                 raise ValueError("speculative serving needs draft_params")
-            if not (self.batching == "ragged" and self.prefill_chunk and self.fused):
-                raise ValueError(
-                    "speculative serving (draft_cfg) requires ragged "
-                    "batching with chunked + fused prefill"
-                )
             if draft_cfg.family not in ("dense", "moe"):
                 # the stage executor serves attention-family blocks only
                 # (the same pre-existing constraint the target is under);
@@ -632,15 +579,9 @@ class ServingEngine:
         # lived in the old executor's cache rows
         self._prefill_toks: Dict[int, List[int]] = {}
         self._prefill_done: Dict[int, int] = {}
-        self._prefill_rr = 0
         self._pred_stage_s = self._predict_stage_times()
-        # per-chunk predictions only make sense when prefill actually runs
-        # in chunks — blocking/lockstep prefill forwards span whole prompts
-        # of varying length, which no single prediction can anchor
-        self._pred_prefill_stage_s = (
-            self._predict_prefill_stage_times(self.prefill_chunk)
-            if self._chunked_prefill_on()
-            else []
+        self._pred_prefill_stage_s = self._predict_prefill_stage_times(
+            self.prefill_chunk
         )
         # per-stage op-class weights are fixed between rebuilds — compute
         # once, not every observation window
@@ -683,7 +624,13 @@ class ServingEngine:
         silently clamp/corrupt the slot's KV (the retirement-time capacity
         check only fires after the damage).  Per the ``oversize`` policy the
         request is either truncated (oldest prompt tokens dropped, flagged
-        ``truncated=True``) or rejected outright."""
+        ``truncated=True``) or rejected outright.
+
+        A request with neither prompt nor generated tokens has nothing to
+        predict its first token from: it raises ``ValueError`` and is not
+        queued."""
+        if not req.prompt and not req.out_tokens:
+            raise ValueError(f"request {req.rid} has an empty prompt")
         if self.draining:
             raise RuntimeError(
                 "engine is draining: new requests must go to another replica"
@@ -743,25 +690,6 @@ class ServingEngine:
             if self.active[slot] is None and qi is not None:
                 head = self.queue[qi]
                 n_active = sum(r is not None for r in self.active)
-                if self.batching == "lockstep":
-                    # lockstep cohort check (legacy baseline): batched decode
-                    # shares one cache position across slots, so a request
-                    # may only join a batch whose active slots sit at
-                    # EXACTLY its resume depth (prompt + generated).
-                    # Unequal-depth requests — mixed prompt lengths, or
-                    # hot-swap re-queues of sequences that were at different
-                    # depths — wait for the wave to drain instead of
-                    # silently corrupting the laggard's KV rows.  Ragged
-                    # batching (the default) has no such constraint: every
-                    # slot carries its own cache position.
-                    pos_set = {
-                        int(self.slot_pos[i])
-                        for i, r in enumerate(self.active)
-                        if r is not None
-                    }
-                    depth = len(head.prompt) + len(head.out_tokens)
-                    if pos_set and pos_set != {depth}:
-                        break
                 toks_head = list(head.prompt) + list(head.out_tokens)
                 # paged: the sequence's pages (net of reusable shared-prefix
                 # pages) must be obtainable from the pool — free now or
@@ -802,50 +730,33 @@ class ServingEngine:
                 req.started = True
                 self.active[slot] = req
                 self._counters["admitted"] += 1
-                # prompt + out_tokens so a request re-queued by a hot-swap
-                # resumes its greedy decode exactly where it was
-                toks_list = toks_head
-                if self._chunked_prefill_on() and toks_list:
-                    # interleaved prefill: only REGISTER the work here — the
-                    # prompt is consumed one prefill_chunk per engine step
-                    # (between decode batches) by _advance_prefill, directly
-                    # into this slot's cache row
-                    self._ensure_caches()
-                    reuse = 0
-                    if self._kv_pool is not None:
-                        # map pages; shared-prefix hits skip their prefill
-                        # chunks (reuse), a partially matched page is COW'd
-                        # on-device before any write can land in it
-                        reuse, copies = self._kv_pool.alloc_sequence(
-                            slot, toks_list, total_head
-                        )
-                        if copies:
-                            self.caches = self.executor.copy_pages(
-                                self.caches, copies
-                            )
-                    self._prefill_toks[slot] = toks_list
-                    self._prefill_done[slot] = reuse
-                    self.slot_pos[slot] = reuse
-                    # new tenant: the draft re-prefills this slot's stream
-                    # from token 0 (old rows are garbage it overwrites), and
-                    # spec writes must stay inside the mapped pages
-                    self._draft_pos[slot] = 0
-                    self._slot_cap[slot] = (
-                        total_head if self._kv_pool is not None else self.max_len
+                # register the prompt (+ out_tokens, so a request re-queued by
+                # a hot-swap resumes its greedy decode exactly where it was):
+                # the fused steps consume it one prefill_chunk per step,
+                # directly into this slot's cache
+                self._ensure_caches()
+                reuse = 0
+                if self._kv_pool is not None:
+                    # map pages; shared-prefix hits skip their prefill
+                    # chunks (reuse), a partially matched page is COW'd
+                    # on-device before any write can land in it
+                    reuse, copies = self._kv_pool.alloc_sequence(
+                        slot, toks_head, total_head
                     )
-                    continue
-                # blocking whole-prompt prefill (lockstep baseline, or
-                # prefill_chunk=None): batch-1 prefill into the slot's row
-                toks = jnp.asarray([toks_list], jnp.int32)
-                logits, slot_caches = self._prefill_slot(toks)
-                nxt = int(jnp.argmax(logits[0, -1]))
-                self._counters["prefill_tokens"] += len(toks_list)
-                self._write_slot_cache(slot, slot_caches)
-                self.slot_pos[slot] = len(toks_list)
-                # the prefill-produced token can itself finish the request
-                # (EOS, or a re-queued request one token short of budget) —
-                # retire NOW or a decode step would overshoot the budget
-                self._emit(slot, nxt)
+                    if copies:
+                        self.caches = self.executor.copy_pages(
+                            self.caches, copies
+                        )
+                self._prefill_toks[slot] = toks_head
+                self._prefill_done[slot] = reuse
+                self.slot_pos[slot] = reuse
+                # new tenant: the draft re-prefills this slot's stream
+                # from token 0 (old rows are garbage it overwrites), and
+                # spec writes must stay inside the mapped pages
+                self._draft_pos[slot] = 0
+                self._slot_cap[slot] = (
+                    total_head if self._kv_pool is not None else self.max_len
+                )
 
     def _ensure_caches(self):
         """Lazily allocate device caches: paged pools (+ trash page) when a
@@ -858,105 +769,6 @@ class ServingEngine:
             )
         else:
             self.caches = self.executor.init_caches(self.slots, self.max_len)
-
-    def _chunked_prefill_on(self) -> bool:
-        """Interleaved chunked prefill is a ragged-batching feature: the
-        lockstep baseline keeps the seed engine's blocking prefill (its
-        equal-depth cohort admission is defined around completed prefills)."""
-        return self.prefill_chunk is not None and self.batching == "ragged"
-
-    def _fused_on(self) -> bool:
-        """Fused mixed-batch stepping rides on chunked prefill: prefill rows
-        can only join the decode batch when prompts arrive in fixed-shape
-        chunks (ragged batching + a chunk size)."""
-        return self.fused and self._chunked_prefill_on()
-
-    def _prefill_slot(self, toks):
-        caches = self.executor.init_caches(1, self.max_len)
-        logits, new_caches = self.executor.forward(
-            toks, caches, cache_pos=0, kind="prefill"
-        )
-        return logits, new_caches
-
-    def _slot_row_caches(self, slot: int):
-        """Batch-1 view of ``slot``'s cache rows (one row per stage layer) —
-        the chunk forward reads/writes the live row, not a fresh cache.
-
-        LEGACY interleaved path only (``fused=False``): the gather here
-        (and the scatter in ``_write_slot_cache``) copies the full
-        ``max_len`` row per layer per chunk — O(max_len/chunk)× more cache
-        traffic than the chunk writes.  The fused path never calls either:
-        prefill chunks ride as rows of the batched forward and the per-row
-        masked KV scatter touches only the written span in place."""
-        return [
-            [
-                {key: a[slot : slot + 1] for key, a in layer.items()}
-                for layer in st_caches
-            ]
-            for st_caches in self.caches
-        ]
-
-    def _advance_prefill(self) -> Optional[int]:
-        """Consume ONE ``prefill_chunk``-token chunk for the next mid-prefill
-        slot (round-robin), forwarded batch-1 into that slot's cache row at
-        its current depth.  At most one chunk per engine step, so active
-        slots never stall more than one chunk between decode steps.  Returns
-        the advanced slot index (None when nothing is mid-prefill)."""
-        if not self._prefill_toks:
-            return None
-        slot = None
-        for off in range(self.slots):
-            cand = (self._prefill_rr + off) % self.slots
-            if cand in self._prefill_toks:
-                slot = cand
-                break
-        self._prefill_rr = (slot + 1) % self.slots
-        toks_all = self._prefill_toks[slot]
-        done = self._prefill_done[slot]
-        n = min(self.prefill_chunk, len(toks_all) - done)
-        # fixed-shape chunks: pad the tail chunk to prefill_chunk tokens so
-        # EVERY chunk forward shares one compiled (1, chunk) program —
-        # whole-prompt prefill recompiles per distinct prompt length, which
-        # is its own head-of-line stall on an XLA backend.  Pad KV rows land
-        # beyond the prompt: causally masked until the decode steps
-        # overwrite them position by position, so they never leak into
-        # logits.  (Skipped in the rare case the pad would spill past the
-        # cache row — the write start would clamp and corrupt real entries.)
-        pad = self.prefill_chunk - n
-        if pad and done + self.prefill_chunk > self.max_len:
-            pad = 0
-        chunk_toks = toks_all[done : done + n] + [0] * pad
-        chunk = jnp.asarray([chunk_toks], jnp.int32)
-        row = self._slot_row_caches(slot)
-        logits, row = self.executor.forward(
-            chunk, row, cache_pos=int(done), kind="prefill"
-        )
-        self._write_slot_cache(slot, row)
-        self._counters["prefill_tokens"] += n
-        done += n
-        self._prefill_done[slot] = done
-        # a garbage decode row writes (and is later overwritten) at this
-        # depth while the prefill is still in flight — see step()
-        self.slot_pos[slot] = done
-        if done == len(toks_all):
-            del self._prefill_toks[slot]
-            del self._prefill_done[slot]
-            # the next token comes from the LAST REAL prompt row (row n-1),
-            # not the padded tail
-            nxt = int(jnp.argmax(logits[0, n - 1]))
-            # the prefill-produced token can itself finish the request
-            self._emit(slot, nxt)
-        return slot
-
-    def _write_slot_cache(self, slot: int, slot_caches):
-        if self.caches is None:
-            self.caches = self.executor.init_caches(self.slots, self.max_len)
-        for si, st_caches in enumerate(slot_caches):
-            for li, layer_cache in enumerate(st_caches):
-                for key, rows in layer_cache.items():
-                    self.caches[si][li][key] = (
-                        self.caches[si][li][key].at[slot].set(rows[0])
-                    )
 
     # ------------------------------------------------------------------
     def _record_finished(self, req: Request):
@@ -981,9 +793,8 @@ class ServingEngine:
             req.done = True
             req.state = "finished"
             self.active[slot] = None
-            # park the freed slot at depth 0: an inactive row's garbage
-            # decode then writes (and attends) at its row's position 0,
-            # which the next admission's full-row prefill overwrites anyway
+            # park the freed slot at depth 0 (an idle row has q_len 0 and
+            # writes nothing; the next tenant prefills from its reuse depth)
             self.slot_pos[slot] = 0
             self._draft_pos[slot] = 0
             self._slot_cap[slot] = self.max_len
@@ -1014,28 +825,18 @@ class ServingEngine:
                     self.observe_window()
 
     def step(self) -> int:
-        """One engine iteration: admit → advance at most one prefill chunk →
-        batched decode → retire → (possibly) close an observation window.
-        Returns the number of active sequences that made progress this step
-        (decoded a token, or advanced a prefill chunk).
+        """One engine iteration: admit → one fused forward → retire →
+        (possibly) close an observation window.  Returns the number of
+        active sequences that made progress this step (decoded a token, or
+        advanced a prefill chunk).
 
-        Ragged batching (default): the decode batch carries a ``(slots,)``
-        ``cache_pos`` vector — every slot writes KV at its own depth and
-        masks over its own valid length, so any mix of depths decodes
-        together and admission is continuous (``_admit`` fills any free
-        slot immediately).  Slots whose prompt is still being consumed by
-        the chunked-prefill state machine sit the decode out (their row
-        decodes garbage that the next chunk overwrites); everyone else
-        decodes every step — a long prompt no longer stalls the batch.
-        ``batching="lockstep"`` shares one position (the max over active
-        slots) and relies on ``_admit``'s equal-depth cohort check — the
-        seed-engine behavior kept as a baseline.
-
-        With ``fused`` on (the default when chunking is on), the step runs
-        ONE fused forward instead: pending prefill chunks pack into the
-        decode batch as rows with their own ``(cache_pos, q_len)``, every
-        mid-prefill slot advances a chunk every step, and the compiled
-        program count per step drops from two to one.
+        The forward is ``[slots, S]`` with a ``(slots,)`` ``cache_pos`` and
+        ``q_lens`` (see :meth:`_step_fused`): decode rows, every
+        mid-prefill slot's next chunk and idle rows ride together, each at
+        its own depth, so admission is continuous and a long prompt never
+        stalls the decoding slots.  With a draft attached the step is
+        :meth:`_step_spec`, whose verify rows carry ``spec_tokens+1``
+        queries.
 
         An attached :class:`~repro.serving.faults.FaultInjector` is polled
         FIRST — scheduled faults land before admission/decode, so a step-N
@@ -1049,54 +850,9 @@ class ServingEngine:
             self._injector.on_step(self)
         with span("engine.admit"):
             self._admit()
-        if self._fused_on():
-            if self._spec_on():
-                return self._step_spec()
-            return self._step_fused()
-        adv_slot = self._advance_prefill() if self._prefill_toks else None
-        if adv_slot is not None:
-            self._counters["chunk_steps"] += 1
-        # decode-ready slots: active AND fully prefilled
-        idx = [
-            i for i, r in enumerate(self.active)
-            if r is not None and i not in self._prefill_toks
-        ]
-        # progress count: slots that decoded a token, plus the slot whose
-        # prefill advanced — counted once if its final chunk let it do both
-        progressed = set(idx)
-        if adv_slot is not None:
-            progressed.add(adv_slot)
-        if not idx:
-            return len(progressed)
-        # batched single-token decode over ALL slots (inactive and
-        # mid-prefill slots decode garbage into their own rows — inactive
-        # rows are masked at retirement, mid-prefill rows are overwritten
-        # by their next chunk)
-        with span("engine.batch"):
-            last = [
-                (
-                    self.active[i].out_tokens[-1]
-                    if self.active[i] and i not in self._prefill_toks
-                    else 0
-                )
-                for i in range(self.slots)
-            ]
-            toks = jnp.asarray(last, jnp.int32)[:, None]
-            if self.batching == "lockstep":
-                pos = int(max(self.slot_pos[i] for i in idx))
-            else:
-                pos = np.asarray(self.slot_pos, np.int32)  # a depth per slot
-        logits, self.caches = self.executor.forward(
-            toks, self.caches, cache_pos=pos, kind="decode"
-        )
-        with span("engine.sample"):
-            nxt = np.asarray(jnp.argmax(logits[:, -1], axis=-1))
-        with span("engine.retire"):
-            for i in idx:
-                self.slot_pos[i] += 1
-                self._emit(i, int(nxt[i]))
-            self._tick_window()
-        return len(progressed)
+        if self._spec_on():
+            return self._step_spec()
+        return self._step_fused()
 
     def _fused_decode_frac(self, n_prefill_rows: int) -> Optional[List[float]]:
         """Predicted decode share of each stage's wall time in a fused
@@ -1138,10 +894,9 @@ class ServingEngine:
         ``(cache_pos, q_len)`` — S is ``prefill_chunk`` when any prefill is
         pending, else 1 (two compiled shapes total).  Decode rows carry
         ``q_len=1`` at their decode depth, prefill rows their chunk at its
-        offset, idle rows ``q_len=0`` (they write NOTHING — unlike the
-        legacy path's garbage rows).  Every mid-prefill slot advances every
-        step, and the slot cache rows are written in place (no
-        ``_slot_row_caches`` gather / ``_write_slot_cache`` scatter)."""
+        offset, idle rows ``q_len=0`` (they write NOTHING).  Every
+        mid-prefill slot advances every step, and the slot cache rows are
+        written in place."""
         idx = [
             i for i, r in enumerate(self.active)
             if r is not None and i not in self._prefill_toks
@@ -1217,7 +972,7 @@ class ServingEngine:
     # ------------------------------------------------------------------
     def _spec_on(self) -> bool:
         """Speculative stepping is active when a draft pipeline was built
-        (``draft_cfg`` given; requires the fused ragged path)."""
+        (``draft_cfg`` given)."""
         return self._draft_executor is not None
 
     def _ensure_draft_caches(self):
@@ -1562,10 +1317,9 @@ class ServingEngine:
         hot-swap.  Their generated tokens are kept: on re-admission the
         prefill covers prompt + out_tokens, so greedy decoding resumes
         exactly where it stopped (caches are rebuilt, generated work is not
-        lost).  With chunked prefill on, the re-prefill runs through the
-        same interleaved state machine — chunk by chunk, never as one
-        monolithic prompt+generated pass — so a hot-swap cannot reintroduce
-        the head-of-line stall it is supposed to avoid.  Mid-prefill
+        lost).  The re-prefill streams in chunk by chunk like any prompt,
+        never as one monolithic prompt+generated pass, so a hot-swap cannot
+        reintroduce a head-of-line stall.  Mid-prefill
         progress itself cannot survive (the chunks written so far live in
         the old topology's cache rows), so those requests restart their
         prefill from token 0."""
@@ -1719,9 +1473,9 @@ class ServingEngine:
         return [pl[st.node_ids[0]] for st in self.executor.stages]
 
     def _decode_batch(self) -> int:
-        """The decode batch the executor actually runs: EVERY step decodes
-        all ``slots`` rows in one batched forward (inactive rows decode
-        garbage), so observed stage times are whole-batch times at this
+        """The decode batch the executor actually runs: EVERY step runs
+        all ``slots`` rows in one batched forward (idle rows with
+        ``q_len`` 0), so observed stage times are whole-batch times at this
         width — predictions must use the batch-aware cost model at the same
         width or the per-class amortization skews the obs/pred ratios."""
         return max(int(self.slots), 1)
@@ -1936,12 +1690,12 @@ class ServingEngine:
 
     def _predict_prefill_stage_times(self, tokens: int) -> List[float]:
         """Predicted per-stage seconds of ONE ``tokens``-token prefill chunk
-        (batch-1 — the chunk forward runs a single slot's row), from the
-        same cost model the decode predictions use: each stage node is
-        rescaled to the chunk's token count relative to the graph's build
-        seq_len (``core.simulate.scale_node_to_tokens``).  The prediction
-        anchors attention's quadratic share at a chunk-local KV context
-        (one prediction serves every chunk of the prompt; late chunks
+        row (one slot's chunk, standalone-costed), from the same cost model
+        the decode predictions use: each stage node is rescaled to the
+        chunk's token count relative to the graph's build seq_len
+        (``core.simulate.scale_node_to_tokens``).  The prediction anchors
+        attention's quadratic share at a chunk-local KV context (one
+        prediction serves every chunk of the prompt; late chunks
         attending a longer cache show up as obs/pred ratio > 1 in the
         report, which is the point of surfacing them).  Feeds the
         ``straggler_report``'s prefill section so prompt work is visible,
@@ -1993,8 +1747,8 @@ class ServingEngine:
             A dict with ``stages`` (per-stage DECODE stats incl.
             ``predicted_s`` and ``obs_over_pred``), ``median_p95``,
             ``median_ratio``, the flagged ``stragglers`` stage indices, and
-            a ``prefill`` section (per-stage prefill-forward stats with
-            per-chunk predictions when chunking is on) — prompt work is
+            a ``prefill`` section (per-stage prefill-share stats with
+            per-chunk predictions) — prompt work is
             visible in the report but never mixed into the decode ratios
             that drive the derate loop.
         """
@@ -2035,9 +1789,9 @@ class ServingEngine:
             baseline = float(np.median(others)) if others else s["obs_over_pred"]
             if baseline > 0 and s["obs_over_pred"] > self.straggler_factor * baseline:
                 stragglers.append(i)
-        # prefill visibility: per-stage stats of the tagged prefill forwards
-        # (whole-run: history + undrained), with per-chunk predictions when
-        # chunking is on.  Report-only — the derate loop never sees these.
+        # prefill visibility: per-stage stats of the prefill shares (whole
+        # run: history + undrained), with per-chunk predictions.
+        # Report-only — the derate loop never sees these.
         pre_obs = [
             list(h) + t
             for h, t in zip(
@@ -2062,16 +1816,12 @@ class ServingEngine:
             ),
             "stragglers": stragglers,
             "prefill": {
-                # None = blocking whole-prompt prefill (no per-chunk preds)
-                "chunk": (
-                    self.prefill_chunk if self._chunked_prefill_on() else None
-                ),
-                # fused mode: these stats are the PREFILL SHARE of each
-                # fused forward (the executor splits one wall-clock sample
-                # into decode/prefill parts by the predicted per-stage
+                "chunk": self.prefill_chunk,
+                # these stats are the PREFILL SHARE of each fused forward
+                # (the executor splits one wall-clock sample into
+                # decode/prefill parts by the predicted per-stage
                 # fractions), so per-chunk predictions stay comparable and
                 # the decode section above stays prompt-burst-proof
-                "fused": self._fused_on(),
                 "stages": pre_stats,
             },
             # terminal requests pushed out of the bounded unclaimed ring

@@ -408,16 +408,12 @@ def test_engine_derates_device_hosting_majority_of_stages(small_model):
 
 def test_engine_hot_swap_resumes_in_flight_requests(small_model):
     """A mid-generation replan re-queues active requests; greedy decode
-    resumes from prompt+generated and produces the identical output.
-
-    ``fused=False`` pins the PR-5 interleaved engine's step cadence (one
-    prefill chunk AND a decode per step); the fused path's cadence is
-    covered in test_fused_step.py."""
+    resumes from prompt+generated and produces the identical output."""
     cfg, model, params = small_model
     cluster = tpu_slice_cluster(n_slices=1)
     mk = lambda: ServingEngine(cfg, params, cluster, slots=1, max_len=64,
                                plan_cfg=PlanConfig(method="round_robin"),
-                               eos_id=-1, fused=False)
+                               eos_id=-1)
     ref_eng = mk()
     ref = Request(rid=0, prompt=[1, 2, 3], max_new_tokens=6)
     ref_eng.submit(ref)
@@ -442,7 +438,7 @@ def test_engine_hot_swap_resumes_in_flight_requests(small_model):
     eng2 = mk()
     req2 = Request(rid=1, prompt=[1, 2, 3], max_new_tokens=6)
     eng2.submit(req2)
-    for _ in range(4):  # (prefill + decode) + 3 decode tokens = 5 of 6
+    for _ in range(5):  # prefill (1 token) + 4 decode tokens = 5 of 6
         eng2.step()
     assert len(req2.out_tokens) == 5
     eng2.derate = {0: 0.5}
@@ -452,20 +448,15 @@ def test_engine_hot_swap_resumes_in_flight_requests(small_model):
     assert req2.out_tokens == ref.out_tokens
 
 
-def test_engine_mixed_depth_lockstep_waits_ragged_admits(small_model):
-    """Mixed-depth admission across batching modes.
-
-    ``batching="lockstep"`` (seed behavior, kept as baseline): batched
-    decode shares one cache position, so a request whose depth differs from
-    the active batch must WAIT — serialized into waves, never corrupting
-    the laggard's KV.  ``batching="ragged"`` (default): every slot carries
-    its own cache position, so the same request is admitted IMMEDIATELY
-    mid-flight.  Both must match each request served alone."""
+def test_engine_mixed_depth_ragged_admits(small_model):
+    """Every slot carries its own cache position, so a request whose depth
+    differs from the active batch is admitted IMMEDIATELY mid-flight, and
+    both requests match their solo runs."""
     cfg, model, params = small_model
     cluster = tpu_slice_cluster(n_slices=1)
-    mk = lambda slots, **kw: ServingEngine(
+    mk = lambda slots: ServingEngine(
         cfg, params, cluster, slots=slots, max_len=64,
-        plan_cfg=PlanConfig(method="etf"), eos_id=-1, **kw)
+        plan_cfg=PlanConfig(method="etf"), eos_id=-1)
     solo = {}
     for rid, prompt in ((0, [1, 2, 3]), (1, [7, 8])):
         e = mk(1)
@@ -474,38 +465,16 @@ def test_engine_mixed_depth_lockstep_waits_ragged_admits(small_model):
         e.run_until_drained()
         solo[rid] = r.out_tokens
 
-    # --- lockstep baseline: the mixed-depth request waits for the wave ---
-    eng = mk(2, batching="lockstep")
+    eng = mk(2)
     r0 = Request(rid=0, prompt=[1, 2, 3], max_new_tokens=5)
     r1 = Request(rid=1, prompt=[7, 8], max_new_tokens=5)
     eng.submit(r0)
     eng.step()                      # r0 admitted and decoding
-    eng.submit(r1)                  # depth 2 != r0's position — must wait
-    assert eng.step() == 1 and eng.active.count(None) == 1
+    eng.submit(r1)                  # different depth — admitted anyway
+    assert eng.step() == 2 and eng.active.count(None) == 0
     eng.run_until_drained()
     assert r0.out_tokens == solo[0]
     assert r1.out_tokens == solo[1]
-
-    # --- ragged (default): the mixed-depth request joins mid-flight ------
-    eng3 = mk(2)
-    assert eng3.batching == "ragged"
-    r0 = Request(rid=0, prompt=[1, 2, 3], max_new_tokens=5)
-    r1 = Request(rid=1, prompt=[7, 8], max_new_tokens=5)
-    eng3.submit(r0)
-    eng3.step()                     # r0 admitted and decoding
-    eng3.submit(r1)                 # different depth — admitted anyway
-    assert eng3.step() == 2 and eng3.active.count(None) == 0
-    eng3.run_until_drained()
-    assert r0.out_tokens == solo[0]
-    assert r1.out_tokens == solo[1]
-
-    # equal-depth requests still batch together in lockstep mode
-    eng2 = mk(2, batching="lockstep")
-    a = Request(rid=0, prompt=[1, 2, 3], max_new_tokens=5)
-    b = Request(rid=1, prompt=[4, 5, 6], max_new_tokens=5)
-    eng2.submit(a)
-    eng2.submit(b)
-    assert eng2.step() == 2
 
 
 def test_engine_auto_windows_and_drain(small_model):

@@ -5,10 +5,9 @@ Covers the tentpole and its satellites:
 * chunked-vs-whole prefill greedy token identity — at the model level
   across architectures (gemma2 sliding windows, mamba2, zamba2, enc-dec,
   pallas/chunked/naive attention impls) and at the engine level;
-* the engine's interleaved prefill state machine — no-starvation (active
-  slots decode between every chunk), blocking-mode regression
-  (``prefill_chunk=None`` reproduces the PR-4 behavior), hot-swap re-queue
-  through the chunked path;
+* the engine's chunked prefill — no-starvation (active slots decode in
+  every step while a prompt streams in), a chunk size that is not a
+  positive int refused, hot-swap re-queue through the chunked path;
 * prefill visibility in the scoring stack — `simulate_pipeline(prompt_len,
   prefill_chunk)` with `validate_pipeline_schedule`'s prefill-task checks,
   `bottleneck_time`/MILP busy accumulators, `PlanConfig.prompt_len`;
@@ -143,53 +142,15 @@ def test_chunked_prefill_token_identity_encdec():
 
 
 # ----------------------------------------------------------------------
-# engine: interleaved prefill state machine
+# engine: chunked prefill
 # ----------------------------------------------------------------------
 
 
-def test_engine_chunked_prefill_matches_blocking_and_sequential(small_model):
-    """The ragged engine with chunked prefill emits exactly the tokens of
-    the blocking-prefill engine AND of each request served alone."""
-    cfg, model, params = small_model
-    rng = np.random.default_rng(4)
-    spec = [
-        ([int(t) for t in rng.integers(1, 200, size=int(rng.integers(2, 30)))],
-         int(rng.integers(2, 7)))
-        for _ in range(6)
-    ]
-    outs = {}
-    for name, kw in (
-        ("chunked", dict(prefill_chunk=8)),                  # fused (default)
-        ("interleaved", dict(prefill_chunk=8, fused=False)), # PR-5 path
-        ("blocking", dict(prefill_chunk=None)),
-    ):
-        eng = _mk_engine(cfg, params, slots=3, **kw)
-        reqs = [Request(rid=i, prompt=list(p), max_new_tokens=m)
-                for i, (p, m) in enumerate(spec)]
-        for r in reqs:
-            eng.submit(r)
-        eng.run_until_drained()
-        assert all(r.done for r in reqs)
-        outs[name] = [r.out_tokens for r in reqs]
-    solo = []
-    for i, (p, m) in enumerate(spec):
-        e = _mk_engine(cfg, params, slots=1, prefill_chunk=8)
-        r = Request(rid=i, prompt=list(p), max_new_tokens=m)
-        e.submit(r)
-        e.run_until_drained()
-        solo.append(r.out_tokens)
-    assert outs["chunked"] == outs["interleaved"] == outs["blocking"] == solo
-
-
 def test_engine_chunked_prefill_no_starvation(small_model):
-    """Active slots decode between EVERY chunk: while a long prompt streams
-    in, the co-resident request gains one token per engine step.
-
-    ``fused=False`` pins the PR-5 interleaved round-robin state machine
-    (``_advance_prefill``); the fused path's no-starvation property is
-    covered in test_fused_step.py."""
+    """Active slots decode in EVERY step: while a long prompt streams in,
+    chunk by chunk, the co-resident request gains one token per step."""
     cfg, model, params = small_model
-    eng = _mk_engine(cfg, params, slots=2, prefill_chunk=4, fused=False)
+    eng = _mk_engine(cfg, params, slots=2, prefill_chunk=4)
     short = Request(rid=0, prompt=[1, 2], max_new_tokens=30)
     eng.submit(short)
     eng.step()                     # short admitted (single chunk) + decoding
@@ -211,30 +172,16 @@ def test_engine_chunked_prefill_no_starvation(small_model):
     assert long_r.done and len(long_r.out_tokens) == 4
 
 
-def test_engine_blocking_mode_regression(small_model):
-    """``prefill_chunk=None`` reproduces the PR-4 engine exactly: whole
-    prompt prefilled inside _admit, no prefill state machine engaged; and
-    lockstep batching never chunks regardless of the setting."""
+@pytest.mark.parametrize("chunk", [None, 0, -8])
+def test_engine_refuses_a_prefill_chunk_that_is_not_positive(small_model, chunk):
+    """The one step streams prompts in chunks: there is no whole-prompt
+    mode for ``None`` or ``0`` to select."""
     cfg, model, params = small_model
-    eng = _mk_engine(cfg, params, slots=2, prefill_chunk=None)
-    assert not eng._chunked_prefill_on()
-    r = Request(rid=0, prompt=[1, 2, 3], max_new_tokens=3)
-    eng.submit(r)
-    eng.step()
-    # blocking: admission prefilled the whole prompt AND a decode ran
-    assert len(r.out_tokens) == 2
-    assert eng._prefill_toks == {}
-    eng.run_until_drained()
-    assert r.done
-
-    lock = _mk_engine(cfg, params, slots=2, batching="lockstep", prefill_chunk=16)
-    assert not lock._chunked_prefill_on()
-
-    # the default chunk size comes from the plan config
-    eng2 = _mk_engine(cfg, params, slots=2)
-    assert eng2.prefill_chunk == PlanConfig().prefill_chunk == 64
-    with pytest.raises(ValueError):
-        _mk_engine(cfg, params, slots=2, prefill_chunk=0)
+    with pytest.raises(ValueError, match="positive int"):
+        _mk_engine(cfg, params, slots=2, prefill_chunk=chunk)
+    with pytest.raises(ValueError, match="positive int"):
+        _mk_engine(cfg, params, slots=2,
+                   plan_cfg=PlanConfig(method="etf", prefill_chunk=chunk))
 
 
 def test_engine_hot_swap_requeues_through_chunked_prefill(small_model):
@@ -459,12 +406,11 @@ def test_simulate_pipeline_prefill_tasks_validated():
     base = simulate_pipeline(g, pl, cm, 5, max_in_flight=2)
     assert res.makespan > base.makespan
     assert res.steady_throughput < base.steady_throughput
-    # lockstep admission composes with prefill rounds
-    lock = simulate_pipeline(
-        g, pl, cm, 5, max_in_flight=2, batching="lockstep",
-        prompt_len=64, prefill_chunk=32,
+    # uniform prompts over two slots compose with admit-on-retire
+    even = simulate_pipeline(
+        g, pl, cm, 5, max_in_flight=2, prompt_len=64, prefill_chunk=32,
     )
-    validate_pipeline_schedule(g, pl, cm, lock)
+    validate_pipeline_schedule(g, pl, cm, even)
 
     with pytest.raises(ValueError):
         simulate_pipeline(g, pl, cm, 3, prompt_len=[1, 2])     # wrong arity

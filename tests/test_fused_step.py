@@ -10,12 +10,10 @@ Covers the tentpole and its satellites:
   (dense, gemma2 windows, pure-SSM, hybrid, MoE, enc-dec) and attention
   impls (naive/chunked/pallas), and at the engine level;
 * the engine's fused step — ONE forward per step over exactly two
-  compiled shapes, every mid-prefill slot advances every step, no
-  full-cache-row gather/scatter (the ``_slot_row_caches`` copies are
-  legacy-only), KV writes touch only each row's written span;
-* regressions — ``batching="lockstep"``, ``prefill_chunk=None`` and the
-  PR-5 interleaved path are bit-identical with fused off, and
-  ``validate_pipeline_schedule`` still rejects schedules violating
+  compiled shapes, every mid-prefill slot advances every step, KV writes
+  touch only each row's written span, and a plan that scores unfused
+  prefill is refused;
+* ``validate_pipeline_schedule`` still rejects schedules violating
   per-chunk precedence or decode-after-prefill ordering;
 * observation-window hygiene — one fused forward splits into per-class
   decode/prefill samples, a long-prompt burst commits no decode derate;
@@ -440,10 +438,12 @@ def test_fused_kv_writes_touch_only_written_span():
 # ----------------------------------------------------------------------
 
 
-def test_engine_fused_matches_interleaved_and_sequential():
-    """The fused engine emits exactly the tokens of the PR-5 interleaved
-    engine AND of each request served alone — including windows where
-    several long prompts stream concurrently."""
+@pytest.mark.parametrize("chunk", [3, 8, 16, 64])
+def test_engine_matches_one_request_at_a_time(chunk):
+    """Several requests served together emit exactly the tokens of each
+    request served alone — including windows where several long prompts
+    stream concurrently.  A chunk of 64 covers the longest prompt, so one
+    chunk carries a whole prompt."""
     cfg, model, params = _model()
     rng = np.random.default_rng(6)
     spec = [
@@ -451,24 +451,21 @@ def test_engine_fused_matches_interleaved_and_sequential():
          int(rng.integers(2, 7)))
         for _ in range(6)
     ]
-    outs = {}
-    for name, fused in (("fused", True), ("interleaved", False)):
-        eng = _mk_engine(cfg, params, slots=3, prefill_chunk=8, fused=fused)
-        reqs = [Request(rid=i, prompt=list(p), max_new_tokens=m)
-                for i, (p, m) in enumerate(spec)]
-        for r in reqs:
-            eng.submit(r)
-        eng.run_until_drained()
-        assert all(r.done for r in reqs)
-        outs[name] = [r.out_tokens for r in reqs]
+    eng = _mk_engine(cfg, params, slots=3, prefill_chunk=chunk)
+    reqs = [Request(rid=i, prompt=list(p), max_new_tokens=m)
+            for i, (p, m) in enumerate(spec)]
+    for r in reqs:
+        eng.submit(r)
+    eng.run_until_drained()
+    assert all(r.done for r in reqs)
     solo = []
     for i, (p, m) in enumerate(spec):
-        e = _mk_engine(cfg, params, slots=1, prefill_chunk=8)
+        e = _mk_engine(cfg, params, slots=1, prefill_chunk=chunk)
         r = Request(rid=i, prompt=list(p), max_new_tokens=m)
         e.submit(r)
         e.run_until_drained()
         solo.append(r.out_tokens)
-    assert outs["fused"] == outs["interleaved"] == solo
+    assert [r.out_tokens for r in reqs] == solo
 
 
 @hypothesis.settings(max_examples=3, deadline=None)
@@ -484,7 +481,6 @@ def test_engine_fused_token_identity_property(seed, chunk):
         for _ in range(4)
     ]
     eng = _mk_engine(cfg, params, slots=2, prefill_chunk=chunk)
-    assert eng._fused_on()
     reqs = [Request(rid=i, prompt=list(p), max_new_tokens=m)
             for i, (p, m) in enumerate(spec)]
     for r in reqs:
@@ -539,70 +535,15 @@ def test_fused_one_forward_per_step_two_shapes(monkeypatch):
     assert (3, 4) in calls and (3, 1) in calls
 
 
-def test_fused_path_never_copies_full_cache_rows(monkeypatch):
-    """Satellite 3 (engine level): the fused path never calls the legacy
-    full-row gather/scatter (``_slot_row_caches`` / ``_write_slot_cache``)
-    — chunk KV lands via the in-place masked-span write only."""
+def test_engine_refuses_a_plan_without_fused_prefill():
+    """The engine takes its chunk from the plan and serves it fused, so a
+    plan that scores prefill chunks as standalone forwards is refused."""
     cfg, model, params = _model()
-    eng = _mk_engine(cfg, params, slots=2, prefill_chunk=4)
-    assert eng._fused_on()
-
-    def boom(*a, **kw):
-        raise AssertionError(
-            "fused path must not gather/scatter full cache rows"
-        )
-
-    monkeypatch.setattr(eng, "_slot_row_caches", boom)
-    monkeypatch.setattr(eng, "_write_slot_cache", boom)
-    rng = np.random.default_rng(8)
-    reqs = [
-        Request(rid=i,
-                prompt=[int(t) for t in rng.integers(1, 200, size=22)],
-                max_new_tokens=3)
-        for i in range(3)
-    ]
-    for r in reqs:
-        eng.submit(r)
-    eng.run_until_drained()
-    assert all(r.done for r in reqs)
-
-
-def test_fused_off_modes_unaffected():
-    """Regressions: lockstep batching and blocking prefill silently ignore
-    the fused flag (``_fused_on`` requires ragged + a chunk size), the
-    engine reads its default from ``PlanConfig.fused_prefill``, and an
-    explicit constructor ``fused=`` overrides the plan."""
-    cfg, model, params = _model()
-    spec = [([1, 2, 3, 4, 5], 3), ([7, 8], 2)]
-
-    def outs(**kw):
-        eng = _mk_engine(cfg, params, slots=2, **kw)
-        reqs = [Request(rid=i, prompt=list(p), max_new_tokens=m)
-                for i, (p, m) in enumerate(spec)]
-        for r in reqs:
-            eng.submit(r)
-        eng.run_until_drained()
-        return eng, [r.out_tokens for r in reqs]
-
-    lock_on, o1 = outs(batching="lockstep", prefill_chunk=16, fused=True)
-    lock_off, o2 = outs(batching="lockstep", prefill_chunk=16, fused=False)
-    assert not lock_on._fused_on() and not lock_off._fused_on()
-    assert o1 == o2
-    blk_on, o3 = outs(prefill_chunk=None, fused=True)
-    assert not blk_on._fused_on()
-    assert o3 == o2
-
-    # the default comes from the plan; the kwarg overrides it
     assert PlanConfig().fused_prefill is True
-    assert _mk_engine(cfg, params, slots=1).fused is True
-    assert _mk_engine(
-        cfg, params, slots=1,
-        plan_cfg=PlanConfig(method="etf", fused_prefill=False),
-    ).fused is False
-    assert _mk_engine(
-        cfg, params, slots=1,
-        plan_cfg=PlanConfig(method="etf", fused_prefill=False), fused=True,
-    ).fused is True
+    assert _mk_engine(cfg, params, slots=1).prefill_chunk == 64
+    with pytest.raises(ValueError, match="fused_prefill"):
+        _mk_engine(cfg, params, slots=1,
+                   plan_cfg=PlanConfig(method="etf", fused_prefill=False))
 
 
 # ----------------------------------------------------------------------
@@ -631,7 +572,7 @@ def test_fused_forward_splits_decode_and_prefill_samples():
     assert drained == dec, "observation windows must be decode-only"
     assert eng.executor.stage_times() == [[] for _ in dec]
     rep = eng.straggler_report()
-    assert rep["prefill"]["fused"] is True
+    assert rep["prefill"]["chunk"] == 4
     # the report's prefill section owns every prefill share recorded (the
     # whole-run history includes whatever earlier windows already split off)
     assert sum(s["n"] for s in rep["prefill"]["stages"]) >= sum(map(len, pre))
@@ -652,7 +593,6 @@ def test_fused_long_prompt_burst_commits_no_derate():
         adapt=AdaptationConfig(window_steps=4, min_samples=1,
                                confirm_windows=1, smoothing=1.0),
     )
-    assert eng._fused_on()
     rng = np.random.default_rng(5)
     for i in range(4):
         eng.submit(Request(

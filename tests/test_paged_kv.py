@@ -434,23 +434,9 @@ def test_engine_paged_matches_dense_no_sharing_and_collapse():
     assert p2 == dense
 
 
-def test_engine_paged_requires_fused_ragged():
-    """Paged KV rides the fused ragged chunked path only — the legacy
-    full-row paths never see page pools, by construction."""
+def test_engine_paged_refuses_a_page_size_that_is_not_positive():
     cfg, _, params = _model("llama3.2-1b")
     cluster = tpu_slice_cluster(n_slices=1)
-    for bad in (
-        dict(batching="lockstep"),
-        dict(fused=False),
-        dict(prefill_chunk=None),
-    ):
-        with pytest.raises(ValueError, match="paged KV"):
-            ServingEngine(
-                cfg, params, cluster, slots=2,
-                plan_cfg=PlanConfig(method="etf", kv_page_tokens=8),
-                eos_id=-1, max_len=64,
-                **{"prefill_chunk": 8, **bad},
-            )
     with pytest.raises(ValueError, match="positive"):
         ServingEngine(
             cfg, params, cluster, slots=2,

@@ -7,7 +7,6 @@ Covers the tentpole and its satellites:
 * admission invariants — retire-and-refill mid-flight without KV row
   corruption, and KV-aware admission still enforced per slot;
 * ragged attention at the kernel level (naive vs pallas, per-row masks);
-* `simulate_pipeline`'s lockstep/ragged admission split;
 * the batch-aware cost model (roofline bending, simulator wiring);
 * the throughput MILP's per-channel big-M horizon tightening;
 * `DeratePolicy` persistence (round trip + engine restart resume).
@@ -28,7 +27,7 @@ from repro.core.graph import chain_graph, random_dag
 from repro.core.heuristics import bottleneck_balance
 from repro.core.milp import solve_placement
 from repro.core.placement import PlanConfig
-from repro.core.simulate import bottleneck_time, simulate, simulate_pipeline
+from repro.core.simulate import bottleneck_time, simulate_pipeline
 from repro.models.model import build_model
 from repro.serving.adaptation import AdaptationConfig, DeratePolicy
 from repro.serving.engine import Request, ServingEngine
@@ -76,7 +75,6 @@ def test_ragged_mixed_depth_matches_sequential_reference(small_model, seed):
         refs.append(r.out_tokens)
 
     eng = _mk_engine(cfg, params, slots=4)
-    assert eng.batching == "ragged"
     reqs = [
         Request(rid=i, prompt=list(p), max_new_tokens=m)
         for i, (p, m) in enumerate(reqs_spec)
@@ -197,38 +195,6 @@ def test_encdec_ragged_decode_positions():
     l2, _ = model.decode_step(params, {"tokens": nxt}, caches, pos)
     assert l2.shape == (B, cfg.vocab_size)
     assert np.isfinite(np.asarray(l2, np.float32)).all()
-
-
-# ----------------------------------------------------------------------
-# simulator: lockstep waves vs ragged admit-on-retire
-# ----------------------------------------------------------------------
-
-
-def test_simulate_pipeline_lockstep_waves():
-    g = chain_graph(["matmul"] * 5, flops=1e9, output_bytes=1e6)
-    cl = inter_server_cluster()
-    cm = CostModel(cl)
-    pl = {nid: nid % cl.k for nid in g.nodes}
-    rag = simulate_pipeline(g, pl, cm, 8, max_in_flight=2, batching="ragged")
-    lock = simulate_pipeline(g, pl, cm, 8, max_in_flight=2, batching="lockstep")
-    # waves can only hurt: the next cohort waits for the slowest member
-    assert lock.makespan >= rag.makespan - 1e-12
-    assert lock.steady_throughput <= rag.steady_throughput + 1e-9
-    # wave structure: with slots=2, completions pair up — request 2k+1's
-    # admission cannot precede request 2k-1's completion
-    starts = {
-        rid: min(r.start for (rr, t), r in lock.schedule.items() if rr == rid)
-        for rid in range(8)
-    }
-    for wave in range(1, 4):
-        prev_done = max(lock.completions[2 * wave - 2], lock.completions[2 * wave - 1])
-        assert starts[2 * wave] >= prev_done - 1e-12
-    # n=1 reduces to the single-query simulator in BOTH modes
-    mk = simulate(g, pl, cm).makespan
-    for mode in ("ragged", "lockstep"):
-        assert simulate_pipeline(g, pl, cm, 1, batching=mode).makespan == mk
-    with pytest.raises(ValueError):
-        simulate_pipeline(g, pl, cm, 2, batching="cohort")
 
 
 # ----------------------------------------------------------------------

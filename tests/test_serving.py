@@ -127,6 +127,23 @@ def test_engine_continuous_batching_slot_reuse(small_model):
     assert a.done and b.done
 
 
+def test_submit_refuses_an_empty_prompt(small_model):
+    """A request with no prompt and no generated tokens has nothing to
+    predict from: submit raises and queues nothing, and the engine serves
+    on."""
+    cfg, model, params = small_model
+    cluster = tpu_slice_cluster(n_slices=1)
+    eng = ServingEngine(cfg, params, cluster, slots=1, max_len=64,
+                        plan_cfg=PlanConfig(method="etf"), eos_id=-1)
+    with pytest.raises(ValueError, match="empty prompt"):
+        eng.submit(Request(rid=0, prompt=[], max_new_tokens=3))
+    assert eng.queue == []
+    r = Request(rid=1, prompt=[1, 2], max_new_tokens=3)
+    eng.submit(r)
+    eng.run_until_drained()
+    assert r.done and len(r.out_tokens) == 3
+
+
 def test_straggler_report_shape(small_model):
     cfg, model, params = small_model
     cluster = tpu_slice_cluster(n_slices=1)

@@ -408,14 +408,8 @@ def test_engine_spec_self_draft_multi_advance():
         assert row["tokens_per_round"] == 4.0
 
 
-def test_engine_spec_requires_fused_path():
+def test_engine_spec_refuses_drafts_it_cannot_serve():
     cfg, model, params = _model()
-    with pytest.raises(ValueError, match="fused"):
-        ServingEngine(
-            cfg, params, _spec_cluster(), slots=2, max_len=64,
-            plan_cfg=PlanConfig(method="etf", spec_tokens=3),
-            fused=False, draft_cfg=cfg, draft_params=params,
-        )
     with pytest.raises(ValueError, match="draft_params"):
         ServingEngine(
             cfg, params, _spec_cluster(), slots=2, max_len=64,

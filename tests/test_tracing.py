@@ -163,22 +163,23 @@ def test_counters_after_a_known_run():
 
 
 def test_compiles_count_new_shapes_only():
-    """Blocking whole-prompt prefill compiles once per prompt length: a
-    second prompt of the same length compiles nothing, a new length does."""
-    eng = _engine(slots=1, prefill_chunk=None)
+    """The fused step compiles once per batch shape: a prompt served in one
+    chunk compiles the chunk shape, a second such prompt compiles nothing,
+    and the first decode step compiles the decode shape."""
+    eng = _engine(slots=1, prefill_chunk=CHUNK)
 
-    def serve(n, rid):
+    def serve(n, new_tokens, rid):
         _drain(eng, [Request(rid=rid, prompt=list(range(1, n + 1)),
-                             max_new_tokens=3)])
+                             max_new_tokens=new_tokens)])
         return eng.straggler_report()["counters"]["compiles"]
 
-    first = serve(6, 0)
+    first = serve(6, 1, 0)          # one chunk, its token retires it
     assert first > 0
-    assert serve(6, 1) == first
-    assert serve(9, 2) > first
+    assert serve(5, 1, 1) == first
+    assert serve(9, 3, 2) > first   # two chunks, then two decode steps
     c = eng.straggler_report()["counters"]
-    assert c["prefill_tokens"] == 6 + 6 + 9 and c["decode_tokens"] == 9
-    assert c["chunk_steps"] == 0 and c["admitted"] == 3
+    assert c["prefill_tokens"] == 6 + 5 + 9 and c["decode_tokens"] == 5
+    assert c["chunk_steps"] == 4 and c["admitted"] == 3
 
 
 def test_compile_counter_is_process_wide():
